@@ -34,6 +34,12 @@ if _os.environ.get("MXNET_GPU_MEM_POOL_RESERVE") and \
     except ValueError:
         pass
 
+# The persistent compile cache is keyed by its directory, so it is placed
+# once, here, before anything below can compile (see _startup.py).
+from ._startup import configure_compile_cache as _configure_compile_cache
+
+_configure_compile_cache()
+
 if _os.environ.get("COORDINATOR_ADDRESS") or _os.environ.get("DMLC_PS_ROOT_URI"):
     from .parallel import dist as _dist
 

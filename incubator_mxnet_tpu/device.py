@@ -3,11 +3,18 @@
 Reference: `python/mxnet/device.py:24` defines `Device(device_type, device_id)`
 with `cpu()`/`gpu()` helpers and a thread-local current-device stack. The
 TPU-native build maps `tpu` to jax TPU devices and keeps `gpu()` as an alias
-for the accelerator so reference-style scripts run unchanged.
+for the accelerator so reference-style scripts run unchanged on a TPU host.
+
+Nothing here hands back a device other than the one asked for: `tpu(i)` (and
+its `gpu(i)` alias) for a chip that is not there raises, and the default
+device is the CPU only in a process that was put on the CPU by name
+(``JAX_PLATFORMS=cpu``, as the tests are).
 """
 from __future__ import annotations
 
 import threading
+
+from .base import MXNetError
 
 __all__ = [
     "Device",
@@ -53,14 +60,15 @@ class Device:
         import jax
 
         kind = _DEVTYPE_TO_JAX[self.device_type]
-        devs = [d for d in jax.devices() if d.platform == kind]
-        if not devs:
-            if kind == "tpu":
-                # accelerator platforms other than literal "tpu" (e.g. tunneled)
-                devs = [d for d in jax.devices() if d.platform != "cpu"]
-            if not devs:
-                devs = jax.devices("cpu")
-        return devs[min(self.device_id, len(devs) - 1)]
+        if kind == "cpu":
+            devs = jax.devices("cpu")
+        else:
+            devs = [d for d in jax.devices() if d.platform == kind]
+        if self.device_id >= len(devs):
+            raise MXNetError(
+                f"{self!r}: this process has {len(devs)} {kind} device(s) "
+                f"(jax default backend {jax.default_backend()!r})")
+        return devs[self.device_id]
 
     # -- protocol -----------------------------------------------------------
     def __eq__(self, other):
@@ -93,13 +101,12 @@ class Device:
 Context = Device
 
 
-def _accelerator_present() -> bool:
+def _on_cpu_by_name() -> bool:
+    """True when the process was pinned to the CPU backend explicitly
+    (``JAX_PLATFORMS=cpu`` / ``jax_platforms``), as the test suite is."""
     import jax
 
-    try:
-        return any(d.platform != "cpu" for d in jax.devices())
-    except RuntimeError:
-        return False
+    return (jax.config.jax_platforms or "").split(",")[0] == "cpu"
 
 
 def cpu(device_id: int = 0) -> Device:
@@ -118,7 +125,7 @@ def gpu(device_id: int = 0) -> Device:
 def num_tpus() -> int:
     import jax
 
-    return sum(1 for d in jax.devices() if d.platform != "cpu")
+    return sum(1 for d in jax.devices() if d.platform == "tpu")
 
 
 num_gpus = num_tpus
@@ -129,17 +136,22 @@ def current_device() -> Device:
     if stack:
         return stack[-1]
     if Device._default is None:
-        Device._default = tpu(0) if _accelerator_present() else cpu(0)
+        if num_tpus():
+            Device._default = tpu(0)
+        elif _on_cpu_by_name():
+            Device._default = cpu(0)
+        else:
+            raise MXNetError(
+                "no TPU found, and the process was not put on the CPU by "
+                "name: set JAX_PLATFORMS=cpu to run there deliberately")
     return Device._default
 
 
 def gpu_memory_info(device_id: int = 0):
-    """(free, total) bytes on the accelerator (reference: device.py:249)."""
-    import jax
-
-    dev = tpu(device_id).jax_device
+    """(free, total) bytes on the accelerator (reference: device.py:249);
+    (0, 0) where that chip does not exist or the backend reports no stats."""
     try:
-        stats = dev.memory_stats()
+        stats = tpu(device_id).jax_device.memory_stats()
         total = stats.get("bytes_limit", 0)
         used = stats.get("bytes_in_use", 0)
         return (total - used, total)

@@ -1084,7 +1084,7 @@ def _cached_jit(name, key, pure_fn, call_vals):
         _jit_deny(name, key)
         return None
     except Exception as e:
-        # transient failure (dropped remote compile, OOM…) or a genuine
+        # transient failure (OOM…) or a genuine
         # user error: evict and fall back to eager — user errors re-raise
         # identically there. Repeated deterministic failures stop paying
         # the trace cost via the deny list.

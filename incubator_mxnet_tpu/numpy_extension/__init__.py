@@ -591,21 +591,23 @@ def _placed_on_cpu(a):
 def layer_norm(data, gamma=None, beta=None, axis=-1, eps=1e-5, **kwargs):  # noqa: ARG001
     jnp = _jnp()
 
-    if gamma is not None and beta is not None:
-        import jax as _jax
+    from ..ops import _dispatch
 
+    if gamma is not None and beta is not None:
         from ..ops import layer_norm as _ln
 
         xv = data._data if isinstance(data, NDArray) else data
-        if (_jax.default_backend() == "tpu" and not _placed_on_cpu(xv)
+        if (_dispatch.use_pallas() and not _placed_on_cpu(xv)
                 and _ln.supports(xv.shape, axis, xv.shape[-1])
                 and jnp.issubdtype(xv.dtype, jnp.floating)):
             # fused pallas path: one HBM pass fwd, fused bwd with row-stat
             # residuals (see ops/layer_norm.py)
+            _dispatch.note("layer_norm", "pallas")
             return apply_op(
                 "layer_norm",
                 lambda x, g, b: _ln.layer_norm(x, g, b, eps=eps),
                 (data, gamma, beta))
+    _dispatch.note("layer_norm", "xla")
 
     def f(x, g, b):
         # dtype-preserving with f32 internal math: the statistics and the
@@ -708,6 +710,8 @@ def dropout(data, p=0.5, axes=(), mode="training", **kwargs):  # noqa: ARG001
     key = next_key()
     dshape = tuple((data._data if isinstance(data, NDArray) else data).shape)
     ddtype = (data._data if isinstance(data, NDArray) else data).dtype
+    from ..ops import _dispatch
+
     if _hw.supports(dshape, axes, ddtype, p) and _hw.use_kernel(key):
         # hardware-RNG pallas kernel: rescues the threefry-keyed path from
         # VPU bit-gen cost (see ops/dropout.py `use_kernel` for the
@@ -715,7 +719,9 @@ def dropout(data, p=0.5, axes=(), mode="training", **kwargs):  # noqa: ARG001
         def f(x):
             return _hw.dropout(x, key, p)
 
+        _dispatch.note("dropout", "pallas")
         return apply_op("dropout", f, (data,))
+    _dispatch.note("dropout", "xla")
 
     def f(x):
         shape = list(x.shape)
@@ -861,11 +867,12 @@ def residual_dropout_ln(x, h, gamma, beta, p=0.0, eps=1e-5, axis=-1):
     """``layer_norm(x + dropout_p(h))`` — the post-LN transformer residual
     site, fused into ONE pallas pass on TPU (`ops/fused_block.py`; 24
     such sites in BERT-base cost ~45 ms/step unfused at seq 512). Off
-    TPU, or for unsupported layouts, falls back to the composed ops with
-    identical semantics."""
+    TPU, or for layouts `ops.fused_block.supports` does not admit, the
+    composed ops run instead with identical semantics."""
     import jax as _jax
 
     from .. import autograd
+    from ..ops import _dispatch
     from ..ops import fused_block as _fb
 
     jnp = _jnp()
@@ -873,7 +880,7 @@ def residual_dropout_ln(x, h, gamma, beta, p=0.0, eps=1e-5, axis=-1):
     xv = x._data if isinstance(x, NDArray) else x
     hv = h._data if isinstance(h, NDArray) else h
     ndim = len(xv.shape)
-    if (_jax.default_backend() == "tpu" and axis in (-1, ndim - 1)
+    if (_dispatch.use_pallas() and axis in (-1, ndim - 1)
             and not _placed_on_cpu(xv)
             and _fb.supports(xv.shape, xv.shape[-1])
             and tuple(xv.shape) == tuple(hv.shape)  # kernel can't broadcast
@@ -893,8 +900,10 @@ def residual_dropout_ln(x, h, gamma, beta, p=0.0, eps=1e-5, axis=-1):
         def f(xa, ha, g, b, s):
             return _fb.residual_dropout_ln(xa, ha, g, b, p_eff, s, eps=eps)
 
+        _dispatch.note("residual_dropout_ln", "pallas")
         return apply_op("residual_dropout_ln", f,
                         (x, h, gamma, beta, NDArray(seeds)))
+    _dispatch.note("residual_dropout_ln", "composed")
     d = dropout(h, p=p) if p else h
     return layer_norm(x + d, gamma, beta, axis=axis, eps=eps)
 
@@ -912,12 +921,13 @@ def gelu_dropout(data, p=0.0, impl="auto"):
     import jax as _jax
 
     from .. import autograd
+    from ..ops import _dispatch
     from ..ops import fused_block as _fb
 
     jnp = _jnp()
     p_eff = float(p) if autograd.is_training() else 0.0
     xv = data._data if isinstance(data, NDArray) else data
-    if (impl == "pallas" and _jax.default_backend() == "tpu"
+    if (impl == "pallas" and _dispatch.use_pallas()
             and 0 < p_eff < 1.0 and not _placed_on_cpu(xv)
             and len(xv.shape) >= 2 and xv.shape[-1] % 128 == 0
             and jnp.issubdtype(xv.dtype, jnp.floating)):
@@ -929,7 +939,9 @@ def gelu_dropout(data, p=0.0, impl="auto"):
         def f(u, s):
             return _fb.gelu_dropout(u, p_eff, s)
 
+        _dispatch.note("gelu_dropout", "pallas")
         return apply_op("gelu_dropout", f, (data, NDArray(seeds)))
+    _dispatch.note("gelu_dropout", "xla")
     out = gelu(data, approximate=False)
     return dropout(out, p=p) if p else out
 

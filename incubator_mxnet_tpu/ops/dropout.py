@@ -27,9 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret_default():
-    return jax.default_backend() != "tpu"
+from . import _dispatch
 
 
 def _mask_kernel_body(seed_ref, x_ref, o_ref, *, threshold, scale, grad):
@@ -127,7 +125,7 @@ def use_kernel(key):
     v5e) but loses to the fully-fused XLA path when keys are rbg-class
     (124k) — a kernel boundary costs more than hardware bit-gen saves. So:
     kernel only for threefry keys on a real TPU."""
-    if jax.default_backend() != "tpu":
+    if not _dispatch.use_pallas():
         return False
     if jnp.issubdtype(getattr(key, "dtype", None), jax.dtypes.prng_key):
         return "fry" in str(jax.random.key_impl(key))
@@ -152,5 +150,5 @@ def dropout(x, key, p):
         x2d = x.reshape(-1, shape[-1])
     else:
         x2d = x.reshape(-1, 1024)
-    out = _dropout_core(x2d, seeds, float(p), _interpret_default())
+    out = _dropout_core(x2d, seeds, float(p), _dispatch.interpret_default())
     return out.reshape(shape)

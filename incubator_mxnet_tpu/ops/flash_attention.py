@@ -32,11 +32,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import _dispatch
+
 NEG_INF = -1.0e30  # finite stand-in for -inf: keeps exp()/max() NaN-free
 
-
-def _interpret_default():
-    return jax.default_backend() != "tpu"
 
 
 def _round_up(x, m):
@@ -446,26 +445,22 @@ def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
     if impl == "auto":
         attn_bytes = b * h * t_q * t_k * jnp.dtype(q.dtype).itemsize
         impl = "xla" if attn_bytes <= _XLA_ATTN_BYTES_LIMIT else "pallas"
+    if impl not in ("xla", "pallas"):
+        raise ValueError(f"flash_attention: unknown impl {impl!r}")
+    _dispatch.note("flash_attention", impl)
     if impl == "xla":
         return _xla_attention(q, k, v, lengths, bool(causal),
                               float(sm_scale), layout=layout)
-    if impl != "pallas":
-        raise ValueError(f"flash_attention: unknown impl {impl!r}")
     if layout == "bthd":
         # the streaming kernel wants heads-major blocks; one relayout is
         # noise next to the O(T²) compute that forces the pallas path
         q = q.transpose(0, 2, 1, 3)
         k = k.transpose(0, 2, 1, 3)
         v = v.transpose(0, 2, 1, 3)
-        o = flash_attention(q, k, v, lengths=lengths, causal=causal,
-                            sm_scale=sm_scale, block_q=block_q,
-                            block_k=block_k, interpret=interpret,
-                            impl="pallas", layout="bhtd")
-        return o.transpose(0, 2, 1, 3)
     tq = t_q
     tk = t_k
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = _dispatch.interpret_default()
 
     block_q = min(block_q, _round_up(tq, 8))
     block_k = min(block_k, _round_up(tk, 8))
@@ -491,7 +486,8 @@ def flash_attention(q, k, v, lengths=None, causal=False, sm_scale=None,
     o = _flash_core(qr, kr, vr, lens, float(sm_scale), bool(causal),
                     int(block_q), int(block_k), bool(interpret),
                     need_mask, lengths is not None)
-    return o[:, :tq].reshape(b, h, tq, d)
+    o = o[:, :tq].reshape(b, h, tq, d)
+    return o.transpose(0, 2, 1, 3) if layout == "bthd" else o
 
 
 def mha_flash(q, k, v, lengths=None, causal=False, sm_scale=None):

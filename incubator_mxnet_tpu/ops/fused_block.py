@@ -35,9 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret_default():
-    return jax.default_backend() != "tpu"
+from . import _dispatch
 
 
 def supports(shape, feat):
@@ -367,7 +365,7 @@ def gelu_dropout(u, p, seeds, interpret=None):
     """``dropout_p(gelu(u))`` over the last axis, one fused pass with
     in-VMEM RNG (backward re-seeds the stream; no mask/bit residuals)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = _dispatch.interpret_default()
     if interpret:
         return _gd_emulate(u, seeds, float(p))
     shape = u.shape
@@ -400,7 +398,7 @@ def residual_dropout_ln(x, h, gamma, beta, p, seeds, eps=1e-5,
     framework key per call — reproducible under `mx.random.seed`).
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = _dispatch.interpret_default()
     shape = x.shape
     feat = shape[-1]
     if interpret:

@@ -29,9 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret_default():
-    return jax.default_backend() != "tpu"
+from . import _dispatch
+from .fused_block import _block_rows
 
 
 def supports(shape, axis, feat):
@@ -172,22 +171,28 @@ def _ln_core_bwd(eps, block_r, interpret, res, dy):
 _ln_core.defvjp(_ln_core_fwd, _ln_core_bwd)
 
 
-def layer_norm(x, gamma, beta, eps=1e-5, block_r=256, interpret=None):
+def layer_norm(x, gamma, beta, eps=1e-5, interpret=None):
     """Fused last-axis layer norm over an arbitrary-rank tensor.
 
     Leading axes collapse to rows; rows pad up to the block size (padded
     rows normalize garbage that is sliced away — their stats never touch
-    real rows). Differentiable via the fused backward kernels.
+    real rows). The row block is derived from the feature width and
+    itemsize (`fused_block._block_rows`, sized for the backward kernel,
+    shared by forward and backward) so that every width `supports()`
+    admits fits the chip's scoped VMEM. Differentiable via the fused
+    backward kernels.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = _dispatch.interpret_default()
     shape = x.shape
     feat = shape[-1]
     rows = 1
     for s in shape[:-1]:
         rows *= s
+    if rows == 0:
+        return x   # empty batch: no grid to launch
     x2d = x.reshape(rows, feat)
-    block = min(block_r, rows) if rows else block_r
+    block = _block_rows(rows, feat, x2d.dtype.itemsize)
     pad = (-rows) % block if block else 0
     if pad:
         x2d = jnp.pad(x2d, ((0, pad), (0, 0)))

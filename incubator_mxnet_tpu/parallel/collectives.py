@@ -19,35 +19,24 @@ __all__ = ["all_reduce", "all_gather", "reduce_scatter", "broadcast",
 def pvary(x, axis_name):
     """Mark a value device-varying over `axis_name` — shard_map's
     replication-typing escape hatch for loop carries whose body outputs
-    are varying (ppermute/axis_index inside). `jax.lax.pvary` where the
-    pinned jax has it; otherwise adding a zeroed `axis_index` term gives
-    the checker a varying operand and XLA folds the arithmetic away.
-    Not a comms op, so no census."""
+    are varying (ppermute/axis_index inside): `jax.lax.pcast(...,
+    to="varying")`. Not a comms op, so no census."""
     import jax
 
     from ..ndarray.ndarray import NDArray
 
     v = x._data if isinstance(x, NDArray) else x
     names = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-    if hasattr(jax.lax, "pvary"):
-        out = jax.lax.pvary(v, names)
-    else:
-        out = v
-        for ax in names:
-            zero = jax.lax.convert_element_type(
-                jax.lax.axis_index(ax) * 0, v.dtype)
-            out = out + zero
+    out = jax.lax.pcast(v, names, to="varying")
     return NDArray(out) if isinstance(x, NDArray) else out
 
 
 def axis_size(axis_name):
     """Static size of a mapped axis (a Python int inside shard_map/pjit).
-    `lax.psum` of the literal 1 constant-folds to the axis size — the
-    portable spelling (`jax.lax.axis_size` is newer than this build's
-    pinned jax). Not a comms op, so no census."""
+    Not a comms op, so no census."""
     import jax
 
-    return jax.lax.psum(1, axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def all_reduce(x, axis_name, op="sum"):

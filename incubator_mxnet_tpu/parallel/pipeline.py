@@ -176,11 +176,9 @@ class PipelineParallel:
                     new_s)
 
         psp = P(axis_name)
-        from jax.experimental.shard_map import shard_map
-
         from ..telemetry.compiles import ledgered_jit
 
-        self._jit = ledgered_jit(shard_map(
+        self._jit = ledgered_jit(jax.shard_map(
             device_fn, mesh=mesh,
             in_specs=(psp, psp, P(), P(), P()),
             out_specs=(P(), psp, psp)), family="train.pipeline.step")

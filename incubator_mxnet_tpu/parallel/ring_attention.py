@@ -99,7 +99,7 @@ def ring_self_attention(q, k, v, mesh=None, axis="sp", causal=False,
     """NDArray-level ring attention: shards the sequence dim of
     (B, H, T, D) inputs over `axis` of the active mesh and runs
     `ring_attention` under shard_map."""
-    from jax.experimental.shard_map import shard_map
+    import jax
     from jax.sharding import PartitionSpec as P
 
     from ..ndarray.ndarray import NDArray
@@ -114,7 +114,7 @@ def ring_self_attention(q, k, v, mesh=None, axis="sp", causal=False,
     vv = v._data if isinstance(v, NDArray) else v
 
     spec = P(None, None, axis, None)  # shard T of (B, H, T, D)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name=axis, causal=causal,
                 sm_scale=sm_scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)

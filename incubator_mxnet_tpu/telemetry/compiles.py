@@ -10,6 +10,7 @@ cheap enough to leave running) records one ledger entry per compile event:
 - program/family name, cache key, lowering+compile wall time;
 - ``compiled.cost_analysis()`` flops / bytes accessed;
 - ``compiled.memory_analysis()`` argument/output/temp/generated bytes;
+- how many pallas kernels (``tpu_custom_call``) the compiled program holds;
 - the donation map and an HLO fingerprint (StableHLO text hash);
 - per-argument signature (shape, dtype, weak_type | static repr).
 
@@ -198,10 +199,12 @@ def _parse_io_aliases(hlo_text):
 def _analyze(fn, args, kwargs):
     """Lower `fn` at the avals of `args` and pull XLA's own accounting:
     flops/bytes from cost_analysis, the four memory_analysis buckets, and
-    a StableHLO fingerprint. Re-lowers once per COMPILE EVENT (telemetry
-    on only); lowering from ShapeDtypeStructs never touches donated
-    buffers. Returns (flops, bytes, memory, fingerprint) — all None on
-    any failure (abstract lowering isn't possible for every program)."""
+    a StableHLO fingerprint, and the number of pallas kernels
+    (``tpu_custom_call``) left in the compiled program. Re-lowers once
+    per COMPILE EVENT (telemetry on only); lowering from
+    ShapeDtypeStructs never touches donated buffers. Returns (flops,
+    bytes, memory, fingerprint, tpu_custom_calls) — all None on any
+    failure (abstract lowering isn't possible for every program)."""
     try:
         sds = [_abstractify(a) for a in args]
         skw = {k: _abstractify(v) for k, v in (kwargs or {}).items()}
@@ -225,16 +228,20 @@ def _analyze(fn, args, kwargs):
                 "generated": getattr(ma, "generated_code_size_in_bytes",
                                      None),
             }
+        aliases = kernels = None
         try:
-            aliases = _parse_io_aliases(compiled.as_text())
-        except Exception:
-            aliases = None
+            hlo = compiled.as_text()
+            kernels = len(re.findall(
+                r'custom_call_target="tpu_custom_call"', hlo))
+            aliases = _parse_io_aliases(hlo)
+        except Exception:  # noqa: FL006 — as_text is optional introspection
+            pass
         if mem is not None and aliases is not None:
             mem["aliased"] = aliases
             mem["aliased_params"] = sorted({p for _, p in aliases})
-        return flops, nbytes, mem, fingerprint
+        return flops, nbytes, mem, fingerprint, kernels
     except Exception:
-        return None, None, None, None
+        return None, None, None, None, None
 
 
 def _trace_clean():
@@ -268,9 +275,10 @@ def _record(family, seconds, args, kwargs, fn, bucket, cache_key, donate,
             observe):
     family = str(family)
     sig = signature_of(args, kwargs)
-    flops = nbytes = mem = fingerprint = None
+    flops = nbytes = mem = fingerprint = kernels = None
     if fn is not None and hasattr(fn, "lower") and _trace_clean():
-        flops, nbytes, mem, fingerprint = _analyze(fn, args, kwargs)
+        flops, nbytes, mem, fingerprint, kernels = _analyze(
+            fn, args, kwargs)
     with _LOCK:
         entries = _LEDGER.setdefault(family, [])
         seen_buckets = _BUCKETS.setdefault(family, set())
@@ -296,6 +304,7 @@ def _record(family, seconds, args, kwargs, fn, bucket, cache_key, donate,
             "bytes_accessed": nbytes,
             "memory": mem,
             "hlo_fingerprint": fingerprint,
+            "tpu_custom_calls": kernels,
             "cause": cause,
             "detail": detail,
         }

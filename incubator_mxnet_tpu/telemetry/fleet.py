@@ -446,11 +446,9 @@ def probe_collectives(mesh=None, axis=None, nbytes=1 << 16, iters=3):
     for op, (fn, in_spec, out_spec, shape, link_bytes) in ops.items():
         x = jnp.zeros(shape, jnp.float32)
         try:
-            from jax.experimental.shard_map import shard_map
-
             jfn = ledgered_jit(
-                shard_map(fn, mesh=mesh, in_specs=(in_spec,),
-                          out_specs=out_spec, check_rep=False),
+                jax.shard_map(fn, mesh=mesh, in_specs=(in_spec,),
+                              out_specs=out_spec, check_vma=False),
                 family="fleet.probe_" + op)
             jfn(x).block_until_ready()     # compile outside the timing
             best = float("inf")

@@ -1,0 +1,121 @@
+"""The main path's pallas kernels, compiled for a DESCRIBED TPU v5e.
+
+Interpret mode (what every other kernel test runs under on the CPU) cannot
+see what the chip's compiler refuses: a block that overflows the 16 MiB of
+scoped VMEM, a slice off the tiling. The TPU compiler is installed here and
+compiles for a chip that is described, not attached — so these cases guard
+every later PR at no chip time. Nothing runs; a compile that passes is not
+a chip run. Skipped where the topology cannot be described.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+
+from incubator_mxnet_tpu.ops import dropout as dropout_k  # noqa: E402
+from incubator_mxnet_tpu.ops import fused_block, layer_norm  # noqa: E402
+from incubator_mxnet_tpu.ops.flash_attention import (  # noqa: E402
+    flash_attention)
+
+ROWS = 32 * 512        # BERT-base's batch 32 x seq 512 activation rows
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one described v5e chip. The persistent compile cache is
+    off in here: an executable compiled for a described chip is written
+    but can never be read back without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _ln(x, g, b):
+    return layer_norm.layer_norm(x, g, b, interpret=False)
+
+
+def _rdl(x, h, g, b, s):
+    return fused_block.residual_dropout_ln(x, h, g, b, 0.1, s,
+                                           interpret=False)
+
+
+def _gelu_dropout(x, s):
+    return fused_block.gelu_dropout(x, 0.1, s, interpret=False)
+
+
+def _dropout(x, s):
+    return dropout_k._dropout_core(x, s, 0.1, False)
+
+
+def _flash(q, k, v, lens):
+    return flash_attention(q, k, v, lengths=lens, impl="pallas",
+                           interpret=False)
+
+
+def _flash_causal(q, k, v):
+    return flash_attention(q, k, v, causal=True, impl="pallas",
+                           interpret=False)
+
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+# (id, fn, argument kinds, x shape, dtypes, kernels in the grad program)
+#   argument kinds: x activation, g (feat,) f32 affine, s (2,) int32 seeds,
+#   l (batch,) int32 lengths. Every case compiles jax.grad of the op, which
+#   holds the forward AND the backward kernel where backward needs both.
+CASES = [
+    # layer norm at the widths whose fixed 256-row block overflowed VMEM:
+    # f32 backward at 3072, f32 forward at 4096, bf16 backward at 4096,
+    # everything at 8192 — forward and backward, f32 and bf16
+    ("ln-3072", _ln, "xgg", (ROWS, 3072), (F32, BF16), 2),
+    ("ln-4096", _ln, "xgg", (ROWS, 4096), (F32, BF16), 2),
+    ("ln-8192", _ln, "xgg", (ROWS, 8192), (F32, BF16), 2),
+    # one case per kernel at BERT-base / GPT-2 width
+    ("ln-768", _ln, "xgg", (ROWS, 768), (BF16,), 2),
+    ("rdl-768", _rdl, "xxggs", (ROWS, 768), (BF16,), 2),
+    ("gelu-dropout-3072", _gelu_dropout, "xs", (ROWS, 3072), (BF16,), 1),
+    ("dropout-768", _dropout, "xs", (ROWS, 768), (BF16,), 1),
+    # flash: BERT's and GPT-2's shapes, and a ragged T with lengths
+    ("flash-32x12x512x64-lengths", _flash, "xxxl", (32, 12, 512, 64),
+     (BF16,), 3),
+    ("flash-8x12x1024x64-causal", _flash_causal, "xxx", (8, 12, 1024, 64),
+     (BF16,), 3),
+    ("flash-ragged-T100-lengths", _flash, "xxxl", (2, 4, 100, 64),
+     (F32,), 3),
+]
+
+
+@pytest.mark.parametrize("fn,kinds,shape,dtypes,n_kernels",
+                         [c[1:] for c in CASES], ids=[c[0] for c in CASES])
+def test_kernel_compiles_for_v5e(chip, fn, kinds, shape, dtypes, n_kernels):
+    def arg(kind, dtype):
+        if kind == "x":
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+        if kind == "g":
+            return jax.ShapeDtypeStruct(shape[-1:], jnp.float32,
+                                        sharding=chip)
+        if kind == "s":
+            return jax.ShapeDtypeStruct((2,), jnp.int32, sharding=chip)
+        return jax.ShapeDtypeStruct(shape[:1], jnp.int32, sharding=chip)
+
+    n_diff = sum(k in "xg" for k in kinds)
+    grad = jax.jit(jax.grad(lambda *a: fn(*a).astype(jnp.float32).sum(),
+                            argnums=tuple(range(n_diff))))
+    for dtype in dtypes:
+        compiled = grad.lower(*[arg(k, dtype) for k in kinds]).compile()
+        assert compiled.as_text().count(
+            'custom_call_target="tpu_custom_call"') == n_kernels, dtype

@@ -7,10 +7,10 @@ paths) are otherwise exercised only by the bench. This file compares a
 core-op sample between the CPU backend and the REAL chip in one
 process.
 
-Run on the bench host:  MX_TPU_TESTS=1 python -m pytest
-tests/test_tpu_consistency.py -q     (conftest keeps the accelerator
-platform visible alongside cpu when MX_TPU_TESTS=1; without it, every
-test here skips.)
+Run on a TPU host:  MX_TPU_TESTS=1 python -m pytest
+tests/test_tpu_consistency.py -q     (conftest keeps the tpu platform
+visible alongside cpu when MX_TPU_TESTS=1; without it, every test here
+skips.)
 """
 import os
 
@@ -27,9 +27,9 @@ def _accel_device():
 
     import incubator_mxnet_tpu as mx
 
-    if not any(d.platform != "cpu" for d in jax.devices()):
-        pytest.skip("no accelerator platform visible")
-    return mx.tpu(0)    # maps to the first non-cpu platform
+    if not any(d.platform == "tpu" for d in jax.devices()):
+        pytest.skip("no TPU visible")
+    return mx.tpu(0)
 
 
 def _pair(fn, inputs, rtol=2e-2, atol=5e-2):

@@ -10,7 +10,10 @@ N times:
 
 - `--launcher local` (default): N processes on this machine, used by the
   distributed kvstore tests (the analogue of the reference's
-  `tests/nightly/dist_sync_kvstore.py` local runs).
+  `tests/nightly/dist_sync_kvstore.py` local runs). The ranks must be
+  pinned to the CPU by name (`JAX_PLATFORMS=cpu`): a TPU chip belongs to
+  one process, and N local ranks would each claim every chip of the host.
+  On a TPU host the layout is ONE process over all its chips.
 - `--launcher ssh -H hostfile`: one process per host over ssh (each TPU
   host in a pod slice runs the same program; jax discovers the global
   topology at initialize()).
@@ -81,6 +84,17 @@ def main():
     extra = dict(kv.split("=", 1) for kv in args.env)
 
     if args.launcher == "local":
+        platforms = dict(os.environ, **extra).get("JAX_PLATFORMS", "")
+        if platforms.split(",")[0] != "cpu":
+            sys.exit(
+                "launch.py --launcher local: the ranks are not pinned to the "
+                f"CPU (JAX_PLATFORMS={platforms!r}). A TPU chip belongs to "
+                "one process, and every local rank would claim every chip "
+                "of this host. Set JAX_PLATFORMS=cpu (or --env "
+                "JAX_PLATFORMS=cpu) for tests and rehearsals; on a TPU host "
+                "run ONE process over all its chips (parallel.DataParallel "
+                "over a Mesh, serve.serve_mesh), and one process per host "
+                "with --launcher ssh.")
         coordinator = f"127.0.0.1:{args.port}"
         procs = []
         for rank in range(args.num_workers):
