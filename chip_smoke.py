@@ -200,7 +200,7 @@ def serve_phase(cfg, seed, watch):
     from incubator_mxnet_tpu.ops import _dispatch
     from incubator_mxnet_tpu.serve.scheduler import (PREFILL_CHUNKS,
                                                      PREFIX_HITS)
-    from incubator_mxnet_tpu.telemetry import compiles
+    from incubator_mxnet_tpu.telemetry import compiles, tracing
 
     import jax
 
@@ -268,6 +268,20 @@ def serve_phase(cfg, seed, watch):
             raise RuntimeError(
                 f"the window did not exercise the prefix cache ({hits} "
                 f"hits) or chunked prefill ({chunks} chunks)")
+        # the program's own step timeline (always on): every boundary of
+        # the loop is stamped, so the phases cover the steps' wall
+        steps = tracing.step_records(t0, t0 + window)
+        wall = sum(r["wall"] for r in steps)
+        accounted = 100.0 * sum(r[ph] for r in steps for ph in tracing.PHASES
+                                if ph != "lock_wait") / wall if wall else 0.0
+        say(f"step records in the window: {len(steps)}, phases cover "
+            f"{accounted:.2f} % of their wall; request records: "
+            f"{len(tracing.request_records(t0, t0 + window))}")
+        if not steps or accounted < 99.0:
+            raise RuntimeError(
+                f"{len(steps)} step records in the window, phases cover "
+                f"{accounted:.2f} % of the steps' wall (< 99: a boundary of "
+                "Scheduler.step is no longer stamped)")
         net.hybridize()
         i = longest(prompts)
         check_against_reference(net, prompts[i], outputs[i], "serve")

@@ -76,6 +76,7 @@ def _run_kernel(x2d, seeds, threshold, scale, interpret, grad):
         out_specs=pl.BlockSpec((block, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
         interpret=interpret,
+        name="mx_dropout",
     )(seeds, x2d)
 
 

@@ -155,6 +155,7 @@ def _fwd(q, k, v, lens, sm_scale, causal, block_q, block_k, interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="mx_flash_fwd",
     )(lens, q, k, v)
 
 
@@ -278,6 +279,7 @@ def _bwd(q, k, v, o, lse, lens, do, sm_scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="mx_flash_dq",
     )(lens, q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -319,6 +321,7 @@ def _bwd(q, k, v, o, lse, lens, do, sm_scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="mx_flash_dkv",
     )(lens, q, k, v, do, lse, delta)
     return dq, dk, dv
 

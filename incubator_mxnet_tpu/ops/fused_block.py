@@ -161,6 +161,7 @@ def _fwd(x2d, h2d, gamma, beta, seeds, p, eps, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="mx_rdln_fwd",
     )(seeds, x2d, h2d, gamma.reshape(1, feat), beta.reshape(1, feat))
 
 
@@ -201,6 +202,7 @@ def _bwd(x2d, h2d, dy2d, mean, rstd, gamma, seeds, p, eps, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="mx_rdln_bwd",
     )(seeds, x2d, h2d, dy2d, mean, rstd, gamma.reshape(1, feat))
     return dx, dh, dgb[0], dgb[1]
 
@@ -327,6 +329,7 @@ def _gd_call(kernel, out_dtype, x2d, seeds, extra, p, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="mx_gelu_dropout",
     )(seeds, x2d, *extra)
 
 

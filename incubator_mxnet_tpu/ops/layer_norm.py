@@ -81,6 +81,7 @@ def _fwd(x2d, gamma, beta, eps, block_r, interpret):
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="mx_ln_fwd",
     )(x2d, gamma.reshape(1, feat), beta.reshape(1, feat))
     return y, mean, rstd
 
@@ -143,6 +144,7 @@ def _bwd(x2d, dy2d, mean, rstd, gamma, block_r, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="mx_ln_bwd",
     )(x2d, dy2d, mean, rstd, gamma.reshape(1, feat))
     return dx, dgb[0], dgb[1]
 

@@ -443,8 +443,12 @@ class DataParallel:
 
         from .mesh import mesh_scope
 
+        from ..telemetry import tracing
+
+        # the launch of the step program as a span in a live profiler
+        # session's trace (a no-op otherwise); no read-back inside
         with (mesh_scope(self.mesh) if self.mesh is not None
-              else contextlib.nullcontext()):
+              else contextlib.nullcontext()), tracing.phase("mx.train.step"):
             loss, new_params, new_states, aux_new, self._t_dev = self._jit(
                 param_vals, frozen_vals, self.opt_states, self._t_dev,
                 lr_dev, wd_dev, self._base_key, xv, yv)

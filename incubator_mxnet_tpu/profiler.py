@@ -128,7 +128,10 @@ def start(profile_process="worker"):  # noqa: ARG001
         # MOMENT start_trace is called (session setup time included), so
         # the anchor must be captured BEFORE the call — capturing it
         # after used to shear the device lanes by the multi-second
-        # profiler-session init on some backends
+        # profiler-session init on some backends. Measured on a v5e: the
+        # rebased lanes sit 0.1-0.2 ms early (TELEMETRY.md). Spans that
+        # must line up with the device to better than that are written
+        # into the profiler's own trace (telemetry/tracing.py)
         _STATE["trace_t0_us"] = time.time() * 1e6
         jax.profiler.start_trace(_STATE["trace_dir"])
         _STATE["jax_tracing"] = True
@@ -417,7 +420,8 @@ def dump(finished=True, profile_process="worker"):  # noqa: ARG001
     (pid 0), the device/runtime lanes from the jax trace (reference:
     profiler.py:125 writes the C++ profiler's chrome trace), and — when
     span tracing is armed — the request/step span lanes from
-    `telemetry.tracing` (all three share the epoch-µs clock base)."""
+    `telemetry.tracing` (all three in epoch µs; the device lanes through
+    the anchor taken in `start`, 0.1-0.2 ms off on a v5e, TELEMETRY.md)."""
     path = _CONFIG["filename"]
     with _LOCK:
         merged = [{"name": "process_name", "ph": "M", "pid": 0,

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import queue as _queue
 import threading
+import time
 
 import numpy as onp
 
@@ -30,7 +31,8 @@ from ..telemetry.locks import tracked_lock
 from ..util import env_float as _env_float
 from ..util import env_int as _env_int
 from .engine import SlotDecoder
-from .scheduler import EngineClosed, Request, Scheduler, _DONE
+from .scheduler import (STEP_SECONDS, EngineClosed, Request, Scheduler,
+                        _DONE)
 
 __all__ = ["ServeEngine"]
 
@@ -166,10 +168,12 @@ class ServeEngine:
         signal, never a silent drop."""
         if temperature is None:
             temperature = self._default_temperature
-        with self._lock:
+        t_call = time.perf_counter()
+        with tracing.phase("mx.serve.submit"), self._lock:
             req = self._sched.submit(prompt_ids, max_new_tokens,
                                      temperature=temperature,
-                                     eos_id=eos_id, deadline_s=deadline_s)
+                                     eos_id=eos_id, deadline_s=deadline_s,
+                                     t_call=t_call)
             # standalone-engine anatomy: request == segment here, so the
             # engine owns the record end to end (the gateway attaches
             # its own records to segments AFTER its dispatch instead)
@@ -191,8 +195,15 @@ class ServeEngine:
         flight-recorder dump behind — the postmortem carries the active
         requests' spans — and then propagates unchanged."""
         try:
-            with self._lock:
-                return self._sched.step()
+            waited_since = time.perf_counter()
+            with tracing.phase("mx.serve.lock_wait"):
+                # the wait `with self._lock` makes, apart so that it
+                # alone is the span
+                self._lock.acquire()   # noqa: FL011
+            try:
+                return self._sched.step(waited_since)
+            finally:
+                self._lock.release()
         except Exception as e:
             from ..telemetry import hbm
 
@@ -210,8 +221,6 @@ class ServeEngine:
     def _drive_until(self, reqs, timeout=None):
         """Make `reqs` finish: wait on the driver if one is running,
         otherwise step the engine from this thread."""
-        import time
-
         t_end = None if timeout is None else time.monotonic() + timeout
         for req in reqs:
             while not req.done:
@@ -292,11 +301,15 @@ class ServeEngine:
     def start(self):
         """Start the background driver thread: it owns the step loop so
         client threads only submit/stream. Idempotent."""
-        import time
-
         if self._driver_running():
             return self
         self._stop.clear()
+
+        def _idle():
+            # nothing queued, nothing running (or a failed step): back off
+            with tracing.phase("mx.serve.idle", timed=True) as idle:
+                time.sleep(_IDLE_SLEEP_S)
+            STEP_SECONDS["idle"].inc(idle.seconds)
 
         def _loop():
             import logging
@@ -323,11 +336,10 @@ class ServeEngine:
                             "manual step()/start() after the cause is "
                             "fixed", failures)
                         break
-                    time.sleep(_IDLE_SLEEP_S)
+                    _idle()
                     continue
                 if not progressed:
-                    # nothing queued, nothing running — idle backoff
-                    time.sleep(_IDLE_SLEEP_S)
+                    _idle()
 
         self._driver = threading.Thread(target=_loop, name="mx-serve-driver",
                                         daemon=True)
@@ -350,8 +362,6 @@ class ServeEngine:
         occupying slots (new work and the never-admitted queue are
         rejected with `EngineClosed`); ``drain=False`` fails everything
         immediately. Releases the device KV cache."""
-        import time
-
         with self._lock:
             self._sched.close(drain=drain)
             running = [r for r in self._sched._in_slot if r is not None]

@@ -108,7 +108,7 @@ from ..models.decoding import (GPTDecoder, NgramProposer, bucket_chunk,
                                chunk_buckets)
 from ..telemetry import compiles as _compiles
 from ..telemetry import hbm as _hbm
-from ..telemetry import registry
+from ..telemetry import registry, tracing
 
 __all__ = ["SlotDecoder", "PageAllocator", "PrefixCache",
            "PagePoolExhausted", "DEFAULT_PAGE_TOKENS",
@@ -937,64 +937,76 @@ class SlotDecoder:
         e.g. the shared-prefix boundary). Returns ``(first_token, bucket,
         pad)`` — the sampled token is meaningful only when this was the
         prompt's final chunk; `bucket`/`pad` feed the caller's span
-        annotations.
+        annotations. `key` is a PRNG key or a callable that makes one: the
+        scheduler hands its key maker in, so that the eager ``fold_in``
+        runs inside the launch span with the rest of the host's work.
         """
         jnp = _j().numpy
-        self._refresh_params()
-        self._ensure_pool()
-        if self._prefill_jit is None:
-            self._prefill_jit = self._build_prefill()
-        pt = self.page_tokens
-        if t_start % pt:
-            raise ValueError(
-                f"chunk start {t_start} is not page-aligned (page_tokens="
-                f"{pt})")
-        chunk = onp.asarray(chunk_tokens, onp.int32).reshape(-1)
-        n = chunk.size
-        bucket = bucket_chunk(n, self.chunk_buckets)
-        pad = bucket - n
-        if pad:
-            chunk = onp.pad(chunk, (0, pad))
-            PAD_TOKENS.inc(pad)
-        # the chunk's pages, padded with the trash page where the bucket
-        # overshoots the slot's mapped range (pad-token K/V is discarded)
-        first_page = t_start // pt
-        row = self._table[slot]
-        cp = bucket // pt
-        chunk_pages = onp.zeros(cp, onp.int32)
-        avail = row[first_page:first_page + cp]
-        chunk_pages[:avail.size] = avail
-        args = (jnp.asarray(chunk)[None, :], jnp.asarray(row),
-                jnp.asarray(chunk_pages), jnp.int32(t_start), jnp.int32(n),
-                key, jnp.float32(max(float(temperature), 1e-6)))
-        if self._int8:
-            (self._pk, self._pv, self._sk, self._sv,
-             first) = self._prefill_jit(
-                self._dec._params, self._pk, self._pv, self._sk, self._sv,
-                *args, top_k=self._top_k, do_sample=self._do_sample)
-        else:
-            self._pk, self._pv, first = self._prefill_jit(
-                self._dec._params, self._pk, self._pv, *args,
-                top_k=self._top_k, do_sample=self._do_sample)
-        if self._draft_dec is not None:
-            # the draft model prefills the SAME chunk into its own
-            # pools (same pages — table/allocator are shared), so spec
-            # drafting starts from a warm draft KV for every request
-            self._draft_dec._auto_refresh()
-            if self._draft_prefill_jit is None:
-                self._draft_prefill_jit = self._build_prefill(
-                    self._draft_dec, "draft_prefill")
+        with tracing.phase("mx.serve.prefill.launch", "prefill_launch"):
+            self._refresh_params()
+            self._ensure_pool()
+            if self._prefill_jit is None:
+                self._prefill_jit = self._build_prefill()
+            pt = self.page_tokens
+            if t_start % pt:
+                raise ValueError(
+                    f"chunk start {t_start} is not page-aligned "
+                    f"(page_tokens={pt})")
+            chunk = onp.asarray(chunk_tokens, onp.int32).reshape(-1)
+            n = chunk.size
+            bucket = bucket_chunk(n, self.chunk_buckets)
+            pad = bucket - n
+            if pad:
+                chunk = onp.pad(chunk, (0, pad))
+                PAD_TOKENS.inc(pad)
+            # the chunk's pages, padded with the trash page where the
+            # bucket overshoots the slot's mapped range (pad-token K/V is
+            # discarded)
+            first_page = t_start // pt
+            row = self._table[slot]
+            cp = bucket // pt
+            chunk_pages = onp.zeros(cp, onp.int32)
+            avail = row[first_page:first_page + cp]
+            chunk_pages[:avail.size] = avail
+            if callable(key):
+                key = key()
+            args = (jnp.asarray(chunk)[None, :], jnp.asarray(row),
+                    jnp.asarray(chunk_pages), jnp.int32(t_start),
+                    jnp.int32(n), key,
+                    jnp.float32(max(float(temperature), 1e-6)))
             if self._int8:
-                (self._dpk, self._dpv, self._dsk, self._dsv,
-                 _) = self._draft_prefill_jit(
-                    self._draft_dec._params, self._dpk, self._dpv,
-                    self._dsk, self._dsv, *args, top_k=self._top_k,
+                (self._pk, self._pv, self._sk, self._sv,
+                 first) = self._prefill_jit(
+                    self._dec._params, self._pk, self._pv, self._sk,
+                    self._sv, *args, top_k=self._top_k,
                     do_sample=self._do_sample)
             else:
-                self._dpk, self._dpv, _ = self._draft_prefill_jit(
-                    self._draft_dec._params, self._dpk, self._dpv, *args,
+                self._pk, self._pv, first = self._prefill_jit(
+                    self._dec._params, self._pk, self._pv, *args,
                     top_k=self._top_k, do_sample=self._do_sample)
-        return int(first), bucket, pad
+            if self._draft_dec is not None:
+                # the draft model prefills the SAME chunk into its own
+                # pools (same pages — table/allocator are shared), so
+                # spec drafting starts from a warm draft KV for every
+                # request
+                self._draft_dec._auto_refresh()
+                if self._draft_prefill_jit is None:
+                    self._draft_prefill_jit = self._build_prefill(
+                        self._draft_dec, "draft_prefill")
+                if self._int8:
+                    (self._dpk, self._dpv, self._dsk, self._dsv,
+                     _) = self._draft_prefill_jit(
+                        self._draft_dec._params, self._dpk, self._dpv,
+                        self._dsk, self._dsv, *args, top_k=self._top_k,
+                        do_sample=self._do_sample)
+                else:
+                    self._dpk, self._dpv, _ = self._draft_prefill_jit(
+                        self._draft_dec._params, self._dpk, self._dpv,
+                        *args, top_k=self._top_k,
+                        do_sample=self._do_sample)
+        with tracing.phase("mx.serve.prefill.readback", "prefill_readback"):
+            first = int(first)            # blocks until the chunk ran
+        return first, bucket, pad
 
     # -- decode -------------------------------------------------------------
 
@@ -1158,28 +1170,34 @@ class SlotDecoder:
         branches on device values. Slots still mid-prefill must have
         ``active=False`` (their writes are redirected to the trash page).
         Returns the next token per slot as host numpy (the one host sync
-        per step)."""
+        per step). `key`: a PRNG key, or a callable that makes one (called
+        inside the launch span, as in `prefill_chunk_step`)."""
         jnp = _j().numpy
-        self._refresh_params()
-        self._ensure_pool()
-        if self._decode_jit is None:
-            self._decode_jit = self._build_decode()
-        args = (self._table_device(),
-                jnp.asarray(last_tok, jnp.int32),
-                jnp.asarray(pos, jnp.int32),
-                jnp.asarray(active, bool),
-                key,
-                jnp.asarray(temperature, jnp.float32))
-        if self._int8:
-            (self._pk, self._pv, self._sk, self._sv,
-             nxt) = self._decode_jit(
-                self._dec._params, self._pk, self._pv, self._sk, self._sv,
-                *args, top_k=self._top_k, do_sample=self._do_sample)
-        else:
-            self._pk, self._pv, nxt = self._decode_jit(
-                self._dec._params, self._pk, self._pv, *args,
-                top_k=self._top_k, do_sample=self._do_sample)
-        return onp.asarray(nxt)
+        with tracing.phase("mx.serve.decode.launch", "decode_launch"):
+            self._refresh_params()
+            self._ensure_pool()
+            if self._decode_jit is None:
+                self._decode_jit = self._build_decode()
+            if callable(key):
+                key = key()
+            args = (self._table_device(),
+                    jnp.asarray(last_tok, jnp.int32),
+                    jnp.asarray(pos, jnp.int32),
+                    jnp.asarray(active, bool),
+                    key,
+                    jnp.asarray(temperature, jnp.float32))
+            if self._int8:
+                (self._pk, self._pv, self._sk, self._sv,
+                 nxt) = self._decode_jit(
+                    self._dec._params, self._pk, self._pv, self._sk,
+                    self._sv, *args, top_k=self._top_k,
+                    do_sample=self._do_sample)
+            else:
+                self._pk, self._pv, nxt = self._decode_jit(
+                    self._dec._params, self._pk, self._pv, *args,
+                    top_k=self._top_k, do_sample=self._do_sample)
+        with tracing.phase("mx.serve.decode.readback", "decode_readback"):
+            return onp.asarray(nxt)       # blocks until the step ran
 
     # -- speculative decoding ----------------------------------------------
 
@@ -1364,33 +1382,37 @@ class SlotDecoder:
         Returns ``(max_slots, spec_k)`` int32 host numpy. No device
         program — the ngram draft's entire cost is this call."""
         out = onp.zeros((self.max_slots, self.spec_k), onp.int32)
-        for s, seq in enumerate(seqs):
-            if seq is not None:
-                out[s] = self._ngram.propose(seq)
+        with tracing.phase("mx.serve.spec.draft.propose", "decode_launch"):
+            for s, seq in enumerate(seqs):
+                if seq is not None:
+                    out[s] = self._ngram.propose(seq)
         return out
 
     def spec_draft_step(self, last_tok, pos, active, limit):
         """Run the draft model's k-step program; returns drafted tokens
         ``(max_slots, spec_k)`` as host numpy."""
         jnp = _j().numpy
-        self._draft_dec._auto_refresh()
-        self._ensure_pool()
-        if self._draft_jit is None:
-            self._draft_jit = self._build_draft()
-        args = (self._table_device(),
-                jnp.asarray(last_tok, jnp.int32),
-                jnp.asarray(pos, jnp.int32),
-                jnp.asarray(active, bool),
-                jnp.asarray(limit, jnp.int32))
-        if self._int8:
-            (self._dpk, self._dpv, self._dsk, self._dsv,
-             toks) = self._draft_jit(
-                self._draft_dec._params, self._dpk, self._dpv,
-                self._dsk, self._dsv, *args)
-        else:
-            self._dpk, self._dpv, toks = self._draft_jit(
-                self._draft_dec._params, self._dpk, self._dpv, *args)
-        return onp.asarray(toks)
+        with tracing.phase("mx.serve.spec.draft.launch", "decode_launch"):
+            self._draft_dec._auto_refresh()
+            self._ensure_pool()
+            if self._draft_jit is None:
+                self._draft_jit = self._build_draft()
+            args = (self._table_device(),
+                    jnp.asarray(last_tok, jnp.int32),
+                    jnp.asarray(pos, jnp.int32),
+                    jnp.asarray(active, bool),
+                    jnp.asarray(limit, jnp.int32))
+            if self._int8:
+                (self._dpk, self._dpv, self._dsk, self._dsv,
+                 toks) = self._draft_jit(
+                    self._draft_dec._params, self._dpk, self._dpv,
+                    self._dsk, self._dsv, *args)
+            else:
+                self._dpk, self._dpv, toks = self._draft_jit(
+                    self._draft_dec._params, self._dpk, self._dpv, *args)
+        with tracing.phase("mx.serve.spec.draft.readback",
+                           "decode_readback"):
+            return onp.asarray(toks)
 
     def spec_verify_step(self, last_tok, drafts, pos, active, limit):
         """Verify ``drafts`` (host ``(max_slots, spec_k)``) for every
@@ -1401,29 +1423,32 @@ class SlotDecoder:
         prefix matching rows ``0..m-1`` plus row ``m`` as the bonus
         token (>= 1 token of guaranteed progress per round)."""
         jnp = _j().numpy
-        self._refresh_params()
-        self._ensure_pool()
-        if self._verify_jit is None:
-            self._verify_jit = self._build_verify()
-        if not self._spec_gauge:
-            self._register_spec_gauge()
-        toks = onp.concatenate(
-            [onp.asarray(last_tok, onp.int32)[:, None],
-             onp.asarray(drafts, onp.int32)], axis=1)
-        args = (self._table_device(),
-                jnp.asarray(toks),
-                jnp.asarray(pos, jnp.int32),
-                jnp.asarray(active, bool),
-                jnp.asarray(limit, jnp.int32))
-        if self._int8:
-            (self._pk, self._pv, self._sk, self._sv,
-             tgt) = self._verify_jit(
-                self._dec._params, self._pk, self._pv, self._sk,
-                self._sv, *args)
-        else:
-            self._pk, self._pv, tgt = self._verify_jit(
-                self._dec._params, self._pk, self._pv, *args)
-        return onp.asarray(tgt)
+        with tracing.phase("mx.serve.spec.verify.launch", "decode_launch"):
+            self._refresh_params()
+            self._ensure_pool()
+            if self._verify_jit is None:
+                self._verify_jit = self._build_verify()
+            if not self._spec_gauge:
+                self._register_spec_gauge()
+            toks = onp.concatenate(
+                [onp.asarray(last_tok, onp.int32)[:, None],
+                 onp.asarray(drafts, onp.int32)], axis=1)
+            args = (self._table_device(),
+                    jnp.asarray(toks),
+                    jnp.asarray(pos, jnp.int32),
+                    jnp.asarray(active, bool),
+                    jnp.asarray(limit, jnp.int32))
+            if self._int8:
+                (self._pk, self._pv, self._sk, self._sv,
+                 tgt) = self._verify_jit(
+                    self._dec._params, self._pk, self._pv, self._sk,
+                    self._sv, *args)
+            else:
+                self._pk, self._pv, tgt = self._verify_jit(
+                    self._dec._params, self._pk, self._pv, *args)
+        with tracing.phase("mx.serve.spec.verify.readback",
+                           "decode_readback"):
+            return onp.asarray(tgt)
 
     def spec_count(self, drafted, accepted):
         """Scheduler callback: fold one slot-round's drafted/accepted

@@ -97,6 +97,22 @@ SPEC_DRAFTED = registry.counter(
 SPEC_ACCEPTED = registry.counter(
     "mx_serve_spec_accepted_tokens_total",
     "draft tokens accepted by the verify program (speculative decoding)")
+# the step clock's phases as running totals: with `idle` (the driver's
+# back-off sleep, serve/api.py) they add up to the driving thread's wall
+STEP_SECONDS = {
+    ph: registry.counter(
+        "mx_serve_step_seconds_total",
+        "seconds of the thread that drives the engine, by phase of "
+        "Scheduler.step (idle: the driver's back-off sleep)",
+        labels={"phase": ph})
+    for ph in tracing.PHASES + ("idle",)}
+SUBMIT_LOCK_WAIT = registry.histogram(
+    "mx_serve_submit_lock_wait_seconds",
+    "entry of ServeEngine.submit to the request queued: the wait for the "
+    "engine lock")
+QUEUE_WAIT = registry.histogram(
+    "mx_serve_queue_wait_seconds",
+    "request queued to the start of the iteration that gave it a slot")
 
 
 class QueueFull(RuntimeError):
@@ -129,7 +145,8 @@ class Request:
                  "tokens", "state", "error", "error_class", "slot",
                  "pages", "shared_tokens", "prefill_pos", "chunks_done",
                  "spec_reserved", "tenant", "prefill_only", "first_token",
-                 "anatomy", "trace_id", "_spans", "_stream", "_done")
+                 "anatomy", "trace_id", "t_submit_call", "t_enqueued",
+                 "t_admit", "t_first_token", "_spans", "_stream", "_done")
 
     def __init__(self, rid, prompt, max_new, temperature, eos_id,
                  deadline, parent_span=None, tenant=None):
@@ -156,6 +173,10 @@ class Request:
         self.prefill_only = False         # disagg handoff segment
         self.first_token = None           # sampled on the prefill side
         self.anatomy = None               # gateway's anatomy record
+        # the request record's stamps, on the step clock's clock (the
+        # performance counter; `submit_t` and the deadline stay monotonic)
+        self.t_submit_call = self.t_enqueued = None
+        self.t_admit = self.t_first_token = None
         # one trace per request (Dapper-style): the root span opens at
         # submit on the client thread and closes at retire on whichever
         # thread drives the engine — explicit lifecycle, never ambient.
@@ -206,6 +227,7 @@ class Request:
     def _emit(self, tok, now):
         if self.first_token_t is None:
             self.first_token_t = now
+            self.t_first_token = tracing.stamp()   # its read-back's end
             TTFT.observe(now - self.submit_t)
         self.tokens.append(tok)
         self._stream.put(tok)
@@ -216,6 +238,16 @@ class Request:
         decode→root (idempotent; every span is a no-op singleton when
         tracing is off)."""
         spans = self._spans
+        if "request" in spans and self.t_enqueued is not None:
+            # retirement (finished, failed, preempted or handed on): the
+            # always-on record, once
+            tracing.add_request_record(
+                id=self.id, trace_id=self.trace_id,
+                t_submit_call=self.t_submit_call,
+                t_enqueued=self.t_enqueued, t_admit=self.t_admit,
+                t_first_token=self.t_first_token, chunks=self.chunks_done,
+                shared_tokens=self.shared_tokens, tokens=len(self.tokens),
+                state=self.state)
         spans.pop("queue", _NULL).close(error=error)
         spans.pop("prefill", _NULL).annotate(
             chunks=self.chunks_done).close(error=error)
@@ -224,15 +256,18 @@ class Request:
         spans.pop("request", _NULL).annotate(
             tokens=len(self.tokens), state=self.state).close(error=error)
 
-    def _finish(self, now):
-        self.state = "done"
+    def _close(self, now, outcome, error=None):
         self.finish_t = now
         if self.anatomy is not None and self.anatomy.owner == "engine":
-            anatomy.complete(self.anatomy, now, "ok",
+            anatomy.complete(self.anatomy, now, outcome,
                              tokens=len(self.tokens))
-        self._trace_close()
+        self._trace_close(error=error)
         self._stream.put(_DONE)
         self._done.set()
+
+    def _finish(self, now):
+        self.state = "done"
+        self._close(now, "ok")
 
     def _fail(self, exc, now):
         from ..fault.retry import classify_exception
@@ -240,15 +275,8 @@ class Request:
         self.state = "failed"
         self.error = exc
         self.error_class = classify_exception(exc)
-        self.finish_t = now
-        if self.anatomy is not None and self.anatomy.owner == "engine":
-            anatomy.complete(
-                self.anatomy, now,
-                "expired" if isinstance(exc, DeadlineExceeded)
-                else "failed", tokens=len(self.tokens))
-        self._trace_close(error=exc)
-        self._stream.put(_DONE)
-        self._done.set()
+        self._close(now, "expired" if isinstance(exc, DeadlineExceeded)
+                    else "failed", error=exc)
 
 
 class Scheduler:
@@ -342,7 +370,9 @@ class Scheduler:
 
     def submit(self, prompt_ids, max_new_tokens, temperature=1.0,
                eos_id=None, deadline_s=None, parent_span=None,
-               tenant=None, prefill_only=False):
+               tenant=None, prefill_only=False, t_call=None):
+        """`t_call`: the caller's stamp (the step clock's clock) from
+        before it waited for the engine lock (`ServeEngine.submit`)."""
         if self.closed:
             raise EngineClosed("serve engine is shut down; new work is "
                                "rejected")
@@ -379,6 +409,9 @@ class Scheduler:
                 "raise n_pages or shrink prompt/max_new_tokens")
         self._next_id += 1
         req.submit_t = now
+        req.t_enqueued = time.perf_counter()
+        req.t_submit_call = req.t_enqueued if t_call is None else t_call
+        SUBMIT_LOCK_WAIT.observe(req.t_enqueued - req.t_submit_call)
         self._queue.append(req)
         QUEUE_DEPTH.set(len(self._queue))
         return req
@@ -406,33 +439,51 @@ class Scheduler:
 
     # -- the step loop ------------------------------------------------------
 
-    def step(self):
+    def step(self, waited_since=None):
         """One scheduling iteration (expire → admit → prefill chunks →
-        decode → retire). Returns True if any progress was made."""
+        decode → retire). Returns True if any progress was made.
+        `waited_since`: the caller's stamp from before it waited for the
+        lock it now holds (`ServeEngine.step`).
+
+        The iteration's boundaries are stamped once each on a step clock
+        (always on: a profiler span each, one step record, the phase
+        counters); the armed ``serve.*`` spans and the capacity / anatomy
+        ledgers take their times from those stamps."""
         from ..fault.injection import inject_at
 
-        # host-side span only — nothing here is ever traced by XLA, so
-        # the zero-steady-state-recompile guarantee is untouched
-        with tracing.span("serve.step", queued=len(self._queue),
-                          active=self._n_active):
-            inject_at("serve_step")
-            now = time.monotonic()
-            self._accrue_page_seconds()
-            self._expire(now)
-            admitted = self._admit(now)
-            chunks = self._prefill_step()
-            decoded = self._decode_once()
-            self._refresh_gauges()
-        return bool(admitted or chunks or decoded)
+        # host-side only — nothing here is ever traced by XLA, so the
+        # zero-steady-state-recompile guarantee is untouched
+        with tracing.StepClock(waited_since, queued=len(self._queue),
+                               active=self._n_active) as clock:
+            with tracing.phase("mx.serve.admit", "admit"):
+                inject_at("serve_step")
+                now = time.monotonic()
+                self._accrue_page_seconds(clock.t_start)
+                self._expire(now)
+                admitted = self._admit(now, clock.t_start)
+            chunks = self._prefill_step(clock)
+            decoding = self._n_decoding
+            decoded = self._decode_once(clock)
+            with tracing.phase("mx.serve.emit", "emit"):
+                self._refresh_gauges()
+            clock.progressed = bool(admitted or chunks or decoded)
+            clock.counts = {
+                "chunks": chunks, "decoding": decoding if decoded else 0,
+                "prefilling": self._n_active - self._n_decoding,
+                "queued": len(self._queue)}
+        for ph, seconds in clock.seconds.items():
+            if seconds:
+                STEP_SECONDS[ph].inc(seconds)
+        return clock.progressed
 
-    def _accrue_page_seconds(self):
+    def _accrue_page_seconds(self, t):
         """Cost-ledger seam: resident KV pages × seconds since the last
-        step, per occupied slot (dead branch while the capacity ledger
-        is disarmed — one module-attribute load per step)."""
+        step's start `t`, per occupied slot (dead branch while the
+        capacity ledger is disarmed — one module-attribute load per
+        step)."""
         if not capacity._ENABLED:
             self._cap_last_t = None
             return
-        t = time.perf_counter()
         last, self._cap_last_t = self._cap_last_t, t
         if last is None:
             return
@@ -456,11 +507,12 @@ class Scheduler:
                     and now > req.deadline:
                 self._retire(s, "deadline", now)
 
-    def _admit(self, now):
+    def _admit(self, now, t_step):
         """Map queued requests onto free slots + reserved pages. The full
         page budget is taken up front so decode can never OOM mid-flight;
         when even the unused prefix-cache entries cannot cover it, the
-        request stays queued until running slots retire."""
+        request stays queued until running slots retire. `t_step` is the
+        iteration's start stamp (the request record's ``t_admit``)."""
         admitted = 0
         alloc = self.slots.allocator
         cache = self.slots.prefix_cache
@@ -492,6 +544,8 @@ class Scheduler:
             self._in_slot[slot] = req
             self._n_active += 1
             admitted += 1
+            req.t_admit = t_step
+            QUEUE_WAIT.observe(req.t_admit - req.t_enqueued)
             if req.anatomy is not None and req.anatomy.owner == "engine":
                 # standalone engine: admit IS dispatch (the gateway
                 # stamps its own records at gateway dispatch instead)
@@ -505,11 +559,9 @@ class Scheduler:
                 "serve.prefill", parent=root, slot=slot,
                 prompt_len=int(req.prompt.size),
                 shared_tokens=shared_tokens)
-        if admitted:
-            QUEUE_DEPTH.set(len(self._queue))
-        return admitted
+        return admitted           # the step's end refreshes the gauges
 
-    def _prefill_step(self):
+    def _prefill_step(self, clock):
         """ONE chunk for every slot still prefilling — chunks of
         different requests and decode steps interleave, so a long prompt
         cannot stall the engine for its whole prefill. The final chunk
@@ -521,62 +573,67 @@ class Scheduler:
             t0 = req.prefill_pos
             n = min(self.slots.prefill_chunk, req.prompt.size - t0)
             last_chunk = t0 + n == req.prompt.size
-            cap_t0 = time.perf_counter() \
-                if (capacity._ENABLED or anatomy._ENABLED) else None
+            # the chunk's window, from the step clock's stamps: the
+            # boundary before it to the end of its read-back (the engine
+            # stamps launch and read-back apart inside)
+            w0 = clock.cursor
             first, bucket, pad = self.slots.prefill_chunk_step(
-                slot, req.prompt[t0:t0 + n], t0, self._next_key(),
+                slot, req.prompt[t0:t0 + n], t0, self._next_key,
                 req.temperature)
-            if cap_t0 is not None:
-                # per-chunk timing: exact per-slot prefill attribution,
-                # one window shared by the capacity + anatomy ledgers
-                if capacity._ENABLED:
-                    capacity.split_device_seconds(
-                        (req.tenant,), self.capacity_model, "prefill",
-                        time.perf_counter() - cap_t0)
-                anatomy.on_prefill_chunk(self, req, cap_t0,
-                                         time.perf_counter())
+            w1 = clock.lap("prefill_readback")
+            # exact per-slot prefill attribution, one window shared by
+            # the capacity + anatomy ledgers (dead branches while off)
+            capacity.split_device_seconds(
+                (req.tenant,), self.capacity_model, "prefill", w1 - w0)
+            anatomy.on_prefill_chunk(self, req, w0, w1)
             req.prefill_pos = t0 + n
             req.chunks_done += 1
             PREFILL_CHUNKS.inc()
             ran += 1
-            span = req._spans.get("prefill", _NULL)
-            span.annotate(bucket=bucket, pad_tokens=pad)
-            if not last_chunk:
-                continue
-            # whole prompt resident: its full pages become shareable
-            self.slots.prefix_cache.register(req.prompt, req.pages)
-            req._spans.pop("prefill", _NULL).annotate(
-                chunks=req.chunks_done).close()
-            t = time.monotonic()
-            if req.anatomy is not None:
-                req.anatomy.prefill_done(t, handoff=req.prefill_only)
-            req._emit(first, t)
-            if self._is_complete(req, first):
-                # one-token request (or instant EOS): retired without
-                # ever joining a decode step
-                req._spans.get("request", _NULL).annotate(
-                    reason=self._complete_reason(req, first))
-                self._retire(slot, self._complete_reason(req, first), t)
-                continue
-            if req.prefill_only:
-                # disagg handoff: the slot frees NOW but the page refs
-                # ride with the request — the migration plane
-                # (serve/disagg.py) claims it via take_prefilled() and
-                # owns moving or releasing those pages
-                req.first_token = int(first)
-                self._handoff(slot, req)
-                continue
-            self._pos[slot] = req.prompt.size
-            self._last[slot] = first
-            self._temps[slot] = max(req.temperature, 1e-6)
-            self._active[slot] = True
-            self._n_decoding += 1
-            # the per-slot decode segment of the request's trace: opens
-            # here, closes at retirement (tokens annotated at close)
-            req._spans["decode"] = tracing.open_span(
-                "serve.decode", parent=req._spans.get("request", _NULL),
-                slot=slot)
+            req._spans.get("prefill", _NULL).annotate(bucket=bucket,
+                                                      pad_tokens=pad)
+            if last_chunk:
+                with tracing.phase("mx.serve.emit", "emit"):
+                    self._prompt_done(slot, req, first)
         return ran
+
+    def _prompt_done(self, slot, req, first):
+        """The final chunk landed: the prompt's pages become shareable,
+        the first token goes out, and the slot turns to decoding (or is
+        retired or handed off at once)."""
+        # whole prompt resident: its full pages become shareable
+        self.slots.prefix_cache.register(req.prompt, req.pages)
+        req._spans.pop("prefill", _NULL).annotate(
+            chunks=req.chunks_done).close()
+        t = time.monotonic()
+        if req.anatomy is not None:
+            req.anatomy.prefill_done(t, handoff=req.prefill_only)
+        req._emit(first, t)
+        if self._is_complete(req, first):
+            # one-token request (or instant EOS): retired without
+            # ever joining a decode step
+            req._spans.get("request", _NULL).annotate(
+                reason=self._complete_reason(req, first))
+            self._retire(slot, self._complete_reason(req, first), t)
+            return
+        if req.prefill_only:
+            # disagg handoff: the slot frees NOW but the page refs
+            # ride with the request — the migration plane
+            # (serve/disagg.py) claims it via take_prefilled() and
+            # owns moving or releasing those pages
+            req.first_token = int(first)
+            self._handoff(slot, req)
+            return
+        self._pos[slot] = req.prompt.size
+        self._last[slot] = first
+        self._temps[slot] = max(req.temperature, 1e-6)
+        self._active[slot] = True
+        self._n_decoding += 1
+        # the per-slot decode segment of the request's trace: opens
+        # here, closes at retirement (tokens annotated at close)
+        req._spans["decode"] = tracing.open_span(
+            "serve.decode", parent=req._spans.get("request", _NULL),
+            slot=slot)
 
     def _is_complete(self, req, tok):
         return (len(req.tokens) >= req.max_new
@@ -587,38 +644,44 @@ class Scheduler:
             return "eos"
         return "length"
 
-    def _decode_once(self):
+    def _decode_once(self, clock):
         if self._n_decoding == 0:
             return False
         if self._spec_k:
-            return self._spec_decode_once()
-        cap_t0 = time.perf_counter() \
-            if (capacity._ENABLED or anatomy._ENABLED) else None
-        with tracing.span("serve.decode_step", slots=self._n_decoding):
-            nxt = self.slots.decode_step(self._last, self._pos,
-                                         self._active, self._next_key(),
-                                         self._temps)
-        if cap_t0 is not None:
-            # ONE batched program per step: split its wall evenly
-            # across the slots that decoded in it
-            if capacity._ENABLED:
-                capacity.split_device_seconds(
-                    [r.tenant for s, r in enumerate(self._in_slot)
-                     if r is not None and self._active[s]],
-                    self.capacity_model, "decode",
-                    time.perf_counter() - cap_t0)
-            anatomy.on_decode_step(self, cap_t0, time.perf_counter())
-        now = time.monotonic()
-        for s, req in enumerate(self._in_slot):
-            if req is None or not self._active[s]:
-                continue                   # free or still prefilling
-            tok = int(nxt[s])
-            req._emit(tok, now)
-            self._pos[s] += 1
-            self._last[s] = tok
-            if self._is_complete(req, tok):
-                self._retire(s, self._complete_reason(req, tok), now)
+            return self._spec_decode_once(clock)
+        # the decode call's window, from the step clock's stamps (the
+        # engine stamps launch and read-back apart inside it)
+        w0 = clock.cursor
+        nxt = self.slots.decode_step(self._last, self._pos, self._active,
+                                     self._next_key, self._temps)
+        w1 = clock.lap("decode_readback")
+        tracing.record_span("serve.decode_step", w0, w1,
+                            slots=self._n_decoding)
+        self._charge_decode(w0, w1)
+        with tracing.phase("mx.serve.emit", "emit"):
+            now = time.monotonic()
+            for s, req in enumerate(self._in_slot):
+                if req is None or not self._active[s]:
+                    continue                   # free or still prefilling
+                tok = int(nxt[s])
+                req._emit(tok, now)
+                self._pos[s] += 1
+                self._last[s] = tok
+                if self._is_complete(req, tok):
+                    self._retire(s, self._complete_reason(req, tok), now)
         return True
+
+    def _charge_decode(self, w0, w1):
+        """ONE batched program (or draft + verify round) per step: split
+        its window evenly across the slots that decoded in it (capacity)
+        and charge the replica's decode residency (anatomy). Dead
+        branches while both are off. Returns the anatomy's delta."""
+        if capacity._ENABLED:
+            capacity.split_device_seconds(
+                [r.tenant for s, r in enumerate(self._in_slot)
+                 if r is not None and self._active[s]],
+                self.capacity_model, "decode", w1 - w0)
+        return anatomy.on_decode_step(self, w0, w1)
 
     def _extend_spec_pages(self, slot, req, k):
         """Draw down `req`'s reservation so every position the verify
@@ -663,7 +726,7 @@ class Scheduler:
             req.spec_reserved += len(extra)
             self.slots.set_slot_pages(slot, req.pages)
 
-    def _spec_decode_once(self):
+    def _spec_decode_once(self, clock):
         """One speculative round: map pages for the verify window, draft
         ``k`` tokens (host n-gram or one draft-model program), verify
         all ``k + 1`` rows in ONE batched target program, then accept
@@ -671,40 +734,40 @@ class Scheduler:
         token is the target model's own greedy choice, so output is
         token-for-token identical to non-speculative greedy decode."""
         k = self._spec_k
-        cap_t0 = time.perf_counter() \
-            if (capacity._ENABLED or anatomy._ENABLED) else None
+        # the round's window from the step clock's stamps: draft, then
+        # verify; both are the step's decode work (host drafting and page
+        # mapping count as launch, the engine stamps its read-backs)
+        w0 = clock.cursor
         limit = onp.zeros(self.slots.max_slots, onp.int32)
         for s, req in enumerate(self._in_slot):
             if req is not None and self._active[s]:
                 limit[s] = self._extend_spec_pages(s, req, k)
-        with tracing.span("serve.spec_draft", slots=self._n_decoding, k=k):
-            if self.slots.draft_kind == "model":
-                drafts = self.slots.spec_draft_step(
-                    self._last, self._pos, self._active, limit)
-            else:
-                seqs = [None] * self.slots.max_slots
-                for s, req in enumerate(self._in_slot):
-                    if req is not None and self._active[s]:
-                        seqs[s] = onp.concatenate(
-                            [req.prompt,
-                             onp.asarray(req.tokens, onp.int32)])
-                drafts = self.slots.spec_propose(seqs)
-        with tracing.span("serve.spec_verify", slots=self._n_decoding,
-                          k=k):
-            targets = self.slots.spec_verify_step(
-                self._last, drafts, self._pos, self._active, limit)
-        spec_dt = 0.0
-        if cap_t0 is not None:
-            # draft + verify are the step's decode work: split across
-            # the slots that participated in the round
-            if capacity._ENABLED:
-                capacity.split_device_seconds(
-                    [r.tenant for s, r in enumerate(self._in_slot)
-                     if r is not None and self._active[s]],
-                    self.capacity_model, "decode",
-                    time.perf_counter() - cap_t0)
-            spec_dt = anatomy.on_decode_step(self, cap_t0,
-                                             time.perf_counter())
+        if self.slots.draft_kind == "model":
+            drafts = self.slots.spec_draft_step(
+                self._last, self._pos, self._active, limit)
+        else:
+            seqs = [None] * self.slots.max_slots
+            for s, req in enumerate(self._in_slot):
+                if req is not None and self._active[s]:
+                    seqs[s] = onp.concatenate(
+                        [req.prompt, onp.asarray(req.tokens, onp.int32)])
+            drafts = self.slots.spec_propose(seqs)
+        w_mid = clock.lap("decode_launch")
+        tracing.record_span("serve.spec_draft", w0, w_mid,
+                            slots=self._n_decoding, k=k)
+        targets = self.slots.spec_verify_step(
+            self._last, drafts, self._pos, self._active, limit)
+        w1 = clock.lap("decode_readback")
+        tracing.record_span("serve.spec_verify", w_mid, w1,
+                            slots=self._n_decoding, k=k)
+        spec_dt = self._charge_decode(w0, w1)
+        with tracing.phase("mx.serve.emit", "emit"):
+            self._spec_accept(k, drafts, targets, limit, spec_dt)
+        return True
+
+    def _spec_accept(self, k, drafts, targets, limit, spec_dt):
+        """Accept the longest matching drafted prefix per slot, emit,
+        retire or roll the lookahead pages back."""
         n_round = max(self._n_decoding, 1)
         now = time.monotonic()
         for s, req in enumerate(self._in_slot):
@@ -752,7 +815,6 @@ class Scheduler:
                 self._retire(s, done_reason, now)
             else:
                 self._trim_spec_pages(s, req)
-        return True
 
     def _retire(self, slot, reason, now):
         """Free `slot` and dereference its pages (shared pages survive

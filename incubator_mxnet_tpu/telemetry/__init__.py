@@ -14,8 +14,9 @@ Six connected parts:
   context, per-thread rings) threaded through serve requests, estimator
   steps, dataloader fetches, kvstore syncs, and checkpoint I/O; flight
   recorder dumping the last spans on crash/injected fault; Chrome-trace
-  export sharing the profiler's clock base (same off-path dead-branch
-  discipline as `stages`);
+  export in epoch µs beside the profiler's rebased lanes (same off-path
+  dead-branch discipline as `stages`); the always-on phase clock of the
+  serving loop (`mx.serve.*` profiler spans, step and request records);
 - `slo`       — declarative objectives over registry series with
   error-budget burn as ``mx_slo_*`` gauges and a loud `monitor.check()`
   hook;

@@ -211,6 +211,6 @@ def ledger_report():
 
 
 # arm with the rest of the telemetry plane (cheap counter incs at the
-# serving seams — the <3% disarmed gate measures the flag checks)
+# serving seams — disarmed, each charge is one flag check)
 if os.environ.get("MXNET_TELEMETRY", "0") not in ("0", ""):
     _ENABLED = True

@@ -11,16 +11,24 @@ decode segments instead of being one opaque number.
 
 Design contract (same discipline as `stages.py`):
 
-- **off** (`MXNET_TELEMETRY` unset, the default): every probe —
-  ``span()``, ``open_span()``, ``event()``, ``annotate()`` — is one
-  module-global ``_ENABLED`` check returning a shared no-op singleton.
-  No allocation, no clock read, no lock. The measured off-path cost is
-  <3% of one funnel op (`tests/test_tracing.py`).
-- **on** (`enable()` or any truthy ``MXNET_TELEMETRY``): spans record
-  ``perf_counter_ns`` durations and an epoch-µs start timestamp — the
-  SAME clock base `profiler.py` rebases the XLA device trace onto, so
-  host spans and device slices merge into one Chrome-trace/Perfetto
-  timeline (`chrome_events()` / `tools/trace_timeline.py`).
+- **off** (`MXNET_TELEMETRY` unset, the default): every span probe —
+  ``span()``, ``open_span()``, ``record_span()``, ``event()``,
+  ``annotate()`` — is one module-global ``_ENABLED`` check returning a
+  shared no-op singleton. No allocation, no clock read, no lock
+  (`tests/test_tracing.py` counts the clock reads: none).
+- **on** (`enable()` or any truthy ``MXNET_TELEMETRY``): a span reads
+  ``perf_counter_ns`` once at each end (or takes a stamp its caller
+  already read) and opens a `jax.profiler.TraceAnnotation` of its own
+  name, so an armed run's spans are written into a live profiler
+  session's own trace, on the device's clock. Its epoch-µs start, for
+  the Chrome export, is derived from the same reading.
+- **always on — the phase clock** (`StepClock`, `phase()`):
+  the serving loop's boundaries are stamped once each with
+  ``time.perf_counter()``; every boundary is also a `TraceAnnotation`
+  (``mx.serve.*``, a no-op unless a profiler session is live), and each
+  iteration that made progress and each retired request leaves one
+  record in a bounded ring (`step_records()`, `request_records()`) that
+  outlives the engine that wrote it.
 - **host-side only**: spans are never created inside jitted bodies
   (lint FL008) and never captured by a trace — the serving engine's
   zero-steady-state-recompile guarantee is untouched.
@@ -62,7 +70,10 @@ from collections import deque
 from .locks import tracked_lock
 
 __all__ = ["Span", "Tracer", "enable", "disable", "is_enabled", "span",
-           "open_span", "event", "annotate", "current_span",
+           "open_span", "record_span", "event", "annotate", "current_span",
+           "StepClock", "phase", "stamp",
+           "add_request_record", "step_records", "request_records",
+           "PHASES", "STEP_RING_CAPACITY", "REQUEST_RING_CAPACITY",
            "current_trace_id", "new_trace_id", "finished_spans",
            "open_spans", "reset", "chrome_events", "chrome_trace",
            "dump_chrome", "flight_dump", "maybe_flight_dump",
@@ -70,6 +81,11 @@ __all__ = ["Span", "Tracer", "enable", "disable", "is_enabled", "span",
 
 RING_CAPACITY = 4096          # finished spans kept per writer thread
 _FLIGHT_SPANS = 256           # most-recent spans a flight dump carries
+STEP_RING_CAPACITY = 8192     # step records kept (19 min at 7 steps/s)
+REQUEST_RING_CAPACITY = 4096  # request records kept
+# the phases of one serving iteration, as a step record names them
+PHASES = ("lock_wait", "admit", "prefill_launch", "prefill_readback",
+          "decode_launch", "decode_readback", "emit")
 
 _ENABLED = False
 _LOCK = tracked_lock("telemetry.tracing", kind="lock")
@@ -79,6 +95,29 @@ _ORPHAN_EVENTS: deque = deque(maxlen=512)   # events with no current span
 _TLS = threading.local()
 _IDS = random.Random()        # span/trace id entropy (host-side only)
 _PREV_EXCEPTHOOK = None
+# epoch µs at perf_counter 0: a span's Chrome timestamp is derived from
+# its one perf_counter reading (host-device alignment does not rest on
+# it: an armed span is also a TraceAnnotation in the profiler's trace)
+_EPOCH_US = time.time() * 1e6 - time.perf_counter() * 1e6
+_STEPS: deque = deque(maxlen=STEP_RING_CAPACITY)
+_REQUESTS: deque = deque(maxlen=REQUEST_RING_CAPACITY)
+_NOTE = None                  # jax.profiler.TraceAnnotation, on first use
+
+
+def _now_us():
+    """Epoch µs on the spans' clock (perf_counter plus the fixed offset)."""
+    return time.perf_counter() * 1e6 + _EPOCH_US
+
+
+def _note(name):
+    """A `jax.profiler.TraceAnnotation`: a host span written into the
+    profiler's own trace while a session is live, a no-op otherwise."""
+    global _NOTE
+    if _NOTE is None:
+        import jax
+
+        _NOTE = jax.profiler.TraceAnnotation
+    return _NOTE(name)
 
 
 def new_trace_id():
@@ -111,7 +150,7 @@ class _NullSpan:
     def event(self, name, **attrs):  # noqa: ARG002
         return self
 
-    def close(self, error=None):  # noqa: ARG002
+    def close(self, error=None, t_end=None):  # noqa: ARG002
         return self
 
     def __bool__(self):
@@ -128,21 +167,30 @@ class Span:
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "attrs",
                  "events", "t0_us", "t0_ns", "dur_ns", "thread", "lane",
-                 "_ambient")
+                 "_ambient", "_note")
 
-    def __init__(self, name, trace_id, parent_id, attrs, lane, ambient):
+    def __init__(self, name, trace_id, parent_id, attrs, lane, ambient,
+                 t0=None):
         self.name = name
         self.trace_id = trace_id
         self.span_id = _new_span_id()
         self.parent_id = parent_id
         self.attrs = attrs
         self.events: list = []
-        self.t0_us = time.time() * 1e6       # epoch µs: profiler clock base
-        self.t0_ns = time.perf_counter_ns()  # monotonic: duration source
+        # `t0`: a time.perf_counter() stamp the caller already read
+        self.t0_ns = time.perf_counter_ns() if t0 is None else int(t0 * 1e9)
+        self.t0_us = self.t0_ns / 1e3 + _EPOCH_US   # epoch µs, derived
         self.dur_ns = None
         self.thread = threading.current_thread().name
         self.lane = lane
         self._ambient = ambient
+        # the same span in a live profiler session's own trace (a stamped
+        # span starts in the past: its boundaries' `mx.*` annotations are
+        # there already)
+        self._note = None
+        if t0 is None:
+            self._note = _note(name)
+            self._note.__enter__()
         with _LOCK:
             _OPEN[self.span_id] = self
 
@@ -177,15 +225,21 @@ class Span:
 
     def event(self, name, **attrs):
         """Point-in-time marker inside this span (Chrome 'instant')."""
-        self.events.append((name, time.time() * 1e6, attrs))
+        self.events.append((name, _now_us(), attrs))
         return self
 
-    def close(self, error=None):
-        """Stamp the duration and move the span to the finished ring.
-        Idempotent (a double close keeps the first duration)."""
+    def close(self, error=None, t_end=None):
+        """Stamp the duration (from `t_end`, a time.perf_counter() stamp
+        the caller already read, else from the clock) and move the span
+        to the finished ring. Idempotent (a double close keeps the first
+        duration)."""
         if self.dur_ns is not None:
             return self
-        self.dur_ns = time.perf_counter_ns() - self.t0_ns
+        self.dur_ns = (time.perf_counter_ns() if t_end is None
+                       else int(t_end * 1e9)) - self.t0_ns
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
+            self._note = None
         if error is not None:
             self.attrs.setdefault("error", type(error).__name__)
             self.attrs.setdefault("error_msg", str(error)[:200])
@@ -220,7 +274,7 @@ class Span:
 # probes (module surface — every call a dead branch while off)
 # ---------------------------------------------------------------------------
 
-def span(name, parent=None, trace_id=None, lane=None, **attrs):
+def span(name, parent=None, trace_id=None, lane=None, t0=None, **attrs):
     """Open an ambient span as a context manager::
 
         with tracing.span("estimator.step", step=i):
@@ -228,11 +282,24 @@ def span(name, parent=None, trace_id=None, lane=None, **attrs):
 
     Nested calls parent automatically (thread-local stack). `parent`
     (a Span) or `trace_id` override the ambient parent — that is how
-    work done on another thread joins a request's trace. Returns the
-    shared no-op span while tracing is off."""
+    work done on another thread joins a request's trace. `t0` is a
+    ``time.perf_counter()`` stamp the caller already read (the span then
+    reads no clock to start). Returns the shared no-op span while
+    tracing is off."""
     if not _ENABLED:
         return _NULL_SPAN
-    return _make_span(name, parent, trace_id, lane, attrs, ambient=True)
+    return _make_span(name, parent, trace_id, lane, attrs, ambient=True,
+                      t0=t0)
+
+
+def record_span(name, t0, t1, **attrs):
+    """A finished span from two ``time.perf_counter()`` stamps the caller
+    already read: parented like `span()`, reads no clock. The shared
+    no-op span while tracing is off."""
+    if not _ENABLED:
+        return _NULL_SPAN
+    return _make_span(name, None, None, None, attrs, ambient=False,
+                      t0=t0).close(t_end=t1)
 
 
 def open_span(name, parent=None, trace_id=None, lane=None, **attrs):
@@ -245,7 +312,7 @@ def open_span(name, parent=None, trace_id=None, lane=None, **attrs):
     return _make_span(name, parent, trace_id, lane, attrs, ambient=False)
 
 
-def _make_span(name, parent, trace_id, lane, attrs, ambient):
+def _make_span(name, parent, trace_id, lane, attrs, ambient, t0=None):
     if parent is None and trace_id is None:
         stack = getattr(_TLS, "stack", None)
         if stack:
@@ -259,7 +326,7 @@ def _make_span(name, parent, trace_id, lane, attrs, ambient):
         parent_id = None
         if trace_id is None:
             trace_id = new_trace_id()
-    return Span(name, trace_id, parent_id, attrs, lane, ambient)
+    return Span(name, trace_id, parent_id, attrs, lane, ambient, t0)
 
 
 def event(name, **attrs):
@@ -271,7 +338,7 @@ def event(name, **attrs):
     if stack:
         stack[-1].event(name, **attrs)
     else:
-        _ORPHAN_EVENTS.append((name, time.time() * 1e6, attrs))
+        _ORPHAN_EVENTS.append((name, _now_us(), attrs))
 
 
 def annotate(**attrs):
@@ -315,6 +382,160 @@ class Tracer:
 
 
 # ---------------------------------------------------------------------------
+# the phase clock: boundaries stamped once, always on
+# ---------------------------------------------------------------------------
+
+class StepClock:
+    """The stamps of one iteration of the serving loop::
+
+        with tracing.StepClock(waited_since, queued=n) as clock:
+
+    A boundary is one ``time.perf_counter()`` reading, taken when a phase
+    ENDS (`lap`): the phase is charged the time since the boundary
+    before it (`cursor`), so consecutive phases share their stamp and
+    nothing between them is clocked twice. The iteration's start and end
+    are two more readings; what follows the last `lap` is in ``wall`` and
+    in no phase (`step_records` readers watch that share). `waited_since`
+    is the stamp at which the caller began to wait for the lock it now
+    holds (charged to ``lock_wait``, outside ``wall``). The armed
+    ``serve.step`` span (`attrs` are its attributes) takes its start and
+    end from the same two readings. While the clock is open it is the
+    calling thread's current one: `phase()` / `stamp()` beneath find it.
+    An iteration that set ``progressed`` leaves one step record."""
+
+    __slots__ = ("t_start", "t_end", "cursor", "seconds", "counts",
+                 "progressed", "span", "_open", "_note", "_prev")
+
+    def __init__(self, waited_since=None, **attrs):
+        self._open = (waited_since, attrs)
+        self.counts = {}
+        self.progressed = False
+        self.seconds = dict.fromkeys(PHASES, 0.0)
+        self.t_end = None
+
+    def __enter__(self):
+        waited_since, attrs = self._open
+        self._prev = getattr(_TLS, "clock", None)
+        _TLS.clock = self
+        self._note = _note("mx.serve.step")
+        self._note.__enter__()
+        self.t_start = self.cursor = time.perf_counter()
+        if waited_since is not None:
+            self.seconds["lock_wait"] = self.t_start - waited_since
+        self.span = span("serve.step", t0=self.t_start, **attrs)
+        self.span.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.t_end = time.perf_counter()
+        self.span.close(error=exc, t_end=self.t_end)   # the stamp, not a read
+        self.span.__exit__(exc_type, exc, tb)
+        self._note.__exit__(None, None, None)
+        _TLS.clock = self._prev
+        if self.progressed and exc_type is None:
+            rec = {"t_start": self.t_start,
+                   "wall": self.t_end - self.t_start}
+            rec.update(self.seconds)
+            rec.update(self.counts)
+            with _LOCK:
+                _STEPS.append(rec)
+        return False
+
+    def lap(self, field):
+        """A boundary: charge `field` the time since the last one.
+        Returns the stamp."""
+        now = time.perf_counter()
+        self.seconds[field] += now - self.cursor
+        self.cursor = now
+        return now
+
+
+class phase:
+    """One boundary of the loop, always on::
+
+        with tracing.phase("mx.serve.decode.launch", "decode_launch"):
+
+    `name` is a `TraceAnnotation` from enter to exit (the span in the
+    profiler's trace); at exit `field` is charged on the thread's open
+    `StepClock` (none open, or no field: no reading). `timed`: stamp both
+    ends itself and keep ``seconds`` (a phase outside any step, like the
+    driver's back-off sleep)."""
+
+    __slots__ = ("_field", "_note", "_t0", "seconds")
+
+    def __init__(self, name, field=None, timed=False):
+        self._field = field
+        self._note = _note(name)
+        self._t0 = timed or None          # None: untimed; else the start
+        self.seconds = None
+
+    def __enter__(self):
+        self._note.__enter__()
+        if self._t0 is not None:
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._field is not None:
+            clock = getattr(_TLS, "clock", None)
+            if clock is not None:
+                clock.lap(self._field)
+        if self._t0 is not None:
+            self.seconds = time.perf_counter() - self._t0
+        self._note.__exit__(None, None, None)
+        return False
+
+
+def stamp():
+    """The last boundary's stamp on the thread's open clock (no reading);
+    with no clock open, the time."""
+    clock = getattr(_TLS, "clock", None)
+    return time.perf_counter() if clock is None else clock.cursor
+
+
+def add_request_record(**rec):
+    """One retired request (``time.perf_counter()`` stamps and counts,
+    see `request_records`); ``t_finish`` is the thread's last boundary."""
+    rec["t_finish"] = stamp()
+    with _LOCK:
+        _REQUESTS.append(rec)
+
+
+def _window(ring, key, since, until):
+    with _LOCK:
+        out = list(ring)
+    return [dict(r) for r in out
+            if (since is None or r[key] >= since)
+            and (until is None or r[key] < until)]
+
+
+def step_records(since=None, until=None):
+    """Copies of the step records whose ``t_start`` lies in ``[since,
+    until)`` (``time.perf_counter()`` seconds; None: unbounded), oldest
+    first. One record per iteration that made progress: ``t_start``,
+    seconds of each of `PHASES` and of ``wall`` (start to end, lock held;
+    ``lock_wait`` lies before it), counts ``chunks``, ``decoding`` (active
+    slots in the decode launch), ``prefilling`` and ``queued`` (at the
+    iteration's end). The ring is the module's, not the engine's: it
+    outlives shutdown and deletion of whatever wrote it, holds the newest
+    `STEP_RING_CAPACITY` records and drops the oldest."""
+    return _window(_STEPS, "t_start", since, until)
+
+
+def request_records(since=None, until=None):
+    """Copies of the request records whose ``t_submit_call`` lies in
+    ``[since, until)``, oldest first (by retirement). One record per
+    request that left the engine: ``id`` (the ``request=`` attribute of
+    its spans), ``trace_id`` (None unless armed), stamps ``t_submit_call``
+    (entry of `ServeEngine.submit`), ``t_enqueued`` (lock held, queued),
+    ``t_admit`` (start of the iteration that gave it a slot) and
+    ``t_first_token`` (None where it never got there), ``t_finish``,
+    ``chunks``, ``shared_tokens``, ``tokens``, ``state``. Newest
+    `REQUEST_RING_CAPACITY` kept, oldest dropped; outlives the engine."""
+    return _window(_REQUESTS, "t_submit_call", since, until)
+
+
+# ---------------------------------------------------------------------------
 # lifecycle
 # ---------------------------------------------------------------------------
 
@@ -346,10 +567,12 @@ def is_enabled():
 
 
 def reset():
-    """Drop every recorded span/event (tests)."""
+    """Drop every recorded span, event, step and request record (tests)."""
     with _LOCK:
         rings = list(_RINGS)
         _OPEN.clear()
+        _STEPS.clear()
+        _REQUESTS.clear()
     for r in rings:
         r.clear()
     _ORPHAN_EVENTS.clear()
@@ -377,7 +600,8 @@ def open_spans():
 
 
 # ---------------------------------------------------------------------------
-# Chrome-trace / Perfetto export (shared clock base with profiler.py)
+# Chrome-trace / Perfetto export (epoch µs; profiler.py rebases its device
+# lanes onto the same epoch by an anchor: TELEMETRY.md has its error)
 # ---------------------------------------------------------------------------
 
 _SPAN_PID = 2                 # host op dispatch owns pid 0, device 1000+
@@ -389,9 +613,11 @@ def chrome_events(spans=None):
     Lanes: spans carrying a ``lane`` (e.g. serve requests get
     ``"req <id>"``) each get their own tid with a thread_name metadata
     row — one horizontal lane per request in Perfetto; unlaned spans
-    share a lane per OS thread. Timestamps are epoch-µs (``time.time``),
-    the same base `profiler._ingest_device_trace` rebases XLA device
-    events onto — so the two sources line up in one timeline."""
+    share a lane per OS thread. Timestamps are epoch-µs derived from
+    each span's perf_counter reading; `profiler._ingest_device_trace`
+    rebases XLA device events onto an epoch anchor of its own (its error
+    is in TELEMETRY.md; an armed span is also in the profiler's trace
+    itself, where nothing has to be rebased)."""
     if spans is None:
         spans = finished_spans()
     lanes: dict = {}
@@ -428,8 +654,8 @@ def chrome_events(spans=None):
 def chrome_trace(include_device=True, spans=None):
     """One Chrome-trace payload: host spans (+ their instant events)
     merged with the XLA device lanes `profiler.py` captured on the last
-    `profiler.stop()`. Both sides share the epoch-µs clock base, so
-    request spans sit directly above the device slices they caused."""
+    `profiler.stop()`, both in epoch µs (the device side through the
+    profiler's anchor, see TELEMETRY.md for its measured error)."""
     events = chrome_events(spans)
     if include_device:
         from .. import profiler

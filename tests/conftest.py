@@ -181,3 +181,37 @@ def seed_rng():
     onp.random.seed(0)
     mx.random.seed(0)
     yield
+
+
+class _CountingTime:
+    """`time`, counting the clock reads made through it."""
+
+    CLOCKS = ("perf_counter", "perf_counter_ns", "monotonic", "time")
+
+    def __init__(self):
+        self.reads = 0
+
+    def __getattr__(self, name):
+        import time
+
+        fn = getattr(time, name)
+        if name not in self.CLOCKS:
+            return fn
+
+        def counted(*a):
+            self.reads += 1
+            return fn(*a)
+        return counted
+
+
+@pytest.fixture
+def count_clock_reads(monkeypatch):
+    """``clock = count_clock_reads(module)`` swaps the module's ``time`` for
+    one that counts its clock reads (``clock.reads``) until the test ends:
+    the structural form of "the off path is cheap" (a CPU run gives counts,
+    not speeds)."""
+    def swap(module):
+        clock = _CountingTime()
+        monkeypatch.setattr(module, "time", clock)
+        return clock
+    return swap

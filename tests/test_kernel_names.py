@@ -1,0 +1,75 @@
+"""Every pallas kernel of the main path carries a stable name, so that a
+profiler trace names it after a refactor (`mx_*` in the ``XLA Ops`` lane)
+and a per-kernel metric can find it. Lowered for the TPU from shapes, on
+the CPU: nothing compiles for a chip and nothing runs."""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from incubator_mxnet_tpu.ops import dropout as dropout_k
+from incubator_mxnet_tpu.ops import fused_block, layer_norm
+from incubator_mxnet_tpu.ops.flash_attention import flash_attention
+
+ROWS, FEAT = 256, 256
+X = jax.ShapeDtypeStruct((ROWS, FEAT), jnp.float32)
+G = jax.ShapeDtypeStruct((FEAT,), jnp.float32)
+S = jax.ShapeDtypeStruct((2,), jnp.int32)
+QKV = jax.ShapeDtypeStruct((2, 2, 256, 64), jnp.float32)
+
+# op, its differentiable arguments, the rest
+OPS = {
+    "ln": (lambda x, g, b: layer_norm.layer_norm(x, g, b, interpret=False),
+           (X, G, G), ()),
+    "rdln": (lambda x, h, g, b, s: fused_block.residual_dropout_ln(
+        x, h, g, b, 0.1, s, interpret=False), (X, X, G, G), (S,)),
+    "gelu_dropout": (lambda x, s: fused_block.gelu_dropout(
+        x, 0.1, s, interpret=False), (X,), (S,)),
+    "dropout": (lambda x, s: dropout_k._dropout_core(x, s, 0.1, False),
+                (X,), (S,)),
+    "flash": (lambda q, k, v: flash_attention(
+        q, k, v, causal=True, impl="pallas", interpret=False),
+        (QKV, QKV, QKV), ()),
+}
+# kernel name -> the op whose forward-and-backward program holds it
+KERNELS = {
+    "mx_ln_fwd": "ln", "mx_ln_bwd": "ln",
+    "mx_rdln_fwd": "rdln", "mx_rdln_bwd": "rdln",
+    "mx_gelu_dropout": "gelu_dropout", "mx_dropout": "dropout",
+    "mx_flash_fwd": "flash", "mx_flash_dq": "flash", "mx_flash_dkv": "flash",
+}
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    """The TPU lowering of each op's gradient program, as text."""
+    out = {}
+    for op, (fn, diff, rest) in OPS.items():
+        grad = jax.grad(lambda *a, _fn=fn: _fn(*a).astype(jnp.float32).sum(),
+                        argnums=tuple(range(len(diff))))
+        out[op] = jax.jit(grad).trace(*diff, *rest).lower(
+            lowering_platforms=("tpu",)).as_text()
+    return out
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_pallas_kernel_is_named_in_its_lowering(lowered, kernel):
+    text = lowered[KERNELS[kernel]]
+    assert "tpu_custom_call" in text
+    assert kernel in text
+
+
+def test_every_pallas_call_of_the_main_path_is_named():
+    """Nine calls, nine names: a call added without one shows here."""
+    import inspect
+    import re
+    import sys
+
+    flash_mod = sys.modules[flash_attention.__module__]
+    names = []
+    for mod in (dropout_k, flash_mod, fused_block, layer_norm):
+        src = inspect.getsource(mod)
+        calls = len(re.findall(r"pl\.pallas_call\(", src))
+        found = re.findall(r'name="(mx_[a-z_]+)"', src)
+        assert calls == len(found), mod.__name__
+        names += found
+    assert sorted(names) == sorted(KERNELS)

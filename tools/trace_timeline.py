@@ -2,10 +2,11 @@
 trace into ONE Chrome-trace JSON (open at https://ui.perfetto.dev →
 "Open trace file", or chrome://tracing).
 
-The two sources already share a clock base: `telemetry.tracing` stamps
-spans with epoch-µs (`time.time()`), and `profiler._ingest_device_trace`
-rebases the XPlane device events onto the same epoch clock — so a serve
-request's prefill span sits directly above the device slices it caused.
+Both sources are in epoch µs: `telemetry.tracing` derives a span's start
+from its `perf_counter` reading, and `profiler._ingest_device_trace`
+rebases the XPlane device events by an epoch anchor (0.1-0.2 ms off on a
+v5e, TELEMETRY.md) — so a serve request's prefill span sits above the
+device slices it caused, to within that.
 Lanes: pid 0 host op dispatch (when the profiler recorded it), pid 2
 host spans (one lane per request via the ``lane`` attr, one per thread
 otherwise), pid 1000+ the XLA device/runtime lanes.
