@@ -8,8 +8,12 @@ vLLM/PagedAttention block-allocation idea re-expressed TPU-natively
 (static shapes, gather-by-page-table, zero steady-state recompiles):
 
 - **page pool** — one persistent device array per K and V **per
-  layer**: a tuple of L arrays of shape ``(n_pages, H, page_tokens,
-  d)``. Page 0 is a reserved *trash* page: unallocated page-table
+  layer**: a tuple of L arrays of ``(n_pages, H, page_tokens, d)``
+  values (a float leaf keeps a head's ``(page_tokens, d)`` plane packed
+  to 128 lanes, ``(page_tokens * d // 128, 128)``, where the head is
+  narrower: no padding, and a page is one contiguous block of the leaf —
+  `ops.paged_attention`, "a page as it is stored"). Page 0 is a
+  reserved *trash* page: unallocated page-table
   entries and inactive-slot writes land there, and its contents are
   never attended (the validity mask excludes them before softmax).
   The per-layer split is load-bearing for cost, not cosmetics: with a
@@ -26,9 +30,11 @@ vLLM/PagedAttention block-allocation idea re-expressed TPU-natively
   without resharding a fused 5-D array.
 - **page table** — a host-side ``(max_slots, pages_per_slot)`` int32
   array mapping each slot's token range to pool pages (mirrored to the
-  device lazily, refreshed only when allocation changes). Decode gathers
-  a slot's logical KV view with a static-shape ``jnp.take`` over the
-  table row; prefill writes whole pages with a static-shape scatter.
+  device lazily, refreshed only when allocation changes). Decode's
+  attention (`ops.paged_attention.paged_decode_attention`) goes through
+  it to the pages under each slot's ``pos`` and to no others; prefill
+  writes whole pages with a static-shape scatter and gathers ONE slot's
+  logical view with a static-shape ``jnp.take`` over its table row.
 - **allocator + prefix cache** — `PageAllocator` (host-only free list +
   refcounts; OOM raises the loud `PagePoolExhausted`, nothing is ever
   silently evicted while referenced) and `PrefixCache` (hash of the
@@ -52,10 +58,20 @@ Two compiled program families in the base configuration:
   chunk). Splitting long prompts into chunks lets the scheduler
   interleave decode steps between chunks, so a long-prompt arrival no
   longer stalls every running request for a whole monolithic prefill.
-- **decode** (ONE program): one token for ALL slots — per-slot scatter
+- **decode** (ONE program): one token for ALL slots — per-slot write
   of the new K/V at ``page_table[s, pos//page_tokens]`` (inactive slots
-  are redirected to the trash page), gather of each slot's view, masked
-  attention, per-slot sampling.
+  are redirected to the trash page), attention over each decoding
+  slot's live pages, per-slot sampling. The attention is one op with two
+  implementations, chosen from what the process observes and counted in
+  ``mx_kernel_dispatch_total{op="paged_decode_attention",impl=}``: on one
+  TPU device with float pools the pallas kernel ``mx_paged_decode``,
+  which reads the pages below ``pos`` straight from the layer's pool
+  leaf and never builds the ``(S, H, max_len, d)`` view; on the CPU,
+  under a multi-device mesh and for int8 pools the XLA expression —
+  gather every slot's whole view through the table, mask, softmax, two
+  einsums.
+  ``mx_serve_decode_pages_total{kind="live"|"view"}`` says what share of
+  that view a step's attention covers.
 
 With **speculative decoding** armed (``spec_k > 0``), decode is
 replaced by two more families that advance up to ``k + 1`` tokens per
@@ -97,6 +113,7 @@ and generation headroom are all dead by construction.
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import math
 import os
@@ -126,6 +143,15 @@ PAD_TOKENS = registry.counter(
     "mx_decode_bucket_pad_tokens_total",
     "prompt tokens added by pad-to-bucket in the decode/serving "
     "path (padding waste)")
+
+
+_PAGES_HELP = ("KV pages a decode step's attention covers: `live`, the "
+               "pages under the decoding slots' positions (what the paged "
+               "kernel reads); `view`, max_slots x pages_per_slot (what a "
+               "gathered view of every slot holds)")
+DECODE_PAGES = {kind: registry.counter("mx_serve_decode_pages_total",
+                                       _PAGES_HELP, labels={"kind": kind})
+                for kind in ("live", "view")}
 
 
 def _j():
@@ -555,8 +581,9 @@ class SlotDecoder:
 
     def _make_pools(self, dec):
         """Per-layer page pools for `dec`: TUPLES of L device arrays of
-        shape ``(n_pages, H, page_tokens, d)`` (int8 adds per-layer
-        ``(n_pages, H)`` scale planes). Separate leaves — not one
+        ``(n_pages, H, page_tokens, d)`` values, a float leaf packed to
+        128 lanes (int8 adds per-layer ``(n_pages, H)`` scale planes).
+        Separate leaves — not one
         stacked 5-D array — so every compiled program's donation map
         aliases each layer's pool in place; see the module docstring
         for why the stacked layout forces an O(L × n_pages) rewrite."""
@@ -575,6 +602,11 @@ class SlotDecoder:
                        for _ in range(L))
             return pk, pv, sk, sv
         dtype = layers["qkv_w"].dtype
+        # float pages are stored packed to the TPU's 128 lanes
+        # (ops.paged_attention, "a page as it is stored")
+        from ..ops.paged_attention import page_store_shape
+
+        shape = (self.n_pages, H) + page_store_shape(self.page_tokens, d)
         pk = tuple(jnp.zeros(shape, dtype) for _ in range(L))
         pv = tuple(jnp.zeros(shape, dtype) for _ in range(L))
         return pk, pv, None, None
@@ -588,6 +620,12 @@ class SlotDecoder:
         every program entry point routes through here, so a weight swap
         lands without draining the engine."""
         self._dec._auto_refresh()
+
+    def _mesh_scope(self):
+        """The mesh the engine's programs are traced under, made visible
+        to the kernel sites' dispatch (`ops._dispatch.use_pallas`): none
+        for the one-device engine."""
+        return contextlib.nullcontext()
 
     def _constrain_pools(self, pk, pv, sk, sv):
         """Traced seam at the tail of every pool-updating program: the
@@ -772,7 +810,10 @@ class SlotDecoder:
         valued view ``(..., n_idx * page_tokens, d)`` (leading dims follow
         `idx`'s shape). fp pools gather straight through."""
         jnp = _j().numpy
+        from ..ops.paged_attention import unpack_pages
+
         v = jnp.take(pool_l, idx, axis=0)
+        v = unpack_pages(v, v.shape[-2] * v.shape[-1] // self.page_tokens)
         if self._int8:
             sc = jnp.take(scale_l, idx, axis=0)
             v = v.astype(jnp.float32) * sc[..., None, None]
@@ -793,6 +834,7 @@ class SlotDecoder:
 
         from ..contrib.quantization import quantize_symmetric
         from ..models.decoding import _dense, _ln, _split_qkv
+        from ..ops.paged_attention import pack_pages
 
         def to_pages(t):
             # (1, H, C, d) -> (C//pt pages, H, pt, d)
@@ -842,8 +884,10 @@ class SlotDecoder:
                     sk_l = sk_l.at[chunk_pages].set(ks[:, :, 0, 0])
                     sv_l = sv_l.at[chunk_pages].set(vs[:, :, 0, 0])
                 else:
-                    pk_l = pk_l.at[chunk_pages].set(kp.astype(pk_l.dtype))
-                    pv_l = pv_l.at[chunk_pages].set(vp.astype(pv_l.dtype))
+                    pk_l = pk_l.at[chunk_pages].set(
+                        pack_pages(kp.astype(pk_l.dtype)))
+                    pv_l = pv_l.at[chunk_pages].set(
+                        pack_pages(vp.astype(pv_l.dtype)))
                 # slot view: (P, H, pt, d) -> (1, H, P*pt, d)
                 vk = self._dequant_view(pk_l, sk_l, pages_row)
                 vv = self._dequant_view(pv_l, sv_l, pages_row)
@@ -1034,11 +1078,21 @@ class SlotDecoder:
         S = self.max_slots
 
         from ..contrib.quantization import quantize_symmetric
+        from ..ops.paged_attention import pack_pages, unpack_pages
 
         def write_token(pool_l, scale_l, wpage, woff, t):
             if not int8:
-                return pool_l.at[wpage, :, woff].set(
-                    t.astype(pool_l.dtype)), scale_l
+                # whole pages out, the token's row set, whole pages back:
+                # a page is one contiguous block of the leaf, so the
+                # update runs in place. (A scatter of (H, d) rows makes
+                # the TPU's compiler turn the whole leaf to a layout with
+                # H beside d, and back.)
+                page = unpack_pages(jnp.take(pool_l, wpage, axis=0),
+                                    t.shape[-1])               # (S,H,pt,d)
+                row = jnp.arange(page.shape[2])[None, None, :, None]
+                page = jnp.where(row == woff[:, None, None, None],
+                                 t.astype(pool_l.dtype)[:, :, None, :], page)
+                return pool_l.at[wpage].set(pack_pages(page)), scale_l
             old = jnp.take(scale_l, wpage, axis=0)             # (S, H)
             amax = jnp.max(jnp.abs(t), axis=-1)                # (S, H)
             new = jnp.maximum(old, jnp.maximum(amax, 1e-8) / 127.0)
@@ -1056,38 +1110,30 @@ class SlotDecoder:
         return write_token
 
     def _decode_layer_step(self, dec, lp, x, pools, table, wpage, woff,
-                           mask, write_token):
+                           lengths, write_token):
         """One layer of the single-token decode body — shared verbatim
         by the decode program and each unrolled step of the draft
         program so all three stay bit-identical. `pools` is the layer's
-        ``(pk_l, pv_l, sk_l, sv_l)``; returns updated ``(x, pools)``."""
+        ``(pk_l, pv_l, sk_l, sv_l)``; `lengths` is how many tokens of
+        each slot the new token attends (its own included; 0 for a slot
+        that does not decode); returns updated ``(x, pools)``."""
         jax = _j()
-        jnp = jax.numpy
         from ..models.decoding import _dense, _ln, _split_qkv
+        from ..ops.paged_attention import paged_decode_attention
 
         H = dec._n_heads
         d = dec._units // H
         S = self.max_slots
-        PT = table.shape[1] * self.page_tokens
         pk_l, pv_l, sk_l, sv_l = pools
         h = _ln(x, lp["ln1_g"], lp["ln1_b"])
         q, k, v = _split_qkv(_dense(h, lp["qkv_w"], lp["qkv_b"]), H)
+        # the new token's K/V is in the pool before attention reads it
         pk_l, sk_l = write_token(pk_l, sk_l, wpage, woff, k[:, :, 0])
         pv_l, sv_l = write_token(pv_l, sv_l, wpage, woff, v[:, :, 0])
-        # per-slot logical view via the page table: one gather,
-        # static index shape (S, P)
-        vk = self._dequant_view(pk_l, sk_l, table)
-        vv = self._dequant_view(pv_l, sv_l, table)
-        vk = jnp.transpose(vk, (0, 2, 1, 3, 4)).reshape(S, H, PT, d)
-        vv = jnp.transpose(vv, (0, 2, 1, 3, 4)).reshape(S, H, PT, d)
-        s = jnp.einsum("shqd,shkd->shqk", q, vk,
-                       preferred_element_type=jnp.float32)
-        s = s / math.sqrt(d)
-        s = jnp.where(mask[:, None, None, :], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1).astype(vv.dtype)
-        o = jnp.einsum("shqk,shkd->shqd", p, vv)
-        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(S, 1, H * d)
-        x = x + _dense(o, lp["proj_w"], lp["proj_b"])
+        with self._mesh_scope():
+            o = paged_decode_attention(q[:, :, 0], pk_l, pv_l, table,
+                                       lengths, k_scale=sk_l, v_scale=sv_l)
+        x = x + _dense(o.reshape(S, 1, H * d), lp["proj_w"], lp["proj_b"])
         h = _ln(x, lp["ln2_g"], lp["ln2_b"])
         ffn = _dense(
             jax.nn.gelu(_dense(h, lp["ffn1_w"], lp["ffn1_b"])),
@@ -1105,15 +1151,15 @@ class SlotDecoder:
 
         def run(params, pk, pv, sk, sv, table, last_tok, pos, active,
                 key, temperature, top_k, do_sample):
-            PT = table.shape[1] * pt
             x = (params["embed"][last_tok][:, None, :]
                  + params["pos"][pos][:, None, :])              # (S, 1, C)
             # each slot writes at its own page/offset; slots that are
             # free or still prefilling are redirected to the trash page
+            # and attend nothing
             wpage = table[jnp.arange(S), pos // pt]
             wpage = jnp.where(active, wpage, 0)
             woff = pos % pt
-            mask = jnp.arange(PT)[None, :] <= pos[:, None]
+            lengths = jnp.where(active, pos + 1, 0)
 
             # unrolled over layers — each pool leaf aliases its donated
             # input (see _make_pools)
@@ -1126,7 +1172,7 @@ class SlotDecoder:
                 x, (pk[li], pv[li], sk[li], sv[li]) = \
                     self._decode_layer_step(
                         dec, lp, x, (pk[li], pv[li], sk[li], sv[li]),
-                        table, wpage, woff, mask, write_token)
+                        table, wpage, woff, lengths, write_token)
             pk, pv = tuple(pk), tuple(pv)
             sk = tuple(sk) if int8 else None
             sv = tuple(sv) if int8 else None
@@ -1196,6 +1242,12 @@ class SlotDecoder:
                 self._pk, self._pv, nxt = self._decode_jit(
                     self._dec._params, self._pk, self._pv, *args,
                     top_k=self._top_k, do_sample=self._do_sample)
+            on = onp.asarray(active, bool)
+            live = int((onp.asarray(pos)[on] // self.page_tokens + 1).sum())
+            view = self.max_slots * self.pages_per_slot
+            DECODE_PAGES["live"].inc(live)
+            DECODE_PAGES["view"].inc(view)
+            tracing.count(pages_live=live, pages_view=view)
         with tracing.phase("mx.serve.decode.readback", "decode_readback"):
             return onp.asarray(nxt)       # blocks until the step ran
 
@@ -1325,7 +1377,6 @@ class SlotDecoder:
         def run(params, pk, pv, sk, sv, table, last_tok, pos, active,
                 limit):
             P = table.shape[1]
-            PT = P * pt
             pmax = params["pos"].shape[0]
             L = len(pk)
             pk, pv = list(pk), list(pv)
@@ -1338,7 +1389,7 @@ class SlotDecoder:
                 wpage = table[jnp.arange(S), jnp.clip(p_i // pt, 0, P - 1)]
                 wpage = jnp.where(active & (p_i <= limit), wpage, 0)
                 woff = p_i % pt
-                mask = jnp.arange(PT)[None, :] <= p_i[:, None]
+                lengths = jnp.where(active, p_i + 1, 0)
                 x = (params["embed"][cur][:, None, :]
                      + params["pos"][jnp.clip(p_i, 0, pmax - 1)][:, None, :])
                 for li in range(L):
@@ -1346,7 +1397,7 @@ class SlotDecoder:
                     x, (pk[li], pv[li], sk[li], sv[li]) = \
                         self._decode_layer_step(
                             dec, lp, x, (pk[li], pv[li], sk[li], sv[li]),
-                            table, wpage, woff, mask, write_token)
+                            table, wpage, woff, lengths, write_token)
                 logits = dec._logits(params, x[:, 0])
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 cur = jnp.where(active, nxt, cur)

@@ -467,7 +467,9 @@ class Scheduler:
             with tracing.phase("mx.serve.emit", "emit"):
                 self._refresh_gauges()
             clock.progressed = bool(admitted or chunks or decoded)
+            # the engine's decode launch counted its pages on the clock
             clock.counts = {
+                "pages_live": 0, "pages_view": 0, **clock.counts,
                 "chunks": chunks, "decoding": decoding if decoded else 0,
                 "prefilling": self._n_active - self._n_decoding,
                 "queued": len(self._queue)}

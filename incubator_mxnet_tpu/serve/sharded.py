@@ -355,6 +355,14 @@ class ShardedSlotDecoder(SlotDecoder):
     def _refresh_params(self):
         self._place_params()
 
+    def _mesh_scope(self):
+        """Kernel sites see the engine's mesh: under more than one device
+        they take their XLA expression (GSPMD cannot partition a Mosaic
+        kernel), on a one-device mesh the kernel, like the base engine."""
+        from ..parallel.mesh import mesh_scope
+
+        return mesh_scope(self.layout.mesh)
+
     def _make_pools(self, dec):
         pk, pv, sk, sv = super()._make_pools(dec)
         return self.layout.place_pools(pk, pv, sk, sv)

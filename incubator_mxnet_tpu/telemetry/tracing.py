@@ -72,7 +72,7 @@ from .locks import tracked_lock
 __all__ = ["Span", "Tracer", "enable", "disable", "is_enabled", "span",
            "open_span", "record_span", "event", "annotate", "current_span",
            "StepClock", "phase", "stamp",
-           "add_request_record", "step_records", "request_records",
+           "add_request_record", "count", "step_records", "request_records",
            "PHASES", "STEP_RING_CAPACITY", "REQUEST_RING_CAPACITY",
            "current_trace_id", "new_trace_id", "finished_spans",
            "open_spans", "reset", "chrome_events", "chrome_trace",
@@ -493,6 +493,15 @@ def stamp():
     return time.perf_counter() if clock is None else clock.cursor
 
 
+def count(**fields):
+    """Add to the counts of the thread's open step clock: they become
+    fields of its step record (no clock open: nothing)."""
+    clock = getattr(_TLS, "clock", None)
+    if clock is not None:
+        for k, v in fields.items():
+            clock.counts[k] = clock.counts.get(k, 0) + v
+
+
 def add_request_record(**rec):
     """One retired request (``time.perf_counter()`` stamps and counts,
     see `request_records`); ``t_finish`` is the thread's last boundary."""
@@ -516,7 +525,11 @@ def step_records(since=None, until=None):
     seconds of each of `PHASES` and of ``wall`` (start to end, lock held;
     ``lock_wait`` lies before it), counts ``chunks``, ``decoding`` (active
     slots in the decode launch), ``prefilling`` and ``queued`` (at the
-    iteration's end). The ring is the module's, not the engine's: it
+    iteration's end), ``pages_live`` and ``pages_view`` (of the decode
+    launch, else 0: KV pages under the decoding slots' positions, and
+    ``max_slots x pages_per_slot`` — what decode's attention reads,
+    against what a gathered view of every slot holds). The ring is the
+    module's, not the engine's: it
     outlives shutdown and deletion of whatever wrote it, holds the newest
     `STEP_RING_CAPACITY` records and drops the oldest."""
     return _window(_STEPS, "t_start", since, until)

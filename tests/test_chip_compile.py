@@ -16,7 +16,8 @@ import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
 
 from incubator_mxnet_tpu.ops import dropout as dropout_k  # noqa: E402
-from incubator_mxnet_tpu.ops import fused_block, layer_norm  # noqa: E402
+from incubator_mxnet_tpu.ops import (fused_block, layer_norm,  # noqa: E402
+                                     paged_attention)
 from incubator_mxnet_tpu.ops.flash_attention import (  # noqa: E402
     flash_attention)
 
@@ -119,3 +120,27 @@ def test_kernel_compiles_for_v5e(chip, fn, kinds, shape, dtypes, n_kernels):
         compiled = grad.lower(*[arg(k, dtype) for k in kinds]).compile()
         assert compiled.as_text().count(
             'custom_call_target="tpu_custom_call"') == n_kernels, dtype
+
+
+@pytest.mark.parametrize("d,heads", [(64, 25), (128, 16)],
+                         ids=["gpt2xl-25x64", "16x128"])
+def test_paged_decode_compiles_for_v5e(chip, d, heads):
+    """`mx_paged_decode` at mx.serve's GPT-2 XL sizes (8 slots x 64 pages
+    of 16 tokens, 25 heads of 64, stored two tokens to a row of 128 lanes)
+    and at a 128-wide head. The pools as the chip lays them out by itself:
+    nothing else of a pool's size may appear, since a re-layout of one
+    leaf is 50 MB."""
+    S, P, pt = 8, 64, 16
+
+    def arg(shape, dtype=F32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    pool = arg((S * P + 1, heads) + paged_attention.page_store_shape(pt, d))
+    compiled = jax.jit(
+        lambda q, k, v, t, n: paged_attention._pallas_paged_decode(
+            q, k, v, t, n, False)).lower(
+        arg((S, heads, d)), pool, pool, arg((S, P), jnp.int32),
+        arg((S,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "{3,2,1,0:T(8,128)}" in text.split("->")[0]     # pages major

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import pytest
 
 from incubator_mxnet_tpu.ops import dropout as dropout_k
-from incubator_mxnet_tpu.ops import fused_block, layer_norm
+from incubator_mxnet_tpu.ops import fused_block, layer_norm, paged_attention
 from incubator_mxnet_tpu.ops.flash_attention import flash_attention
 
 ROWS, FEAT = 256, 256
@@ -15,8 +15,14 @@ X = jax.ShapeDtypeStruct((ROWS, FEAT), jnp.float32)
 G = jax.ShapeDtypeStruct((FEAT,), jnp.float32)
 S = jax.ShapeDtypeStruct((2,), jnp.int32)
 QKV = jax.ShapeDtypeStruct((2, 2, 256, 64), jnp.float32)
+# serve's decode step: 4 slots of 8 pages, a pool of 33 pages of 16 tokens
+# (two heads of 64: stored two tokens to a row of 128 lanes)
+PAGED_Q = jax.ShapeDtypeStruct((4, 2, 64), jnp.float32)
+POOL = jax.ShapeDtypeStruct((33, 2, 8, 128), jnp.float32)
+TABLE = jax.ShapeDtypeStruct((4, 8), jnp.int32)
+LENGTHS = jax.ShapeDtypeStruct((4,), jnp.int32)
 
-# op, its differentiable arguments, the rest
+# op, its differentiable arguments (none: forward only), the rest
 OPS = {
     "ln": (lambda x, g, b: layer_norm.layer_norm(x, g, b, interpret=False),
            (X, G, G), ()),
@@ -29,6 +35,10 @@ OPS = {
     "flash": (lambda q, k, v: flash_attention(
         q, k, v, causal=True, impl="pallas", interpret=False),
         (QKV, QKV, QKV), ()),
+    "paged_decode": (
+        lambda q, k, v, t, n: paged_attention._pallas_paged_decode(
+            q, k, v, t, n, False),
+        (), (PAGED_Q, POOL, POOL, TABLE, LENGTHS)),
 }
 # kernel name -> the op whose forward-and-backward program holds it
 KERNELS = {
@@ -36,17 +46,21 @@ KERNELS = {
     "mx_rdln_fwd": "rdln", "mx_rdln_bwd": "rdln",
     "mx_gelu_dropout": "gelu_dropout", "mx_dropout": "dropout",
     "mx_flash_fwd": "flash", "mx_flash_dq": "flash", "mx_flash_dkv": "flash",
+    "mx_paged_decode": "paged_decode",
 }
 
 
 @pytest.fixture(scope="module")
 def lowered():
-    """The TPU lowering of each op's gradient program, as text."""
+    """The TPU lowering of each op's gradient program (of the op itself
+    where nothing is differentiated), as text."""
     out = {}
     for op, (fn, diff, rest) in OPS.items():
-        grad = jax.grad(lambda *a, _fn=fn: _fn(*a).astype(jnp.float32).sum(),
-                        argnums=tuple(range(len(diff))))
-        out[op] = jax.jit(grad).trace(*diff, *rest).lower(
+        if diff:
+            fn = jax.grad(
+                lambda *a, _fn=fn: _fn(*a).astype(jnp.float32).sum(),
+                argnums=tuple(range(len(diff))))
+        out[op] = jax.jit(fn).trace(*diff, *rest).lower(
             lowering_platforms=("tpu",)).as_text()
     return out
 
@@ -59,14 +73,15 @@ def test_pallas_kernel_is_named_in_its_lowering(lowered, kernel):
 
 
 def test_every_pallas_call_of_the_main_path_is_named():
-    """Nine calls, nine names: a call added without one shows here."""
+    """Ten calls, ten names: a call added without one shows here."""
     import inspect
     import re
     import sys
 
     flash_mod = sys.modules[flash_attention.__module__]
     names = []
-    for mod in (dropout_k, flash_mod, fused_block, layer_norm):
+    for mod in (dropout_k, flash_mod, fused_block, layer_norm,
+                paged_attention):
         src = inspect.getsource(mod)
         calls = len(re.findall(r"pl\.pallas_call\(", src))
         found = re.findall(r'name="(mx_[a-z_]+)"', src)

@@ -106,7 +106,8 @@ def test_every_progressed_step_leaves_one_record(served):
     assert len(recs) == served["progressed"] > 0
     assert [r["t_start"] for r in recs] == sorted(r["t_start"] for r in recs)
     assert set(recs[0]) == {"t_start", "wall", "chunks", "decoding",
-                            "prefilling", "queued", *tracing.PHASES}
+                            "prefilling", "queued", "pages_live",
+                            "pages_view", *tracing.PHASES}
 
 
 def test_phases_are_non_negative_and_add_up_to_at_most_wall(served):
