@@ -11,6 +11,12 @@ weights made from ``--seed`` and nothing downloaded:
   and 32 new tokens each, half of them behind a shared system prompt and
   arriving while the others decode, streamed to completion. One request is
   then checked against the Gluon block's own full forward on the same device.
+* **eva** — the EvaByte family (`serve/eva.py`) at its published widths
+  (4096 wide, 32 heads of 128, FFN 11,008, window 2,048, chunk 16) and two
+  layers deep, bfloat16: one request whose prefill crosses a window
+  boundary and one whose decode does (both kinds of page, the roll program,
+  the paged kernel at head size 128), each token checked against the plain
+  reference (`chipbench/reference/evabyte.py`).
 * **train** — ``models.bert.bert_base()`` through
   ``parallel.sharded.DataParallel(...).step`` under ``amp.init("bfloat16")``,
   dropout 0.1, at batch 32 x seq 512 and batch 64 x seq 128, 5 steps each on a
@@ -45,6 +51,22 @@ SERVE_SIZES = {
     True: dict(model="gpt_tiny", max_slots=4, max_len=128,
                n_requests=4, prompt_lo=20, prompt_hi=100, shared_prefix=16,
                new_tokens=6),
+}
+EVA_SIZES = {   # sizes under EvaByte's own keys; (prompt, new tokens) pairs
+    False: dict(cfg=dict(num_hidden_layers=2, hidden_size=4096,
+                         num_attention_heads=32, intermediate_size=11008,
+                         vocab_size=320, num_pred_heads=8, window_size=2048,
+                         chunk_size=16, rope_theta=100000, rms_norm_eps=1e-5,
+                         init_std=0.01275, max_position_embeddings=4096),
+                dtype="bfloat16", page_tokens=16, prefill_chunk=512,
+                requests=((2100, 8), (2040, 24)), gap_limit=0.1),
+    True: dict(cfg=dict(num_hidden_layers=2, hidden_size=64,
+                        num_attention_heads=4, intermediate_size=96,
+                        vocab_size=50, num_pred_heads=2, window_size=32,
+                        chunk_size=4, rope_theta=100000, rms_norm_eps=1e-5,
+                        init_std=0.2, max_position_embeddings=96),
+               dtype="float32", page_tokens=4, prefill_chunk=8,
+               requests=((40, 4), (28, 12)), gap_limit=1e-3),
 }
 TRAIN_SIZES = {
     False: dict(model="bert_base", vocab=30522, steps=5,
@@ -299,6 +321,55 @@ def serve_phase(cfg, seed, watch):
 # one chip: train
 # ---------------------------------------------------------------------------
 
+def eva_phase(sizes, seed):
+    """Two EvaByte requests through `ServeEngine`, one rolling in prefill and
+    one in decode; every served token's reference logit within `gap_limit`
+    of the reference's best."""
+    import incubator_mxnet_tpu as mx
+    from chipbench.reference import evabyte as ref
+    from chipbench.runners.serve_eva import build_decoder
+    from incubator_mxnet_tpu.telemetry import registry
+
+    cfg = sizes["cfg"]
+    t0 = time.perf_counter()
+    dec = build_decoder(cfg, seed, ref, sizes["dtype"])
+    eng = mx.serve.ServeEngine(
+        dec, max_slots=2, max_len=cfg["max_position_embeddings"],
+        page_tokens=sizes["page_tokens"], prefill_chunk=sizes["prefill_chunk"])
+    rng = onp.random.default_rng([seed, 0xE7A])
+    prompts = [rng.integers(0, cfg["vocab_size"], n).astype(onp.int32)
+               for n, _ in sizes["requests"]]
+    rolls = registry.counter("mx_serve_eva_rolls_total")
+    before = rolls.value
+    eng.start()
+    try:
+        handles = [eng.submit(p, new)
+                   for p, (_, new) in zip(prompts, sizes["requests"])]
+        outs = [list(eng.iter_tokens(h, timeout=600.0)) for h in handles]
+    finally:
+        eng.shutdown(drain=False)
+    if rolls.value - before != len(prompts):
+        raise AssertionError(f"expected one roll a request, counted "
+                             f"{rolls.value - before}")
+    tokens = onp.zeros((len(prompts), cfg["max_position_embeddings"]),
+                       onp.int32)
+    rows, served = [], []
+    for b, (p, out) in enumerate(zip(prompts, outs)):
+        seq = onp.concatenate([p, onp.asarray(out, onp.int32)])
+        tokens[b, :seq.size - 1] = seq[:-1]
+        rows += [(b, p.size - 1 + j) for j in range(len(out))]
+        served += out
+    logits = ref.logits_at(cfg, seed, tokens, rows)
+    gap = logits.max(-1) - logits[onp.arange(len(served)), served]
+    say(f"eva: {len(served)} tokens of {len(prompts)} requests across "
+        f"{rolls.value - before} rolls in {time.perf_counter() - t0:.1f} s; "
+        f"widest gap to the reference's best logit {gap.max():.4f} "
+        f"(limit {sizes['gap_limit']}), kernel branches {kernel_branches({})}")
+    if not gap.max() <= sizes["gap_limit"]:
+        raise AssertionError(f"eva: a served token lies {gap.max():.4f} "
+                             "below the reference's best")
+
+
 def build_trainer(cfg, seq, seed, mesh=None):
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import gluon, optimizer
@@ -537,6 +608,7 @@ def main(argv=None):
         mesh_train_phase(TRAIN_SIZES[args.tiny], args.seed)
     else:
         serve_phase(SERVE_SIZES[args.tiny], args.seed, watch)
+        eva_phase(EVA_SIZES[args.tiny], args.seed)
         # the toy widths are below a lane (128): no kernel site admits them
         train_phase(TRAIN_SIZES[args.tiny], args.seed, watch,
                     expect_kernels=not args.tiny)

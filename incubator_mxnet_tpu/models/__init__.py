@@ -6,7 +6,11 @@
   dp/tp/sp shardings over a Mesh — the multi-chip flagship path.
 - `gpt`: decoder-only causal LM (GluonNLP GPT-2 role) over the causal
   flash-attention path, with a sampling `generate` loop.
+- `evabyte`: byte-level decoder (rotary, gated, bias-free) whose attention
+  keeps one exact window and chunk summaries of everything before it; pure
+  jax, served by `mx.serve` (`serve/eva.py`).
 """
 from .bert import BERTClassifier, BERTEncoder, BERTModel, TransformerEncoderCell  # noqa: F401
+from . import evabyte  # noqa: F401
 from . import gpt  # noqa: F401
 from . import sharded_bert  # noqa: F401

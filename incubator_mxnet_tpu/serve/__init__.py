@@ -21,6 +21,10 @@ Three connected parts:
   donated buffers — zero steady-state recompiles and no per-step
   allocation. Optional int8 KV storage
   (``MXNET_SERVE_KV_DTYPE=int8``) halves resident KV bytes per slot;
+- `eva`       — :class:`EvaSlotDecoder`: the same pool and allocator for
+  the EvaByte family, a slot's pages of two kinds (the exact rows of its
+  current window, one summary row per finished chunk of every window
+  before) and the roll between them;
 - `scheduler` — :class:`Scheduler`: bounded admission queue (FIFO or
   remaining-chunk SJF), loud :class:`QueueFull` backpressure,
   per-request deadlines (:class:`DeadlineExceeded`, retryable under
@@ -100,6 +104,7 @@ from . import api  # noqa: F401
 from . import disagg  # noqa: F401
 from . import elastic  # noqa: F401
 from . import engine  # noqa: F401
+from . import eva  # noqa: F401
 from . import gateway  # noqa: F401
 from . import router  # noqa: F401
 from . import scheduler  # noqa: F401
@@ -110,6 +115,7 @@ from .disagg import MigrationAborted  # noqa: F401
 from .elastic import ReplicaScaleError, ReplicaSetController  # noqa: F401
 from .engine import (PageAllocator, PagePoolExhausted,  # noqa: F401
                      PrefixCache, SlotDecoder)
+from .eva import EvaSlotDecoder  # noqa: F401
 from .gateway import Gateway, GatewayRequest, ModelRegistry  # noqa: F401
 from .router import ReplicaRouter, replica_meshes  # noqa: F401
 from .scheduler import (DeadlineExceeded, EngineClosed,  # noqa: F401
@@ -118,7 +124,8 @@ from .sharded import (ServeLayout, ShardedSlotDecoder,  # noqa: F401
                       serve_mesh)
 from .tenancy import Tenant, TokenBucket, WDRRQueue  # noqa: F401
 
-__all__ = ["ServeEngine", "SlotDecoder", "Scheduler", "Request",
+__all__ = ["ServeEngine", "SlotDecoder", "EvaSlotDecoder", "Scheduler",
+           "Request",
            "PageAllocator", "PrefixCache", "PagePoolExhausted",
            "QueueFull", "DeadlineExceeded", "EngineClosed",
            "Gateway", "GatewayRequest", "ModelRegistry",
@@ -127,5 +134,5 @@ __all__ = ["ServeEngine", "SlotDecoder", "Scheduler", "Request",
            "ReplicaSetController", "ReplicaScaleError",
            "MigrationAborted",
            "Tenant", "TokenBucket", "WDRRQueue",
-           "api", "disagg", "elastic", "engine", "gateway", "router",
+           "api", "disagg", "elastic", "engine", "eva", "gateway", "router",
            "scheduler", "sharded", "tenancy"]

@@ -46,8 +46,10 @@ class ServeEngine:
 
     Parameters
     ----------
-    block_or_decoder : Block | GPTDecoder
-        The model to serve.
+    block_or_decoder : Block | GPTDecoder | EvaByteDecoder
+        The model to serve. An `EvaByteDecoder` is served by
+        `serve.eva.EvaSlotDecoder` (window and summary pages; no
+        speculative decoding, int8 pages or prefix reuse).
     max_slots : int
         In-flight request capacity (static decode batch width).
     max_len : int, optional
@@ -85,18 +87,21 @@ class ServeEngine:
 
     def __init__(self, block_or_decoder, max_slots=8, max_len=None,
                  page_tokens=None, prefill_chunk=None, n_pages=None,
-                 kv_dtype=None, prefix_reuse=True, policy=None,
+                 kv_dtype=None, prefix_reuse=None, policy=None,
                  max_queue=None, deadline_s=None, eos_id=None,
                  do_sample=False, top_k=None, temperature=1.0, seed=0,
                  spec_k=None, draft=None):
         import os
 
-        slots = SlotDecoder(block_or_decoder, max_slots=max_slots,
-                            max_len=max_len, page_tokens=page_tokens,
-                            prefill_chunk=prefill_chunk, n_pages=n_pages,
-                            kv_dtype=kv_dtype, prefix_reuse=prefix_reuse,
-                            do_sample=do_sample, top_k=top_k,
-                            spec_k=spec_k, draft=draft)
+        family = SlotDecoder
+        if getattr(block_or_decoder, "family", None) == "evabyte":
+            from .eva import EvaSlotDecoder as family
+        slots = family(block_or_decoder, max_slots=max_slots,
+                       max_len=max_len, page_tokens=page_tokens,
+                       prefill_chunk=prefill_chunk, n_pages=n_pages,
+                       kv_dtype=kv_dtype, prefix_reuse=prefix_reuse,
+                       do_sample=do_sample, top_k=top_k,
+                       spec_k=spec_k, draft=draft)
         if policy is None:
             policy = os.environ.get("MXNET_SERVE_POLICY", "fifo")
         if max_queue is None:

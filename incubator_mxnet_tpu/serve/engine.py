@@ -413,7 +413,8 @@ class SlotDecoder:
         dtype). int8 halves resident KV bytes with one scale per
         (layer, page, head).
     prefix_reuse : bool
-        Arm the shared-prefix cache (default True).
+        Arm the shared-prefix cache (default: on where the family has
+        one).
     do_sample / top_k : sampling mode, STATIC per engine; `temperature`
         stays a runtime per-request argument.
     spec_k : int
@@ -428,18 +429,18 @@ class SlotDecoder:
         (drafted ids index the target embedding).
     """
 
+    #: pages are mapped by position, all of a request's at admission; a
+    #: family whose slots take and free pages as they go says True
+    #: (`serve/eva.py`) and the scheduler maps positions before it runs them
+    lazy_pages = False
+
     def __init__(self, source, max_slots=8, max_len=None, page_tokens=None,
                  prefill_chunk=None, n_pages=None, kv_dtype=None,
-                 prefix_reuse=True, do_sample=False, top_k=None,
+                 prefix_reuse=None, do_sample=False, top_k=None,
                  spec_k=None, draft=None):
-        if isinstance(source, GPTDecoder):
-            self._dec = source
-        elif hasattr(source, "blocks") and hasattr(source, "position_embed"):
-            self._dec = GPTDecoder(source)
-        else:
-            raise TypeError(
-                "SlotDecoder needs a GPTDecoder or a GPT-shaped Block "
-                f"(blocks + position_embed), got {type(source).__name__}")
+        self._dec = self._resolve_decoder(source)
+        if prefix_reuse is None:
+            prefix_reuse = True
         model_max = self._dec._max_length
         self.max_len = int(max_len) if max_len is not None else model_max
         if self.max_len > model_max:
@@ -457,8 +458,8 @@ class SlotDecoder:
         if pt < 1:
             raise ValueError(f"page_tokens must be >= 1, got {pt}")
         self.page_tokens = pt
-        self.pages_per_slot = -(-self.max_len // pt)          # ceil
-        self.view_tokens = self.pages_per_slot * pt
+        self.view_tokens = -(-self.max_len // pt) * pt        # ceil
+        self.pages_per_slot = self._table_width()
         chunk = int(prefill_chunk) if prefill_chunk is not None else \
             env_int("MXNET_SERVE_PREFILL_CHUNK", DEFAULT_PREFILL_CHUNK)
         chunk = max(pt, -(-chunk // pt) * pt)                 # page-align up
@@ -554,6 +555,45 @@ class SlotDecoder:
         # families and census owners carry the tenant name
         self.census_name = "serve"
 
+    def _resolve_decoder(self, source):
+        """The decoder object this engine's programs are built for."""
+        if getattr(source, "family", None) == "evabyte":
+            raise NotImplementedError(
+                f"{type(self).__name__} does not serve the evabyte family "
+                "(window and summary pages, the roll): `serve.eva."
+                "EvaSlotDecoder` does, on one device; a sharded engine for "
+                "it is not written")
+        if isinstance(source, GPTDecoder):
+            return source
+        if hasattr(source, "blocks") and hasattr(source, "position_embed"):
+            return GPTDecoder(source)
+        raise TypeError(
+            "SlotDecoder needs a GPTDecoder or a GPT-shaped Block "
+            f"(blocks + position_embed), got {type(source).__name__}")
+
+    def _kv_geometry(self, dec):
+        """``(layers, heads, head size, float dtype)`` of `dec`'s K/V rows."""
+        if hasattr(dec, "kv_geometry"):
+            return dec.kv_geometry()
+        layers = dec._params["layers"]
+        return (int(layers["ln1_g"].shape[0]), dec._n_heads,
+                dec._units // dec._n_heads, layers["qkv_w"].dtype)
+
+    # -- page arithmetic (the scheduler asks; it keeps none of its own) -----
+
+    def _table_width(self):
+        """Entries of a slot's row of the page table."""
+        return self.view_tokens // self.page_tokens
+
+    def pages_needed(self, n):
+        """The most pages a request holds at once while it writes K/V for
+        positions ``0 .. n-1``: the admission budget."""
+        return -(-int(n) // self.page_tokens)
+
+    def pages_at(self, n):
+        """Pages a slot holds with positions ``0 .. n-1`` mapped."""
+        return -(-int(n) // self.page_tokens)
+
     # -- page table ---------------------------------------------------------
 
     def set_slot_pages(self, slot, pages):
@@ -588,10 +628,7 @@ class SlotDecoder:
         aliases each layer's pool in place; see the module docstring
         for why the stacked layout forces an O(L × n_pages) rewrite."""
         jnp = _j().numpy
-        layers = dec._params["layers"]
-        L = layers["ln1_g"].shape[0]
-        H = dec._n_heads
-        d = dec._units // H
+        L, H, d, dtype = self._kv_geometry(dec)
         shape = (self.n_pages, H, self.page_tokens, d)
         if self._int8:
             pk = tuple(jnp.zeros(shape, jnp.int8) for _ in range(L))
@@ -601,7 +638,6 @@ class SlotDecoder:
             sv = tuple(jnp.zeros((self.n_pages, H), jnp.float32)
                        for _ in range(L))
             return pk, pv, sk, sv
-        dtype = layers["qkv_w"].dtype
         # float pages are stored packed to the TPU's 128 lanes
         # (ops.paged_attention, "a page as it is stored")
         from ..ops.paged_attention import page_store_shape
@@ -731,17 +767,12 @@ class SlotDecoder:
         disaggregation plane's ``mx_serve_page_migration_bytes_total``
         is exactly pages-moved × this. Derived from shapes, so it needs
         no allocated pool."""
-        dec = self._dec
-        layers = dec._params["layers"]
-        L = int(layers["ln1_g"].shape[0])
-        H = dec._n_heads
-        d = dec._units // H
+        L, H, d, dtype = self._kv_geometry(self._dec)
         if self._int8:
             # int8 K + V page slabs plus two f32 per-(page, H) scales
             per_layer = 2 * H * self.page_tokens * d + 2 * H * 4
         else:
-            itemsize = onp.dtype(layers["qkv_w"].dtype).itemsize
-            per_layer = 2 * H * self.page_tokens * d * itemsize
+            per_layer = 2 * H * self.page_tokens * d * dtype.itemsize
         return L * per_layer
 
     # -- page migration (the disaggregation transfer seam) -------------------
@@ -972,6 +1003,18 @@ class SlotDecoder:
         return _compiles.instrument_jit(
             fn, f"{self.census_name}.{kind}", bucket=bucket, donate=donate)
 
+    def _to_bucket(self, chunk_tokens):
+        """``(tokens padded to their bucket, real length, bucket, pad)`` of
+        a prefill chunk (the waste is counted)."""
+        chunk = onp.asarray(chunk_tokens, onp.int32).reshape(-1)
+        n = chunk.size
+        bucket = bucket_chunk(n, self.chunk_buckets)
+        pad = bucket - n
+        if pad:
+            chunk = onp.pad(chunk, (0, pad))
+            PAD_TOKENS.inc(pad)
+        return chunk, n, bucket, pad
+
     def prefill_chunk_step(self, slot, chunk_tokens, t_start, key,
                            temperature=1.0):
         """Run ONE page-aligned prefill chunk for `slot`.
@@ -996,13 +1039,7 @@ class SlotDecoder:
                 raise ValueError(
                     f"chunk start {t_start} is not page-aligned "
                     f"(page_tokens={pt})")
-            chunk = onp.asarray(chunk_tokens, onp.int32).reshape(-1)
-            n = chunk.size
-            bucket = bucket_chunk(n, self.chunk_buckets)
-            pad = bucket - n
-            if pad:
-                chunk = onp.pad(chunk, (0, pad))
-                PAD_TOKENS.inc(pad)
+            chunk, n, bucket, pad = self._to_bucket(chunk_tokens)
             # the chunk's pages, padded with the trash page where the
             # bucket overshoots the slot's mapped range (pad-token K/V is
             # discarded)

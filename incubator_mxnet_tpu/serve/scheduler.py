@@ -318,6 +318,13 @@ class Scheduler:
         # speculative decoding: k drafted tokens verified per round
         # (0 = plain one-token decode; stub engines have no spec_k)
         self._spec_k = int(getattr(slots, "spec_k", 0) or 0)
+        # the page arithmetic is the slots object's (`SlotDecoder.
+        # pages_needed` / `pages_at`); a stand-in that has only
+        # `page_tokens` maps every page by position
+        by_position = lambda n: -(-int(n) // slots.page_tokens)  # noqa: E731
+        self._pages_for = getattr(slots, "pages_needed", by_position)
+        self._pages_at = getattr(slots, "pages_at", by_position)
+        self._lazy_pages = bool(getattr(slots, "lazy_pages", False))
 
     # -- keys ---------------------------------------------------------------
 
@@ -340,11 +347,19 @@ class Scheduler:
         prompt's pages: its first token samples from logits without a KV
         write, and every decode write happens on the adopting replica —
         this is exactly why a dedicated prefill replica holds so few
-        resident pages."""
-        pt = self.slots.page_tokens
+        resident pages.
+
+        The arithmetic is the slots object's (`pages_needed`): a family
+        whose slots free pages as they go holds fewer than
+        ``ceil(positions / page_tokens)``."""
         if req.prefill_only:
-            return -(-req.prompt.size // pt)
-        return -(-(req.prompt.size + req.max_new - 1) // pt)
+            return self._pages_for(req.prompt.size)
+        return self._pages_for(self._written(req))
+
+    @staticmethod
+    def _written(req):
+        """Positions of `req` that get a K/V row."""
+        return req.prompt.size + req.max_new - 1
 
     def _pages_initial(self, req):
         """Pages physically allocated at admit. Non-spec: the full
@@ -354,11 +369,16 @@ class Scheduler:
         that speculative rounds draw down page-by-page and rejected
         suffixes roll back into, so admission pressure is identical to
         the non-spec engine (reserved pages are counted against
-        admissions) but unaccepted lookahead never holds real pages."""
+        admissions) but unaccepted lookahead never holds real pages. A
+        family with `lazy_pages` (pages taken and freed as a slot goes)
+        starts with its first chunk's and draws the same reservation down
+        position by position (`_map_positions`)."""
+        if self._lazy_pages:
+            return self._pages_at(
+                min(req.prompt.size, self.slots.prefill_chunk))
         if req.prefill_only or not self._spec_k:
             return self._pages_needed(req)
-        pt = self.slots.page_tokens
-        return -(-(req.prompt.size + 1) // pt)
+        return self._pages_at(req.prompt.size + 1)
 
     def _spec_reserved_total(self):
         """Pages budgeted to live requests but not physically held —
@@ -401,6 +421,11 @@ class Scheduler:
                       None if deadline_s is None else now + float(deadline_s),
                       parent_span=parent_span, tenant=tenant)
         req.prefill_only = bool(prefill_only)
+        if req.prefill_only and self._lazy_pages:
+            req._trace_close()
+            raise NotImplementedError(
+                "prefill-only handoff moves pages mapped by position; this "
+                "engine's slots take and free pages as they go")
         if self._pages_needed(req) > self.slots.allocator.usable_pages:
             req._trace_close()
             raise PagePoolExhausted(
@@ -575,6 +600,8 @@ class Scheduler:
             t0 = req.prefill_pos
             n = min(self.slots.prefill_chunk, req.prompt.size - t0)
             last_chunk = t0 + n == req.prompt.size
+            if self._lazy_pages:
+                self._map_positions(slot, req, t0, t0 + n)
             # the chunk's window, from the step clock's stamps: the
             # boundary before it to the end of its read-back (the engine
             # stamps launch and read-back apart inside)
@@ -651,6 +678,14 @@ class Scheduler:
             return False
         if self._spec_k:
             return self._spec_decode_once(clock)
+        if self._lazy_pages:
+            for s, req in enumerate(self._in_slot):
+                if req is not None and self._active[s]:
+                    pos = int(self._pos[s])
+                    if self._pages_at(pos + 1) > len(req.pages) \
+                            or self.slots.rolls_before(pos):
+                        self._map_positions(s, req, pos, min(
+                            self.slots.window_end(pos), self._written(req)))
         # the decode call's window, from the step clock's stamps (the
         # engine stamps launch and read-back apart inside it)
         w0 = clock.cursor
@@ -684,6 +719,28 @@ class Scheduler:
                  if r is not None and self._active[s]],
                 self.capacity_model, "decode", w1 - w0)
         return anatomy.on_decode_step(self, w0, w1)
+
+    def _map_positions(self, slot, req, pos, end):
+        """A slot with `lazy_pages`, before it writes rows at positions
+        ``pos .. end - 1`` (all in one window): where `pos` opens a window,
+        the window before it is rolled into summary pages and its pages go
+        back to the allocator; then the reservation is drawn down until the
+        positions are mapped. ``len(req.pages) + req.spec_reserved`` stays
+        the admission-time worst case (`_extend_spec_pages`), which
+        `slots.pages_needed` sized for the roll's moment: `alloc` cannot
+        fail here."""
+        slots, alloc = self.slots, self.slots.allocator
+        pages = list(req.pages)
+        if slots.rolls_before(pos):
+            n_win = slots.window_pages
+            new = alloc.alloc(slots.summary_pages)
+            slots.roll_step(slot, pages[-n_win:], new)
+            alloc.decref(pages[-n_win:])
+            pages = pages[:-n_win] + new
+        pages += alloc.alloc(max(0, slots.pages_at(end) - len(pages)))
+        req.spec_reserved += len(req.pages) - len(pages)
+        req.pages = pages
+        slots.set_slot_pages(slot, pages)
 
     def _extend_spec_pages(self, slot, req, k):
         """Draw down `req`'s reservation so every position the verify
@@ -958,6 +1015,10 @@ class Scheduler:
         KV, `physical` is what the caller must hand to :meth:`adopt`
         (under spec decode the tail stays a reservation, mirroring
         `_pages_initial`), and `reserved` seeds ``spec_reserved``."""
+        if self._lazy_pages:
+            raise NotImplementedError(
+                "adoption hands over pages mapped by position; this "
+                "engine's slots take and free pages as they go")
         pt = self.slots.page_tokens
         content = -(-prompt_len // pt)
         total = -(-(prompt_len + max_new - 1) // pt)

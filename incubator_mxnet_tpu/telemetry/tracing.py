@@ -85,7 +85,7 @@ STEP_RING_CAPACITY = 8192     # step records kept (19 min at 7 steps/s)
 REQUEST_RING_CAPACITY = 4096  # request records kept
 # the phases of one serving iteration, as a step record names them
 PHASES = ("lock_wait", "admit", "prefill_launch", "prefill_readback",
-          "decode_launch", "decode_readback", "emit")
+          "decode_launch", "decode_readback", "emit", "eva_roll")
 
 _ENABLED = False
 _LOCK = tracked_lock("telemetry.tracing", kind="lock")

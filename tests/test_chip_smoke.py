@@ -41,3 +41,10 @@ def test_compile_cache_defaults_to_one_fixed_directory(monkeypatch):
     assert configure_compile_cache() == fixed
     assert configure_compile_cache() == fixed      # no pid, time or temp name
     assert jax.config.jax_compilation_cache_dir == fixed
+
+
+def test_eva_phase_rehearsal_crosses_a_roll_and_agrees_with_the_reference(capsys):
+    """The EvaByte phase at its toy size on the CPU: one roll in prefill,
+    one in decode, tokens the reference's."""
+    chip_smoke.eva_phase(chip_smoke.EVA_SIZES[True], 3)
+    assert "across 2 rolls" in capsys.readouterr().out

@@ -72,6 +72,33 @@ def test_pallas_kernel_is_named_in_its_lowering(lowered, kernel):
     assert kernel in text
 
 
+def test_the_roll_is_a_named_program_and_scope():
+    """`mx_eva_roll` (serve/eva.py) is XLA, not pallas: the name is the
+    jitted program's (the ``XLA Modules`` lane reads ``jit_mx_eva_roll``) and
+    a named scope on every op in it."""
+    from incubator_mxnet_tpu.models.evabyte import (EvaByteConfig,
+                                                    EvaByteDecoder)
+    from incubator_mxnet_tpu.serve.eva import EvaSlotDecoder
+
+    cfg = EvaByteConfig(num_hidden_layers=1, hidden_size=256,
+                        num_attention_heads=2, intermediate_size=64,
+                        vocab_size=8, num_pred_heads=1, window_size=64,
+                        chunk_size=4, max_position_embeddings=256)
+    top, layer = cfg.leaf_shapes()
+    params = {n: jnp.zeros(s) for n, s in top.items()}
+    params["layers"] = [{n: jnp.zeros(s) for n, s in layer.items()}]
+    slots = EvaSlotDecoder(EvaByteDecoder(cfg, params), max_slots=2,
+                           page_tokens=4, prefill_chunk=16)
+    pool = (jax.ShapeDtypeStruct((9, 2, 4, 128), jnp.bfloat16),)
+    feats = ((jax.ShapeDtypeStruct((2, 128), jnp.float32),) * 2,)
+    pages = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)  # noqa: E731
+    text = slots._build_roll()._fn.trace(
+        feats, pool, pool, pages(16), pages(4)).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "jit_mx_eva_roll" in text
+    assert text.count("mx_eva_roll/") > 10
+
+
 def test_every_pallas_call_of_the_main_path_is_named():
     """Ten calls, ten names: a call added without one shows here."""
     import inspect
