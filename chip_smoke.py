@@ -217,6 +217,26 @@ def kernel_branches(before):
 # one chip: serve
 # ---------------------------------------------------------------------------
 
+def launch_modes(steps, what):
+    """How the decode launches of `steps` (step records) were made: says
+    the share queued while the step before was still unfetched, and the
+    rows launched for a request whose EOS came a step late. Every request
+    here ends by length, and a run in which no launch went ahead has lost
+    the mechanism."""
+    launches = [r for r in steps if r["decoding"]]
+    only = [r for r in launches if not r["chunks"]]
+    ahead = sum(r["mode"] == "ahead" for r in only)
+    overshoot = sum(r["overshoot"] for r in steps)
+    share = 100.0 * ahead / len(only) if only else 0.0
+    say(f"{what}: {len(launches)} decode launches, {len(only)} in steps "
+        f"without a prefill chunk, {ahead} of those made ahead of the last "
+        f"fetch ({share:.1f} %); overshoot rows {overshoot}")
+    if not ahead or overshoot:
+        raise RuntimeError(
+            f"{what}: {ahead} decode launches made ahead, {overshoot} "
+            "overshoot rows (expected some, and none)")
+
+
 def serve_phase(cfg, seed, watch):
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu.ops import _dispatch
@@ -304,6 +324,7 @@ def serve_phase(cfg, seed, watch):
                 f"{len(steps)} step records in the window, phases cover "
                 f"{accounted:.2f} % of the steps' wall (< 99: a boundary of "
                 "Scheduler.step is no longer stamped)")
+        launch_modes(steps, "serve")
         net.hybridize()
         i = longest(prompts)
         check_against_reference(net, prompts[i], outputs[i], "serve")
@@ -328,7 +349,7 @@ def eva_phase(sizes, seed):
     import incubator_mxnet_tpu as mx
     from chipbench.reference import evabyte as ref
     from chipbench.runners.serve_eva import build_decoder
-    from incubator_mxnet_tpu.telemetry import registry
+    from incubator_mxnet_tpu.telemetry import registry, tracing
 
     cfg = sizes["cfg"]
     t0 = time.perf_counter()
@@ -351,6 +372,7 @@ def eva_phase(sizes, seed):
     if rolls.value - before != len(prompts):
         raise AssertionError(f"expected one roll a request, counted "
                              f"{rolls.value - before}")
+    launch_modes(tracing.step_records(t0), "eva")
     tokens = onp.zeros((len(prompts), cfg["max_position_embeddings"]),
                        onp.int32)
     rows, served = [], []

@@ -160,6 +160,14 @@ def _j():
     return jax
 
 
+def _upload(host, dtype=None):
+    """A launch's own copy of a host array, on the device. The launch may
+    still read it when the host's array has changed: a program is queued,
+    not waited for, and an upload need not have left the host's buffer
+    when the call returns (on the CPU it never does)."""
+    return _j().numpy.asarray(onp.array(host, dtype))
+
+
 class PagePoolExhausted(RuntimeError):
     """The KV page pool cannot satisfy an allocation — loud, like
     `QueueFull`: pages referenced by live requests or the prefix cache
@@ -501,6 +509,9 @@ class SlotDecoder:
         self._sk = self._sv = None          # int8 per-(page, H) scales
         self._prefill_jit = None
         self._decode_jit = None
+        # the tokens of the last decode launch, on the device: the next
+        # launch reads a continuing slot's last token from them
+        self._tokens = None
 
         # -- speculative decoding --------------------------------------
         sk_env = env_int("MXNET_SERVE_SPEC_K", 0)
@@ -613,7 +624,7 @@ class SlotDecoder:
 
     def _table_device(self):
         if self._table_dirty or self._table_dev is None:
-            self._table_dev = _j().numpy.asarray(self._table)
+            self._table_dev = _upload(self._table)
             self._table_dirty = False
         return self._table_dev
 
@@ -669,6 +680,14 @@ class SlotDecoder:
         each updated pool leaf to its input sharding so XLA's donation
         map still aliases all ``2L`` leaves in place."""
         return pk, pv, sk, sv
+
+    def _pin_tokens(self, tokens):
+        """Seam for a decode launch's ``(max_slots,)`` tokens, which the
+        next launch takes back: traced at the program's tail, and applied
+        to the zeros the first launch takes. Identity here; the sharded
+        engine pins them replicated, so that the first launch and every
+        later one see one input placement (no second compile)."""
+        return tokens
 
     def _shardcheck_specs(self):
         """Per-argument shardcheck spec entries for ``(params, *pools)``,
@@ -736,6 +755,7 @@ class SlotDecoder:
         """Drop the device pool (shutdown); the next prefill reallocates."""
         self._pk = self._pv = self._sk = self._sv = None
         self._dpk = self._dpv = self._dsk = self._dsv = None
+        self._tokens = None
         self._table_dev = None
         self._table_dirty = True
 
@@ -1021,9 +1041,12 @@ class SlotDecoder:
 
         `chunk_tokens` is the 1D host slice ``prompt[t_start:t_start+n]``
         with ``t_start`` page-aligned (0 or a multiple of `page_tokens`,
-        e.g. the shared-prefix boundary). Returns ``(first_token, bucket,
-        pad)`` — the sampled token is meaningful only when this was the
-        prompt's final chunk; `bucket`/`pad` feed the caller's span
+        e.g. the shared-prefix boundary). The chunk is LAUNCHED, not waited
+        for. Returns ``(first_token, bucket, pad)`` — the sampled token as
+        the program gives it, a device scalar not yet fetched: it is
+        meaningful only when this was the prompt's final chunk, and only
+        then does the caller fetch it (``int(first)``, which blocks until
+        the chunk ran); `bucket`/`pad` feed the caller's span
         annotations. `key` is a PRNG key or a callable that makes one: the
         scheduler hands its key maker in, so that the eager ``fold_in``
         runs inside the launch span with the rest of the host's work.
@@ -1044,7 +1067,7 @@ class SlotDecoder:
             # bucket overshoots the slot's mapped range (pad-token K/V is
             # discarded)
             first_page = t_start // pt
-            row = self._table[slot]
+            row = self._table[slot].copy()    # the launch's own (`_upload`)
             cp = bucket // pt
             chunk_pages = onp.zeros(cp, onp.int32)
             avail = row[first_page:first_page + cp]
@@ -1085,8 +1108,6 @@ class SlotDecoder:
                         self._draft_dec._params, self._dpk, self._dpv,
                         *args, top_k=self._top_k,
                         do_sample=self._do_sample)
-        with tracing.phase("mx.serve.prefill.readback", "prefill_readback"):
-            first = int(first)            # blocks until the chunk ran
         return first, bucket, pad
 
     # -- decode -------------------------------------------------------------
@@ -1186,8 +1207,11 @@ class SlotDecoder:
         S = self.max_slots
         write_token = self._make_write_token()
 
-        def run(params, pk, pv, sk, sv, table, last_tok, pos, active,
-                key, temperature, top_k, do_sample):
+        def run(params, pk, pv, sk, sv, table, last_tok, prev_tok, pos,
+                active, key, temperature, top_k, do_sample):
+            # a slot that goes on from the launch before takes the token
+            # that launch gave it, which the host may not have seen yet
+            last_tok = jnp.where(last_tok < 0, prev_tok, last_tok)
             x = (params["embed"][last_tok][:, None, :]
                  + params["pos"][pos][:, None, :])              # (S, 1, C)
             # each slot writes at its own page/offset; slots that are
@@ -1219,25 +1243,26 @@ class SlotDecoder:
             # free/prefilling slots carry their last token forward — the
             # host never reads them, but a defined value keeps the
             # program deterministic
-            nxt = jnp.where(active, nxt, last_tok)
+            nxt = self._pin_tokens(jnp.where(active, nxt, last_tok))
             pk, pv, sk, sv = self._constrain_pools(pk, pv, sk, sv)
             return pk, pv, sk, sv, nxt
 
         if int8:
-            def decode(params, pk, pv, sk, sv, table, last_tok, pos,
-                       active, key, temperature, *, top_k, do_sample):
-                return run(params, pk, pv, sk, sv, table, last_tok, pos,
-                           active, key, temperature, top_k, do_sample)
+            def decode(params, pk, pv, sk, sv, table, last_tok, prev_tok,
+                       pos, active, key, temperature, *, top_k, do_sample):
+                return run(params, pk, pv, sk, sv, table, last_tok,
+                           prev_tok, pos, active, key, temperature, top_k,
+                           do_sample)
 
             return self._observed(
                 jax.jit(decode, static_argnames=("top_k", "do_sample"),
                         donate_argnums=(1, 2, 3, 4)),
                 "decode", donate=(1, 2, 3, 4))
 
-        def decode(params, pk, pv, table, last_tok, pos, active, key,
-                   temperature, *, top_k, do_sample):
+        def decode(params, pk, pv, table, last_tok, prev_tok, pos, active,
+                   key, temperature, *, top_k, do_sample):
             pk, pv, _, _, nxt = run(params, pk, pv, None, None, table,
-                                    last_tok, pos, active, key,
+                                    last_tok, prev_tok, pos, active, key,
                                     temperature, top_k, do_sample)
             return pk, pv, nxt
 
@@ -1246,37 +1271,49 @@ class SlotDecoder:
                     donate_argnums=(1, 2)),
             "decode", donate=(1, 2))
 
+    def _decode_args(self, last_tok, pos, active, key, temperature):
+        """What a decode program takes after the pools: the table, the
+        launch's own copies of the host's arrays, and the tokens of the
+        launch before (`decode_step`)."""
+        jnp = _j().numpy
+        if callable(key):
+            key = key()
+        if self._tokens is None:
+            self._tokens = self._pin_tokens(
+                jnp.zeros(self.max_slots, jnp.int32))
+        return (self._table_device(), _upload(last_tok, onp.int32),
+                self._tokens, _upload(pos, onp.int32), _upload(active, bool),
+                key, _upload(temperature, onp.float32))
+
     def decode_step(self, last_tok, pos, active, key, temperature):
-        """One decode step for every DECODE-ACTIVE slot. `last_tok` /
-        `pos` / `active` / `temperature` are HOST arrays (shape
+        """LAUNCH one decode step for every DECODE-ACTIVE slot. `last_tok`
+        / `pos` / `active` / `temperature` are HOST arrays (shape
         ``(max_slots,)``) owned by the scheduler — the step loop never
         branches on device values. Slots still mid-prefill must have
         ``active=False`` (their writes are redirected to the trash page).
-        Returns the next token per slot as host numpy (the one host sync
-        per step). `key`: a PRNG key, or a callable that makes one (called
-        inside the launch span, as in `prefill_chunk_step`)."""
-        jnp = _j().numpy
+        A NEGATIVE entry of `last_tok` stands for "the token the launch
+        before this one produced for that slot": it is taken from that
+        launch's output on the device, so the caller can queue this step
+        before it has fetched the last one's tokens. Returns the next token
+        per slot as the program gives it, a device array NOT yet fetched:
+        ``numpy.asarray`` of it is the one host sync of a step, and whoever
+        needs the tokens makes it (`Scheduler._land`). `key`: a PRNG key, or
+        a callable that makes one (called inside the launch span, as in
+        `prefill_chunk_step`)."""
         with tracing.phase("mx.serve.decode.launch", "decode_launch"):
             self._refresh_params()
             self._ensure_pool()
             if self._decode_jit is None:
                 self._decode_jit = self._build_decode()
-            if callable(key):
-                key = key()
-            args = (self._table_device(),
-                    jnp.asarray(last_tok, jnp.int32),
-                    jnp.asarray(pos, jnp.int32),
-                    jnp.asarray(active, bool),
-                    key,
-                    jnp.asarray(temperature, jnp.float32))
+            args = self._decode_args(last_tok, pos, active, key, temperature)
             if self._int8:
                 (self._pk, self._pv, self._sk, self._sv,
-                 nxt) = self._decode_jit(
+                 self._tokens) = self._decode_jit(
                     self._dec._params, self._pk, self._pv, self._sk,
                     self._sv, *args, top_k=self._top_k,
                     do_sample=self._do_sample)
             else:
-                self._pk, self._pv, nxt = self._decode_jit(
+                self._pk, self._pv, self._tokens = self._decode_jit(
                     self._dec._params, self._pk, self._pv, *args,
                     top_k=self._top_k, do_sample=self._do_sample)
             on = onp.asarray(active, bool)
@@ -1285,8 +1322,7 @@ class SlotDecoder:
             DECODE_PAGES["live"].inc(live)
             DECODE_PAGES["view"].inc(view)
             tracing.count(pages_live=live, pages_view=view)
-        with tracing.phase("mx.serve.decode.readback", "decode_readback"):
-            return onp.asarray(nxt)       # blocks until the step ran
+        return self._tokens
 
     # -- speculative decoding ----------------------------------------------
 
@@ -1665,8 +1701,8 @@ class SlotDecoder:
 
         decode_args = (params,) + pools + (
             sds((S, self.pages_per_slot), i32),         # page table
-            sds((S,), i32), sds((S,), i32),             # last_tok, pos
-            sds((S,), bool),                            # active
+            sds((S,), i32), sds((S,), i32),             # last_tok, prev_tok
+            sds((S,), i32), sds((S,), bool),            # pos, active
             key, sds((S,), f32))                        # key, temperature
         dc_specs = None if head_specs is None else head_specs + (
             (None,) * (len(decode_args) - len(head_specs)))

@@ -352,9 +352,10 @@ class EvaSlotDecoder(SlotDecoder):
 
     def prefill_chunk_step(self, slot, chunk_tokens, t_start, key,
                            temperature=1.0):
-        """One prefill chunk for `slot`, as `SlotDecoder`'s; `t_start` is a
-        multiple of `prefill_chunk`, and the chunk's window pages are
-        mapped (the scheduler saw to both)."""
+        """One prefill chunk for `slot`, as `SlotDecoder`'s (launched, its
+        first token returned unfetched); `t_start` is a multiple of
+        `prefill_chunk`, and the chunk's window pages are mapped (the
+        scheduler saw to both)."""
         jnp = _j().numpy
         with tracing.phase("mx.serve.prefill.launch", "prefill_launch"):
             self._ensure_pool()
@@ -366,7 +367,7 @@ class EvaSlotDecoder(SlotDecoder):
                     f"chunk start {t_start} is not a multiple of "
                     f"prefill_chunk ({self.prefill_chunk})")
             chunk, n, bucket, pad = self._to_bucket(chunk_tokens)
-            row = self._table[slot]
+            row = self._table[slot].copy()    # the launch's own
             n_sum = self.summary_pages * (t_start // self.window)
             sum_pages = onp.zeros(max(1, self.pages_per_slot
                                       - self.window_pages), onp.int32)
@@ -385,8 +386,6 @@ class EvaSlotDecoder(SlotDecoder):
                 jnp.asarray(chunk_pages), jnp.int32(t_start), jnp.int32(n),
                 key, jnp.float32(max(float(temperature), 1e-6)),
                 top_k=self._top_k, do_sample=self._do_sample)
-        with tracing.phase("mx.serve.prefill.readback", "prefill_readback"):
-            first = int(first)            # blocks until the chunk ran
         return first, bucket, pad
 
     # -- decode ---------------------------------------------------------------
@@ -399,8 +398,10 @@ class EvaSlotDecoder(SlotDecoder):
         summary_rows = self.window // self.chunk
         write_token = self._make_write_token()
 
-        def decode(params, pk, pv, table, last_tok, pos, active, key,
-                   temperature, *, top_k, do_sample):
+        def decode(params, pk, pv, table, last_tok, prev_tok, pos, active,
+                   key, temperature, *, top_k, do_sample):
+            # a slot that goes on from the launch before takes its token
+            last_tok = jnp.where(last_tok < 0, prev_tok, last_tok)
             w, r = pos // self.window, pos % self.window
             # free or prefilling slots write to the trash page and attend
             # nothing
@@ -421,21 +422,17 @@ class EvaSlotDecoder(SlotDecoder):
             static_argnames=("top_k", "do_sample"), donate_argnums=(1, 2))
 
     def decode_step(self, last_tok, pos, active, key, temperature):
-        """One decode step for every decode-active slot, as
-        `SlotDecoder`'s; a slot whose `pos` opens a window was rolled
-        before (the scheduler saw to it)."""
-        jnp = _j().numpy
+        """Launch one decode step for every decode-active slot, as
+        `SlotDecoder`'s (tokens returned unfetched; a negative `last_tok`
+        is the launch before's); a slot whose `pos` opens a window was
+        rolled before (the scheduler saw to it)."""
         with tracing.phase("mx.serve.decode.launch", "decode_launch"):
             self._ensure_pool()
             if self._decode_jit is None:
                 self._decode_jit = self._build_decode()
-            if callable(key):
-                key = key()
-            self._pk, self._pv, nxt = self._decode_jit(
-                self._dec._params, self._pk, self._pv, self._table_device(),
-                jnp.asarray(last_tok, jnp.int32), jnp.asarray(pos, jnp.int32),
-                jnp.asarray(active, bool), key,
-                jnp.asarray(temperature, jnp.float32),
+            self._pk, self._pv, self._tokens = self._decode_jit(
+                self._dec._params, self._pk, self._pv,
+                *self._decode_args(last_tok, pos, active, key, temperature),
                 top_k=self._top_k, do_sample=self._do_sample)
             at = onp.asarray(pos, onp.int64)[onp.asarray(active, bool)]
             rows_s = int((at // self.window).sum()) \
@@ -449,5 +446,4 @@ class EvaSlotDecoder(SlotDecoder):
             DECODE_ROWS["window"].inc(rows_w)
             DECODE_ROWS["summary"].inc(rows_s)
             tracing.count(pages_live=live, pages_view=view)
-        with tracing.phase("mx.serve.decode.readback", "decode_readback"):
-            return onp.asarray(nxt)       # blocks until the step ran
+        return self._tokens

@@ -1138,6 +1138,11 @@ class Gateway:
         resident page-aligned KV stays warm in THAT replica's prefix
         cache — prefix affinity later resumes it there)."""
         seg = victim._segment
+        # the decode step in flight may carry the victim's next token, or
+        # its last: then its slot is free already, and the pump folds it
+        rep.sched.settle()
+        if seg.slot is None:
+            return
         self._drain_segment(victim, seg, now)
         rep.sched.preempt(seg.slot, now)
         rep.live.remove(victim)

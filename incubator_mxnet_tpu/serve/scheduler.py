@@ -18,13 +18,29 @@ monolithic prefill. Every `step()`:
    request's first token (TTFT is measured here) and flips the slot to
    *decoding*; the completed prompt's full pages are registered in the
    prefix cache for later requests;
-5. runs ONE batched decode step over all decoding slots;
-6. retires slots whose request hit EOS or its token budget — pages are
-   dereferenced (shared pages survive in the cache) and the slot is
-   immediately free, mid-flight;
+5. LAUNCHES one batched decode step over all decoding slots, behind the
+   step launched an iteration ago, whose tokens the host has not fetched
+   yet: a slot that goes on takes its last token from that step's output
+   on the device, so the device goes from step to step while the host
+   prepares the next launch (`_launch`). A slot whose token on its way is
+   its ``max_new``-th is freed as soon as that step is queued;
+6. fetches the step launched an iteration ago, hands its tokens out and
+   retires slots whose request hit EOS — pages are dereferenced (shared
+   pages survive in the cache) and the slot is immediately free,
+   mid-flight (`_land`). An EOS is learnt one step late: the row already
+   launched for that slot is an overshoot, and its token is dropped;
 7. refreshes the queue-depth / occupancy gauges
    (``mx_serve_page_occupancy`` is pull-based off the allocator, shared
    pages counted once).
+
+**The step in flight.** Between two iterations one decode step is queued
+on the device and unfetched (`_flight`). Whatever changes a slot outside
+this order first fetches it and hands its tokens out (`settle`): a
+deadline that expired, `preempt`, `close`. `abandon` drops it (the engine
+is dead and its requests are re-owned). An engine with a step in flight
+is not `idle`. A slots object whose ``decode_step`` computes on the host
+and returns numpy (the tests' stand-ins) has nothing in flight: its
+tokens are handed out in the iteration that made them.
 
 Admission policies: ``"fifo"`` (arrival order) and ``"sjf"`` — which
 under chunked prefill orders by *remaining prefill chunks* (a long
@@ -46,7 +62,8 @@ Backpressure stays LOUD: a full queue raises :class:`QueueFull` at
 All mutable slot state (``pos``/``last``/``active``/page tables) lives
 HOST-side as numpy arrays passed into the compiled step — the loop
 never branches on device values (lint FL007/FL009), and the one
-device→host sync per step is the token fetch clients need anyway.
+device→host sync per step is the token fetch clients need anyway, made
+after the next step is queued.
 """
 from __future__ import annotations
 
@@ -91,6 +108,26 @@ PREFILL_CHUNKS = registry.counter(
     "mx_serve_prefill_chunks_total",
     "prefill chunks executed (long prompts span several, interleaved "
     "with decode steps)")
+DECODE_LAUNCHES = {
+    mode: registry.counter(
+        "mx_serve_decode_launches_total",
+        "decode programs launched: `ahead`, queued while the step before "
+        "was still unfetched (the device goes from one to the next); "
+        "`cold`, with nothing in flight (the device waits for the launch)",
+        labels={"mode": mode})
+    for mode in ("ahead", "cold")}
+OVERSHOOT_ROWS = registry.counter(
+    "mx_serve_decode_overshoot_rows_total",
+    "rows of a decode launch made for a request that had already emitted "
+    "its EOS (learnt one step late): the row's token is dropped")
+PREFILL_FETCHES = {
+    kind: registry.counter(
+        "mx_serve_prefill_fetches_total",
+        "prefill chunks by what became of the token they sampled: `final`, "
+        "a prompt's last chunk, fetched (the request's first token); "
+        "`skipped`, an intermediate chunk, never fetched",
+        labels={"kind": kind})
+    for kind in ("final", "skipped")}
 SPEC_DRAFTED = registry.counter(
     "mx_serve_spec_drafted_tokens_total",
     "draft tokens proposed to the verify program (speculative decoding)")
@@ -145,8 +182,9 @@ class Request:
                  "tokens", "state", "error", "error_class", "slot",
                  "pages", "shared_tokens", "prefill_pos", "chunks_done",
                  "spec_reserved", "tenant", "prefill_only", "first_token",
-                 "anatomy", "trace_id", "t_submit_call", "t_enqueued",
-                 "t_admit", "t_first_token", "_spans", "_stream", "_done")
+                 "inflight", "anatomy", "trace_id", "t_submit_call",
+                 "t_enqueued", "t_admit", "t_first_token", "_spans",
+                 "_stream", "_done")
 
     def __init__(self, rid, prompt, max_new, temperature, eos_id,
                  deadline, parent_span=None, tenant=None):
@@ -172,6 +210,7 @@ class Request:
         self.spec_reserved = 0            # budgeted-but-unallocated pages
         self.prefill_only = False         # disagg handoff segment
         self.first_token = None           # sampled on the prefill side
+        self.inflight = 0                 # tokens launched, not yet fetched
         self.anatomy = None               # gateway's anatomy record
         # the request record's stamps, on the step clock's clock (the
         # performance counter; `submit_t` and the deadline stay monotonic)
@@ -309,6 +348,9 @@ class Scheduler:
         self._n_active = 0                  # occupied (prefilling+decoding)
         self._n_decoding = 0
         self._prefilled = []                # disagg: awaiting migration
+        # the decode step launched and not yet fetched: its tokens (a
+        # device array) and its rows ``[(slot, request)]``
+        self._flight = None
         self._next_id = 0
         self.closed = False
         # cost-ledger identity (the gateway stamps the model name) and
@@ -466,7 +508,8 @@ class Scheduler:
 
     def step(self, waited_since=None):
         """One scheduling iteration (expire → admit → prefill chunks →
-        decode → retire). Returns True if any progress was made.
+        launch the next decode step → fetch the last one → retire).
+        Returns True if any progress was made.
         `waited_since`: the caller's stamp from before it waited for the
         lock it now holds (`ServeEngine.step`).
 
@@ -487,15 +530,16 @@ class Scheduler:
                 self._expire(now)
                 admitted = self._admit(now, clock.t_start)
             chunks = self._prefill_step(clock)
-            decoding = self._n_decoding
             decoded = self._decode_once(clock)
             with tracing.phase("mx.serve.emit", "emit"):
                 self._refresh_gauges()
             clock.progressed = bool(admitted or chunks or decoded)
-            # the engine's decode launch counted its pages on the clock
+            # the decode launch counted its rows and pages on the clock,
+            # the fetch its overshoot rows
             clock.counts = {
-                "pages_live": 0, "pages_view": 0, **clock.counts,
-                "chunks": chunks, "decoding": decoding if decoded else 0,
+                "pages_live": 0, "pages_view": 0, "decoding": 0,
+                "mode": None, "overshoot": 0, **clock.counts,
+                "chunks": chunks,
                 "prefilling": self._n_active - self._n_decoding,
                 "queued": len(self._queue)}
         for ph, seconds in clock.seconds.items():
@@ -529,9 +573,13 @@ class Scheduler:
             req._fail(DeadlineExceeded(
                 f"request {req.id} expired after "
                 f"{now - req.submit_t:.3f}s in queue"), now)
-        for s, req in enumerate(self._in_slot):
-            if req is not None and req.deadline is not None \
-                    and now > req.deadline:
+        late = [(s, req) for s, req in enumerate(self._in_slot)
+                if req is not None and req.deadline is not None
+                and now > req.deadline]
+        if late:
+            self.settle()     # what the step in flight made is theirs
+        for s, req in late:
+            if self._in_slot[s] is req:       # not finished by that step
                 self._retire(s, "deadline", now)
 
     def _admit(self, now, t_step):
@@ -592,7 +640,10 @@ class Scheduler:
         """ONE chunk for every slot still prefilling — chunks of
         different requests and decode steps interleave, so a long prompt
         cannot stall the engine for its whole prefill. The final chunk
-        emits the request's first token and flips the slot to decoding."""
+        emits the request's first token and flips the slot to decoding.
+        A chunk is launched, and only a prompt's final chunk is waited
+        for: the token an intermediate chunk samples is never fetched, so
+        the host goes on to queue the next program behind it."""
         ran = 0
         for slot, req in enumerate(self._in_slot):
             if req is None or req.prefill_pos >= req.prompt.size:
@@ -603,13 +654,20 @@ class Scheduler:
             if self._lazy_pages:
                 self._map_positions(slot, req, t0, t0 + n)
             # the chunk's window, from the step clock's stamps: the
-            # boundary before it to the end of its read-back (the engine
-            # stamps launch and read-back apart inside)
+            # boundary before it to the end of its launch (the engine
+            # stamps that inside) or, for a final chunk, of its read-back
             w0 = clock.cursor
             first, bucket, pad = self.slots.prefill_chunk_step(
                 slot, req.prompt[t0:t0 + n], t0, self._next_key,
                 req.temperature)
-            w1 = clock.lap("prefill_readback")
+            if last_chunk:
+                with tracing.phase("mx.serve.prefill.readback",
+                                   "prefill_readback"):
+                    first = int(first)    # blocks until the chunk ran
+                w1 = clock.cursor
+            else:
+                w1 = clock.lap("prefill_launch")
+            PREFILL_FETCHES["final" if last_chunk else "skipped"].inc()
             # exact per-slot prefill attribution, one window shared by
             # the capacity + anatomy ledgers (dead branches while off)
             capacity.split_device_seconds(
@@ -674,10 +732,55 @@ class Scheduler:
         return "length"
 
     def _decode_once(self, clock):
-        if self._n_decoding == 0:
-            return False
+        """Launch the next decode step, then fetch the one launched an
+        iteration ago and hand its tokens out: the host's work for a
+        launch lies under the device's work on the step before."""
         if self._spec_k:
+            # the draft needs the verified tokens on the host: its round
+            # fetches what it launches
+            if not self._n_decoding:
+                return False
+            clock.counts["decoding"] = self._n_decoding
             return self._spec_decode_once(clock)
+        landing = self._flight
+        if not self._n_decoding and landing is None:
+            return False
+        # the window of the iteration's decode work, from the step
+        # clock's stamps: the launch (the engine stamps it inside), then
+        # the blocking fetch
+        w0 = clock.cursor
+        launched = None
+        if self._n_decoding:
+            clock.counts["mode"] = self._launch()
+            launched = self._flight
+            if landing is None and isinstance(launched[0], onp.ndarray):
+                # a slots object that computes on the host: its tokens
+                # are here already, nothing is in flight
+                landing = launched
+        w1 = clock.cursor if landing is None else self._land(landing)
+        self._decode_window(w0, w1, (landing or launched)[1])
+        return True
+
+    def _decode_window(self, w0, w1, rows):
+        """The armed span and the ledgers' charge for one window of decode
+        work (a launch, a fetch, or a launch and the fetch after it)."""
+        tracing.record_span("serve.decode_step", w0, w1, slots=len(rows))
+        self._charge_decode(w0, w1, rows)
+
+    def _launch(self):
+        """Queue one decode program over the decoding slots, behind the
+        step in flight where there is one (mode ``"ahead"``; else
+        ``"cold"``: the device waits while the host launches). A slot that
+        goes on from the step in flight takes its last token from that
+        step's output on the device (a negative entry of ``last_tok``,
+        `SlotDecoder.decode_step`); one that turned to decoding since, or
+        was adopted, takes the host's. What the host knows in advance it
+        acts on now: ``pos`` rises with the launch, and a request whose
+        token on its way is its ``max_new``-th leaves its slot at once
+        (no row, no write and no key are spent on a step it cannot use;
+        the programs that may touch its pages again are queued behind
+        this one). Returns the mode."""
+        ahead = self._flight
         if self._lazy_pages:
             for s, req in enumerate(self._in_slot):
                 if req is not None and self._active[s]:
@@ -686,39 +789,86 @@ class Scheduler:
                             or self.slots.rolls_before(pos):
                         self._map_positions(s, req, pos, min(
                             self.slots.window_end(pos), self._written(req)))
-        # the decode call's window, from the step clock's stamps (the
-        # engine stamps launch and read-back apart inside it)
-        w0 = clock.cursor
-        nxt = self.slots.decode_step(self._last, self._pos, self._active,
-                                     self._next_key, self._temps)
-        w1 = clock.lap("decode_readback")
-        tracing.record_span("serve.decode_step", w0, w1,
-                            slots=self._n_decoding)
-        self._charge_decode(w0, w1)
+        last = self._last
+        if ahead is not None:
+            last = last.copy()
+            for s, req in ahead[1]:
+                if req.slot == s:             # goes on from that step
+                    last[s] = -1
+        rows = self._decoding_rows()
+        tokens = self.slots.decode_step(last, self._pos, self._active,
+                                        self._next_key, self._temps)
+        mode = "cold" if ahead is None else "ahead"
+        DECODE_LAUNCHES[mode].inc()
+        tracing.count(decoding=len(rows))
+        for s, req in rows:
+            req.inflight += 1
+            self._pos[s] += 1
+            if len(req.tokens) + req.inflight >= req.max_new:
+                self._release(s, "length")
+        self._flight = (tokens, rows)
+        return mode
+
+    def _land(self, flight):
+        """Fetch a launched decode step's tokens (the one host sync of a
+        step) and hand them out; retire what an EOS ends, and close what
+        `_launch` freed in advance. A row whose request has ended since it
+        was launched (an EOS learnt a step late) is an overshoot: its
+        token is dropped, its write went to the request's own page or the
+        trash page. Returns the stamp at which the fetch ended."""
+        if flight is self._flight:
+            self._flight = None
+        tokens, rows = flight
+        with tracing.phase("mx.serve.decode.readback", "decode_readback"):
+            tokens = onp.asarray(tokens)      # blocks until that step ran
+        fetched = tracing.stamp()
         with tracing.phase("mx.serve.emit", "emit"):
             now = time.monotonic()
-            for s, req in enumerate(self._in_slot):
-                if req is None or not self._active[s]:
-                    continue                   # free or still prefilling
-                tok = int(nxt[s])
+            for s, req in rows:
+                req.inflight -= 1
+                if req.state != "running":
+                    OVERSHOOT_ROWS.inc()
+                    tracing.count(overshoot=1)
+                    continue
+                tok = int(tokens[s])
                 req._emit(tok, now)
-                self._pos[s] += 1
-                self._last[s] = tok
-                if self._is_complete(req, tok):
+                if not self._is_complete(req, tok):
+                    if req.slot is not None:
+                        self._last[s] = tok
+                elif req.slot is None:        # `_launch` freed its slot
+                    self._close_request(
+                        req, self._complete_reason(req, tok), now)
+                else:
                     self._retire(s, self._complete_reason(req, tok), now)
-        return True
+        return fetched
 
-    def _charge_decode(self, w0, w1):
+    def settle(self):
+        """Fetch the decode step in flight, if there is one, and hand its
+        tokens out. Whatever changes a slot outside an iteration's own
+        order calls this first: afterwards ``pos``, ``last`` and every
+        request's tokens say the same thing."""
+        flight = self._flight
+        if flight is not None:
+            w0 = tracing.stamp()
+            self._decode_window(w0, self._land(flight), flight[1])
+
+    def _charge_decode(self, w0, w1, rows=None):
         """ONE batched program (or draft + verify round) per step: split
-        its window evenly across the slots that decoded in it (capacity)
-        and charge the replica's decode residency (anatomy). Dead
-        branches while both are off. Returns the anatomy's delta."""
+        its window evenly across the requests of its `rows` (default: the
+        slots decoding now) (capacity) and charge the replica's decode
+        residency (anatomy). Dead branches while both are off. Returns
+        the anatomy's delta."""
         if capacity._ENABLED:
+            if rows is None:
+                rows = self._decoding_rows()
             capacity.split_device_seconds(
-                [r.tenant for s, r in enumerate(self._in_slot)
-                 if r is not None and self._active[s]],
+                [req.tenant for _, req in rows],
                 self.capacity_model, "decode", w1 - w0)
         return anatomy.on_decode_step(self, w0, w1)
+
+    def _decoding_rows(self):
+        return [(s, req) for s, req in enumerate(self._in_slot)
+                if req is not None and self._active[s]]
 
     def _map_positions(self, slot, req, pos, end):
         """A slot with `lazy_pages`, before it writes rows at positions
@@ -879,8 +1029,12 @@ class Scheduler:
         """Free `slot` and dereference its pages (shared pages survive
         via their cache/peer references). EOS/length retirement completes
         the request; deadline/shutdown fails it."""
+        self._close_request(self._release(slot, reason), reason, now)
+
+    def _release(self, slot, reason):
+        """Free `slot` for the next admission and dereference its pages;
+        returns the request that held it, now in no slot and not closed."""
         req = self._in_slot[slot]
-        req._spans.get("request", _NULL).annotate(reason=reason)
         self._in_slot[slot] = None
         if self._active[slot]:
             self._n_decoding -= 1
@@ -898,8 +1052,15 @@ class Scheduler:
         EVICTIONS.inc()
         registry.counter(
             "mx_serve_evictions_total",
-            "slots freed (EOS / length / deadline / shutdown)",
+            "slots freed (EOS / length / deadline / shutdown / "
+            "preemption)",
             labels={"reason": reason}).inc()
+        return req
+
+    def _close_request(self, req, reason, now):
+        """The end of a request that is in no slot any more: EOS/length
+        completes it, deadline/shutdown fails it."""
+        req._spans.get("request", _NULL).annotate(reason=reason)
         if reason == "deadline":
             req._fail(DeadlineExceeded(
                 f"request {req.id} expired mid-flight after "
@@ -930,6 +1091,9 @@ class Scheduler:
         closed; the caller owns re-queueing the remaining work)."""
         if now is None:
             now = time.monotonic()
+        # the tokens of the step in flight are the request's (one of them
+        # may be its last: then the slot is free and nothing is preempted)
+        self.settle()
         req = self._in_slot[slot]
         if req is None:
             raise ValueError(f"slot {slot} is empty — nothing to preempt")
@@ -945,27 +1109,8 @@ class Scheduler:
             seq = onp.concatenate(
                 [req.prompt, onp.asarray(gen, onp.int32)])[:aligned]
             self.slots.prefix_cache.register(seq, req.pages[:aligned // pt])
-        self._in_slot[slot] = None
-        if self._active[slot]:
-            self._n_decoding -= 1
-        self._active[slot] = False
-        self._pos[slot] = 0
-        self._last[slot] = 0
-        self._temps[slot] = 1.0
-        self._n_active -= 1
-        if req.pages:
-            self.slots.allocator.decref(req.pages)
-        req.pages = None
-        req.spec_reserved = 0
-        self.slots.clear_slot(slot)
-        req.slot = None
+        self._release(slot, "preempted")
         req.state = "preempted"
-        EVICTIONS.inc()
-        registry.counter(
-            "mx_serve_evictions_total",
-            "slots freed (EOS / length / deadline / shutdown / "
-            "preemption)",
-            labels={"reason": "preempted"}).inc()
         req._spans.get("request", _NULL).annotate(
             reason="preempted", resident_tokens=resident,
             kept_pages=aligned // pt)
@@ -1114,11 +1259,20 @@ class Scheduler:
 
     @property
     def n_active(self):
-        return self._n_active
+        """Requests the engine still owes tokens: the occupied slots, and
+        the requests whose slot was freed when their last step was
+        launched and whose last token is still in flight."""
+        n = self._n_active
+        if self._flight is not None:
+            n += sum(1 for _, req in self._flight[1]
+                     if req.slot is None and req.state == "running")
+        return n
 
     @property
     def idle(self):
-        return self._n_active == 0 and not self._queue
+        """Nothing queued, no slot occupied, no step in flight."""
+        return self._n_active == 0 and not self._queue \
+            and self._flight is None
 
     def close(self, drain):
         """Stop admission. With ``drain`` the caller keeps stepping until
@@ -1126,6 +1280,7 @@ class Scheduler:
         chunks first); queued (never-started) requests are failed either
         way — "finish running slots, reject new work"."""
         self.closed = True
+        self.settle()         # what the step in flight made is delivered
         now = time.monotonic()
         while self._queue:
             req = self._queue.popleft()
@@ -1156,8 +1311,11 @@ class Scheduler:
         handles and re-queues the work on a surviving replica). Unlike
         `close`, nothing is failed — the Requests still here are
         orphaned engine segments whose tenants have moved on; queue and
-        slots are dropped as-is so the page pool can be released."""
+        slots are dropped as-is so the page pool can be released. The step
+        in flight is dropped unfetched with them: its tokens were handed
+        to nobody, so the re-queued work makes them again, once."""
         self.closed = True
+        self._flight = None
         self._queue.clear()
         self._in_slot = [None] * self.slots.max_slots
         self._prefilled = []

@@ -370,6 +370,11 @@ class ShardedSlotDecoder(SlotDecoder):
     def _constrain_pools(self, pk, pv, sk, sv):
         return self.layout.constrain_pools(pk, pv, sk, sv)
 
+    def _pin_tokens(self, tokens):
+        jax = _j()
+        return jax.lax.with_sharding_constraint(
+            tokens, self.layout.sharding(jax.sharding.PartitionSpec()))
+
     def _place_migrated(self, leaves, name):
         """A disagg page-migration scatter runs eagerly, so its outputs
         carry whatever sharding the eager op picked — re-pin them to the

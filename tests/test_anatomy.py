@@ -14,6 +14,7 @@ prefill/decode pod the migrated request's ``handoff_migration`` state
 is nonzero, its states sum to its measured wall within 2%, and the
 decode replica's residency is decode-dominated.
 """
+import gc
 import json
 import os
 import sys
@@ -37,6 +38,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 @pytest.fixture(autouse=True)
 def _armed_anatomy():
     injection.clear_injection()
+    # a gateway an earlier test file left to the garbage collector still
+    # answers `mx_gateway_queue_depth` (a pull gauge over its queues) in
+    # place of the value a test here sets
+    gc.collect()
     registry.reset()
     anatomy.reset()
     anatomy.enable()

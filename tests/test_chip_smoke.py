@@ -47,4 +47,32 @@ def test_eva_phase_rehearsal_crosses_a_roll_and_agrees_with_the_reference(capsys
     """The EvaByte phase at its toy size on the CPU: one roll in prefill,
     one in decode, tokens the reference's."""
     chip_smoke.eva_phase(chip_smoke.EVA_SIZES[True], 3)
-    assert "across 2 rolls" in capsys.readouterr().out
+    out = capsys.readouterr().out
+    assert "across 2 rolls" in out
+    # the step records' account of the decode launches (ISSUE 30)
+    line = next(ln for ln in out.splitlines() if "eva: " in ln
+                and "decode launches" in ln)
+    assert "overshoot rows 0" in line and "(0.0 %)" not in line
+
+
+def test_serve_phase_rehearsal_says_how_the_decode_launches_were_made(capsys):
+    """The GPT serve phase at its toy size on the CPU: the phases still
+    cover the steps' wall with launch and fetch in different iterations,
+    and the phase prints the share of launches made ahead of the last fetch
+    and the overshoot count."""
+    chip_smoke.serve_phase(chip_smoke.SERVE_SIZES[True], 0,
+                           chip_smoke.CompileWatch())
+    out = capsys.readouterr().out
+    line = next(ln for ln in out.splitlines() if "serve: " in ln
+                and "decode launches" in ln)
+    assert "made ahead of the last fetch" in line
+    assert "overshoot rows 0" in line and "(0.0 %)" not in line
+
+
+def test_launch_modes_refuses_a_run_with_no_launch_made_ahead():
+    steps = [{"decoding": 2, "chunks": 0, "mode": "cold", "overshoot": 0}] * 3
+    with pytest.raises(RuntimeError, match="0 decode launches made ahead"):
+        chip_smoke.launch_modes(steps, "serve")
+    steps = [{"decoding": 2, "chunks": 0, "mode": "ahead", "overshoot": 1}]
+    with pytest.raises(RuntimeError, match="1 overshoot rows"):
+        chip_smoke.launch_modes(steps, "serve")

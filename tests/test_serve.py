@@ -591,6 +591,100 @@ def test_engine_drain_finishes_running_rejects_new(net, ref_dec):
         e.submit(prompts[0], 4)
 
 
+# -- the decode step in flight (ISSUE 30), through `ServeEngine` ---------------
+
+def _serial(engine):
+    """The serial order of work on the same programs: a `decode_step` that
+    hands back host tokens leaves nothing in flight."""
+    slots = engine._sched.slots
+    inner = slots.decode_step
+    slots.decode_step = lambda *a: onp.asarray(inner(*a))
+
+
+def _references(ref_dec, prompts, budgets):
+    return [[int(t) for t in ref_dec.generate(p[None, :], b).asnumpy()[0]
+             [p.size:]] for p, b in zip(prompts, budgets)]
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_manual_step_loop_until_idle_delivers_every_token(net, kv_dtype):
+    """`while eng.step()`: a step that only fetches the step in flight still
+    counts as progress, so the loop does not stop a token short; and the
+    tokens are those of an engine that fetches every step before the next
+    (a `decode_step` handing back host tokens: the serial order)."""
+    prompts, budgets = _mixed_requests(7, seed=11)
+    outs = []
+    for serial in (False, True):
+        e = serve.ServeEngine(net, max_slots=3, max_len=64, max_queue=32,
+                              kv_dtype=kv_dtype)
+        slots = e._sched.slots
+        if serial:
+            _serial(e)
+        handles = [e.submit(p, b) for p, b in zip(prompts, budgets)]
+        in_flight = 0
+        while e.step():
+            in_flight += e._sched._flight is not None
+        assert all(h.done for h in handles) and e.n_active == 0
+        assert (in_flight > 0) == (not serial)
+        outs.append([h.result() for h in handles])
+        assert [len(o) for o in outs[-1]] == budgets
+        e.shutdown(drain=False)
+        assert slots.allocator.used_pages == 0
+    assert outs[0] == outs[1]
+
+
+def test_sampled_tokens_do_not_depend_on_when_they_are_fetched(net):
+    """Sampling at a fixed seed: the key counter is consumed chunk by chunk
+    and launch by launch in the serial order's sequence, so a step in
+    flight changes no sampled token."""
+    prompts, budgets = _mixed_requests(7, seed=12)
+    outs, keys = [], []
+    for serial in (False, True):
+        e = serve.ServeEngine(net, max_slots=3, max_len=64, max_queue=32,
+                              do_sample=True, top_k=5, temperature=0.9,
+                              seed=17)
+        if serial:
+            _serial(e)
+        handles = [e.submit(p, b) for p, b in zip(prompts, budgets)]
+        e._drive_until(handles)
+        outs.append([h.result() for h in handles])
+        keys.append(e._sched._key_ctr)
+        e.shutdown(drain=False)
+    assert outs[0] == outs[1] and keys[0] == keys[1]
+    assert [len(o) for o in outs[0]] == budgets
+
+
+def test_shutdown_drain_with_a_step_in_flight(net, ref_dec):
+    """`shutdown(drain=True)` on a hand-stepped engine whose only request's
+    last token is still on the device: delivered, not dropped."""
+    e = serve.ServeEngine(net, max_slots=2, max_len=64, max_queue=8)
+    p = _prompt(6, seed=3)
+    h = e.submit(p, 4)
+    while not (e._sched._flight is not None and h.slot is None):
+        assert e.step()
+    assert not h.done and e.n_active == 1      # owed, though no slot is held
+    e.shutdown(drain=True)
+    assert h.result() == _references(ref_dec, [p], [4])[0]
+
+
+def test_eos_overshoot_through_the_engine(eng, ref_dec):
+    """An EOS ends a request a step after the device made it: the row
+    already launched is dropped and counted, and no token follows the EOS."""
+    from incubator_mxnet_tpu.serve.scheduler import OVERSHOOT_ROWS
+
+    p = _prompt(9, seed=21)
+    ref = _references(ref_dec, [p], [10])[0]
+    at = next(i for i in range(1, 9) if ref[i] not in ref[:i])
+    before = OVERSHOOT_ROWS.value
+    h = eng.submit(p, 10, eos_id=ref[at])
+    eng._drive_until([h])
+    assert h.result() == ref[:at + 1]
+    # the request is over; the step launched before its EOS was seen is not
+    assert not eng._sched.idle and eng.step() is True
+    assert OVERSHOOT_ROWS.value == before + 1
+    assert eng.n_active == 0 and eng._sched.idle
+
+
 @pytest.mark.slow
 def test_bench_gpt_serve_contract():
     """The bench lands real numbers under the loud-failure contract:
@@ -811,7 +905,8 @@ def test_per_layer_pool_ledger_decode_cost_flat(net, monkeypatch):
             text = slots._build_decode().trace(
                 slots._dec._params, slots._pk, slots._pv,
                 sds((S, P), jnp.int32), sds((S,), jnp.int32),
-                sds((S,), jnp.int32), sds((S,), jnp.bool_),
+                sds((S,), jnp.int32), sds((S,), jnp.int32),
+                sds((S,), jnp.bool_),
                 jax.random.PRNGKey(0), sds((S,), jnp.float32),
                 top_k=None, do_sample=False).lower(
                 lowering_platforms=("tpu",)).as_text()

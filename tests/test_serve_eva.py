@@ -95,6 +95,64 @@ def test_step_records_charge_the_roll_inside_wall(dec):
     eng.shutdown(drain=False)
 
 
+def test_a_roll_with_a_step_in_flight_serves_the_references_tokens(dec):
+    """Two slots in decode; one crosses a window boundary while the step
+    before it is still unfetched: the roll is queued behind that step, the
+    freed window pages are written again only by programs queued later, and
+    the served tokens are the plain reference's (teacher-forced, head 0)."""
+    tracing.reset()
+    eng = mx.serve.ServeEngine(dec, **ENGINE)
+    rng = onp.random.default_rng(3)
+    prompts = [rng.integers(0, 50, n).astype(onp.int32) for n in (27, 50)]
+    new = [30, 30]              # rolls in decode at 32 and at 64
+    handles = [eng.submit(p, n) for p, n in zip(prompts, new)]
+    drive(eng, handles)
+    outs = [h.result() for h in handles]
+    rolled_ahead = [r for r in tracing.step_records()
+                    if r["eva_roll"] > 0 and r["mode"] == "ahead"
+                    and not r["chunks"]]
+    assert len(rolled_ahead) == 2           # both decode rolls, a step ahead
+    tokens = onp.zeros((2, 160), onp.int32)
+    rows = []
+    for b, (p, o) in enumerate(zip(prompts, outs)):
+        seq = onp.concatenate([p, onp.asarray(o, onp.int32)])
+        tokens[b, :seq.size - 1] = seq[:-1]
+        rows += [(b, p.size - 1 + j) for j in range(len(o))]
+    logits = ref.logits_at(CFG, 7, tokens, rows)
+    served = onp.concatenate([onp.asarray(o) for o in outs])
+    gap = logits.max(-1) - logits[onp.arange(served.size), served]
+    assert served.size == 60 and gap.max() <= 1e-4
+    slots = eng._sched.slots
+    assert slots.allocator.free_pages == slots.allocator.usable_pages
+    assert eng._sched.idle
+    eng.shutdown(drain=False)
+
+
+def test_an_eos_after_a_roll_gives_every_page_back(dec):
+    """The overshoot row of an EOS learnt a step late may have mapped a page
+    or rolled a window for a request that was already over: retirement
+    gives back whatever the slot holds."""
+    eng = mx.serve.ServeEngine(dec, **ENGINE)
+    p = onp.random.default_rng(5).integers(0, 50, 29).astype(onp.int32)
+    h = eng.submit(p, 12)
+    drive(eng, [h])
+    free = h.result()
+    # the token served at position 31: the row after it opens a window
+    eos = free[2]
+    assert eos not in free[:2]
+    from incubator_mxnet_tpu.serve.scheduler import OVERSHOOT_ROWS
+
+    before = OVERSHOOT_ROWS.value
+    h2 = eng.submit(p, 12, eos_id=int(eos))
+    drive(eng, [h2])
+    assert h2.result() == free[:3]
+    assert eng.step() is True       # fetches the step launched ahead
+    assert OVERSHOOT_ROWS.value == before + 1
+    slots = eng._sched.slots
+    assert slots.allocator.free_pages == slots.allocator.usable_pages
+    eng.shutdown(drain=False)
+
+
 def test_summaries_are_the_references():
     rng = onp.random.default_rng(1)
     k, v = (rng.normal(size=(24, 3, 8)).astype(onp.float32) for _ in range(2))

@@ -107,7 +107,8 @@ def test_every_progressed_step_leaves_one_record(served):
     assert [r["t_start"] for r in recs] == sorted(r["t_start"] for r in recs)
     assert set(recs[0]) == {"t_start", "wall", "chunks", "decoding",
                             "prefilling", "queued", "pages_live",
-                            "pages_view", *tracing.PHASES}
+                            "pages_view", "mode", "overshoot",
+                            *tracing.PHASES}
 
 
 def test_phases_are_non_negative_and_add_up_to_at_most_wall(served):
@@ -130,6 +131,45 @@ def test_decoding_is_the_active_slots_of_each_decode_launch(served):
     for r in tracing.step_records():
         assert (r["decode_launch"] > 0.0) == (r["decoding"] > 0)
         assert (r["prefill_launch"] > 0.0) == (r["chunks"] > 0)
+
+
+def test_mode_says_how_each_decode_launch_was_made(served):
+    """`mode`: "ahead" where the step before was still unfetched when this
+    one was queued, "cold" where nothing was in flight, None where the
+    iteration launched no decode step (it may still have fetched one)."""
+    recs = tracing.step_records()
+    for r in recs:
+        assert (r["mode"] in ("ahead", "cold")) == (r["decoding"] > 0), r
+        assert r["overshoot"] == 0          # every request ended by length
+    modes = [r["mode"] for r in recs if r["mode"]]
+    assert modes[0] == "cold" and modes.count("ahead") > modes.count("cold")
+    # the tokens of the last launch are fetched by an iteration of their own
+    assert recs[-1]["mode"] is None and recs[-1]["decode_readback"] > 0.0
+
+
+def test_an_intermediate_chunk_records_no_prefill_readback(net):
+    """A 40-token prompt alone: three chunks in three iterations, and only
+    the last of them is waited for (its token is the request's first)."""
+    eng = _engine(net)
+    try:
+        h = eng.submit(PROMPTS[1], 2)
+        while not h.done:
+            eng.step()
+    finally:
+        eng.shutdown(drain=False)
+    chunked = [r for r in tracing.step_records() if r["chunks"]]
+    assert [r["chunks"] for r in chunked] == [1, 1, 1]
+    assert [r["prefill_readback"] > 0.0 for r in chunked] \
+        == [False, False, True]
+    assert all(r["prefill_launch"] > 0.0 for r in chunked)
+
+
+def test_phases_cover_the_steps_wall_with_a_step_in_flight(served):
+    """Launch and fetch now lie in different iterations; together with the
+    other phases they still account for the loop's time."""
+    recs = tracing.step_records()
+    covered = sum(r[ph] for r in recs for ph in PHASES)
+    assert covered >= 0.99 * sum(r["wall"] for r in recs)
 
 
 def test_records_window_by_start_and_are_copies(served):

@@ -273,6 +273,28 @@ def test_tp_mesh_parity_two_families_and_clean_shardcheck(net):
         compiles.reset()
 
 
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_tp_mesh_step_in_flight_one_decode_program(net, kv_dtype):
+    """The sharded engine inherits `decode_step`: the tokens of a launch
+    come back replicated over the mesh and the next launch takes them as
+    they are, so the first launch (zeros placed the same way) and every
+    later one share ONE compiled decode program; tokens are those of the
+    same engine fetching every step before it launches the next."""
+    prompts = [_prompt(n, seed=i) for i, n in enumerate((5, 19, 33, 8, 12))]
+    outs = []
+    for serial in (False, True):
+        slots = ShardedSlotDecoder(net, mesh=_mesh(2), max_slots=3,
+                                   max_len=64, prefill_chunk=16,
+                                   page_tokens=8, kv_dtype=kv_dtype)
+        if serial:
+            inner = slots.decode_step
+            slots.decode_step = lambda *a: onp.asarray(inner(*a))
+        outs.append(_serve_tokens(slots, prompts, max_new=7))
+        assert slots._decode_jit._cache_size() == 1
+        assert tuple(slots._tokens.sharding.spec) == ()
+    assert outs[0] == outs[1] and all(len(o) == 7 for o in outs[0])
+
+
 def test_tp_mesh_int8_kv_runs_with_clean_shardcheck(net):
     sh = ShardedSlotDecoder(net, mesh=_mesh(2), max_slots=2, max_len=64,
                             n_pages=24, kv_dtype="int8")
