@@ -1491,7 +1491,7 @@ def bench_gpt_serve_sharded(requests=16, max_slots=4, prompt_max=40,
                     for rec in report["decode"].collectives.values())
                 out["collective_bytes_per_token"] = step_bytes / max_slots
                 # HBM-capacity story: each device holds 1/tp of the pools
-                pools = list(eng._pk) + list(eng._pv)
+                pools = jax.tree.leaves(eng._pools)
                 out["kv_bytes_total"] = sum(x.nbytes for x in pools)
                 out["kv_bytes_per_device"] = sum(
                     x.addressable_shards[0].data.nbytes for x in pools)

@@ -527,7 +527,7 @@ def sharded_serve_phase(cfg, seed, n_chips):
 
         # every chip holds its share: KV pools and a column-parallel weight
         sh = engines["sharded"]
-        pools = list(sh._pk) + list(sh._pv)  # noqa: SLF001
+        pools = jax.tree.leaves(sh._pools)  # noqa: SLF001
         total = sum(x.nbytes for x in pools)
         per_dev = {}
         for x in pools:

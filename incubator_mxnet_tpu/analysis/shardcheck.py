@@ -51,9 +51,12 @@ _REPLICATED_MIN_BYTES = 1 << 20
 # result-shape regex: `%x = f32[128,64]{1,0} all-gather(f32[64,64] ...)`.
 _HLO_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter",
                     "collective-permute", "all-to-all")
+# the result type — one shape, or a tuple of them where XLA combined several
+# operands into one collective — then the op
 _HLO_RESULT_RE = re.compile(
-    r"=\s+(?:\(?\s*)([a-z0-9]+)\[([0-9,]*)\][^\s]*\s+"
-    r"(" + "|".join(_HLO_COLLECTIVES) + r")(?:-start|-done)?\(")
+    r"=\s+(\([^()]*\)|[a-z0-9]+\[[0-9,]*\]\S*)\s+"
+    r"(" + "|".join(_HLO_COLLECTIVES) + r")(-start|-done)?\(")
+_HLO_SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
 _HLO_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
                  "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
                  "s32": 4, "u32": 4, "f32": 4,
@@ -62,6 +65,10 @@ _HLO_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
 # jaxpr primitives that are explicit cross-shard transfers (shard_map /
 # pmap-style code); GSPMD-inserted ones only appear in tier 3.
 _JAXPR_COLLECTIVES = {"psum": "all-reduce", "psum2": "all-reduce",
+                      # what `lax.psum` / `all_gather` trace to under a
+                      # `shard_map` that checks varying axes (jax >= 0.7)
+                      "psum_invariant": "all-reduce",
+                      "all_gather_invariant": "all-gather",
                       "all_gather": "all-gather",
                       "reduce_scatter": "reduce-scatter",
                       "psum_scatter": "reduce-scatter",
@@ -261,15 +268,14 @@ def _scan_jaxpr(jaxpr, collectives):
 def _scan_hlo(hlo_text, collectives):
     """Collective census over compiled HLO: count + bytes of each result."""
     for m in _HLO_RESULT_RE.finditer(hlo_text):
-        dtype, dims, op = m.group(1), m.group(2), m.group(3)
-        item = _HLO_ITEMSIZE.get(dtype, 4)
-        n = 1
-        for d in dims.split(","):
-            if d.strip():
-                n *= int(d)
-        rec = collectives.setdefault(op, {"count": 0, "bytes": 0})
+        shapes = _HLO_SHAPE_RE.findall(m.group(1))
+        if m.group(3) == "-start":      # (operand alias, result, ...)
+            shapes = shapes[:1]
+        rec = collectives.setdefault(m.group(2), {"count": 0, "bytes": 0})
         rec["count"] += 1
-        rec["bytes"] += n * item
+        for dtype, dims in shapes:
+            rec["bytes"] += _HLO_ITEMSIZE.get(dtype, 4) * math.prod(
+                int(d) for d in dims.split(",") if d.strip())
 
 
 def _match_donations(report, leaves, out_leaves, donate_argnums):

@@ -19,7 +19,11 @@ program:
 
 The layer math mirrors `GPTModel.forward` exactly (pre-norm blocks,
 gelu FFN, tied LM head) — greedy decode emits the same tokens as the
-eager full-forward loop, asserted by `tests/test_gpt.py`.
+eager full-forward loop, asserted by `tests/test_gpt.py`. It is written
+once, `GPTDecoder.layer`, against a cache-access object: `generate` hands
+it two small dense caches (`_PromptCache`, `_StepCache`), `mx.serve`'s
+programs their paged ones (`serve/pages.py`), and `generate` is the
+reference the engine's tests compare those programs against.
 """
 from __future__ import annotations
 
@@ -134,7 +138,6 @@ def _ln(x, g, b, eps=1e-5):
 
 def _dense(x, w, b=None):
     """`npx.fully_connected(flatten=False)`: y = x @ W^T (+ b)."""
-    jnp = _j().numpy
     y = x @ w.T
     return y if b is None else y + b
 
@@ -150,6 +153,53 @@ def _split_qkv(h, n_heads):
     k = jnp.transpose(qkv[:, :, 1], (0, 2, 1, 3))
     v = jnp.transpose(qkv[:, :, 2], (0, 2, 1, 3))
     return q, k, v
+
+
+class _PromptCache:
+    """`GPTDecoder.generate`'s prefill as a cache-access object: causal
+    flash attention over the whole prompt; keeps its ``k, v`` padded to
+    `cache_len` rows for the decode steps."""
+
+    def __init__(self, cache_len):
+        self.cache_len = cache_len
+
+    def attend(self, li, q, k, v):  # noqa: ARG002
+        jnp = _j().numpy
+        from ..ops.flash_attention import flash_attention
+
+        o = flash_attention(q, k, v, causal=True,
+                            sm_scale=1.0 / math.sqrt(q.shape[-1]))
+        pad = [(0, 0), (0, 0), (0, self.cache_len - q.shape[2]), (0, 0)]
+        self.k, self.v = jnp.pad(k, pad), jnp.pad(v, pad)
+        return jnp.transpose(o, (0, 2, 1, 3))
+
+
+class _StepCache:
+    """One decode step of `GPTDecoder.generate` against one layer's dense
+    cache ``k, v`` (N, H, cache_len, d): the token's row is written at
+    `pos` and its query attends rows ``0 .. pos``."""
+
+    def __init__(self, k, v, pos):
+        self.k, self.v, self.pos = k, v, pos
+
+    def attend(self, li, q, k, v):  # noqa: ARG002
+        jax = _j()
+        jnp = jax.numpy
+        # write this token's k/v at position pos (static-shape update)
+        ck = self.k = jax.lax.dynamic_update_slice(self.k, k,
+                                                   (0, 0, self.pos, 0))
+        cv = self.v = jax.lax.dynamic_update_slice(self.v, v,
+                                                   (0, 0, self.pos, 0))
+        # attend to positions 0..pos; later slots hold zeros/garbage that
+        # the mask excludes (f32 scores for a stable softmax)
+        s = jnp.einsum("nhqd,nhkd->nhqk", q, ck,
+                       preferred_element_type=jnp.float32)
+        s = s / math.sqrt(q.shape[-1])
+        mask = jnp.arange(ck.shape[2]) <= self.pos
+        s = jnp.where(mask[None, None, None, :], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1).astype(cv.dtype)
+        o = jnp.einsum("nhqk,nhkd->nhqd", p, cv)
+        return jnp.transpose(o, (0, 2, 1, 3))
 
 
 class GPTDecoder:
@@ -225,64 +275,62 @@ class GPTDecoder:
             self._params = self._extract_params(self._model)
             self._param_ids = ids
 
-    # -- math ---------------------------------------------------------------
+    # -- the mathematics (traced) --------------------------------------------
 
-    def _logits(self, params, x):
+    def kv_geometry(self):
+        """``(layers, heads, head size, dtype)`` of the K/V rows a cache
+        holds for this model."""
+        layers = self._params["layers"]
+        return (int(layers["ln1_g"].shape[0]), self._n_heads,
+                self._units // self._n_heads, layers["qkv_w"].dtype)
+
+    def embed(self, params, tokens, pos):
+        """``tokens`` (N, T) at positions ``pos`` (N, T) or (T,), clamped to
+        the position table: ``x`` (N, T, C). ``tokens`` (N,), one new row
+        a sequence, at ``pos`` (N,): ``x`` (N, 1, C), the axis added after
+        the gathers (gathered as ``(N, 1)`` the chip lays the rows out
+        otherwise and a decode step of GPT-2 XL takes 0.11 ms more:
+        `PERF.md` §6, PR 31)."""
+        jnp = _j().numpy
+        pos = jnp.clip(pos, 0, params["pos"].shape[0] - 1)
+        e, p = params["embed"][tokens], params["pos"][pos]
+        if tokens.ndim == 1:
+            e, p = e[:, None, :], p[:, None, :]
+        return e + p
+
+    def layer_params(self, params, li):
+        """Layer `li`'s leaves: the ONE place that slices the stacked
+        weights (a scan over ``params["layers"]`` hands the same dict)."""
+        return {n: a[li] for n, a in params["layers"].items()}
+
+    def layer(self, li, lp, x, pos, cache):  # noqa: ARG002
+        """One pre-norm block over ``x`` (N, T, C): the family's ONE layer
+        definition beside the Gluon block (`models/gpt.py`), which
+        `tests/test_gpt.py` holds it to. `cache` is a cache-access object
+        (`serve/pages.py`; `_PromptCache` / `_StepCache` below)::
+
+            cache.attend(li, q, k, v) -> o      # (N, H, T, d) each
+
+        stores the rows ``k, v`` of layer `li` and returns the attention of
+        ``q`` over what it holds, rows in ``(N, T, H, d)`` order. `pos` is
+        not read: the positions went in with `embed`."""
+        jax = _j()
+        h = _ln(x, lp["ln1_g"], lp["ln1_b"])
+        q, k, v = _split_qkv(_dense(h, lp["qkv_w"], lp["qkv_b"]),
+                             self._n_heads)
+        o = cache.attend(li, q, k, v)
+        x = x + _dense(o.reshape(x.shape), lp["proj_w"], lp["proj_b"])
+        h = _ln(x, lp["ln2_g"], lp["ln2_b"])
+        ffn = _dense(jax.nn.gelu(_dense(h, lp["ffn1_w"], lp["ffn1_b"])),
+                     lp["ffn2_w"], lp["ffn2_b"])
+        return x + ffn
+
+    def next_logits(self, params, x):
+        """Next-token logits of residual rows ``x`` (..., C)."""
         x = _ln(x, params["lnf_g"], params["lnf_b"])
         if self._tie:
             return x @ params["embed"].T
         return x @ params["head_w"].T
-
-    def _prefill_layer(self, x, lp, cache_len):
-        """Full-prompt causal attention; returns (x', k, v) padded to S."""
-        jax = _j()
-        jnp = jax.numpy
-        from ..ops.flash_attention import flash_attention
-
-        H = self._n_heads
-        h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-        q, k, v = _split_qkv(_dense(h, lp["qkv_w"], lp["qkv_b"]), H)
-        d = q.shape[-1]
-        o = flash_attention(q, k, v, causal=True,
-                            sm_scale=1.0 / math.sqrt(d))
-        N, _, T, _ = o.shape
-        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(N, T, H * d)
-        x = x + _dense(o, lp["proj_w"], lp["proj_b"])
-        h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-        ffn = _dense(jax.nn.gelu(_dense(h, lp["ffn1_w"], lp["ffn1_b"])),
-                     lp["ffn2_w"], lp["ffn2_b"])
-        pad = [(0, 0), (0, 0), (0, cache_len - T), (0, 0)]
-        return x + ffn, jnp.pad(k, pad), jnp.pad(v, pad)
-
-    def _decode_layer(self, x, lp, ck, cv, pos):
-        """One-token forward against the cache; writes k/v at `pos`."""
-        jax = _j()
-        jnp = jax.numpy
-        lax = jax.lax
-
-        H = self._n_heads
-        h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-        q, k, v = _split_qkv(_dense(h, lp["qkv_w"], lp["qkv_b"]), H)
-        d = q.shape[-1]
-        # write this token's k/v at position pos (static-shape update)
-        ck = lax.dynamic_update_slice(ck, k, (0, 0, pos, 0))
-        cv = lax.dynamic_update_slice(cv, v, (0, 0, pos, 0))
-        # attend to positions 0..pos; later slots hold zeros/garbage that
-        # the mask excludes (f32 scores for a stable softmax)
-        s = jnp.einsum("nhqd,nhkd->nhqk", q, ck,
-                       preferred_element_type=jnp.float32)
-        s = s / math.sqrt(d)
-        mask = jnp.arange(ck.shape[2]) <= pos
-        s = jnp.where(mask[None, None, None, :], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1).astype(cv.dtype)
-        o = jnp.einsum("nhqk,nhkd->nhqd", p, cv)
-        N = x.shape[0]
-        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(N, 1, H * d)
-        x = x + _dense(o, lp["proj_w"], lp["proj_b"])
-        h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-        ffn = _dense(jax.nn.gelu(_dense(h, lp["ffn1_w"], lp["ffn1_b"])),
-                     lp["ffn2_w"], lp["ffn2_b"])
-        return x + ffn, ck, cv
 
     def _sample(self, logits, key, temperature, top_k, do_sample):
         jax = _j()
@@ -313,19 +361,23 @@ class GPTDecoder:
             # beyond t0, but decode overwrites position p before the
             # `arange <= pos` mask ever admits it, so the junk is never
             # attended.
-            N, B = tokens.shape
-            L = params["layers"]["ln1_g"].shape[0]
+            # Both halves run `layer`, the block the serving programs run,
+            # scanned over the stacked layers (one traced body whatever the
+            # depth): a dense cache is built in the scan body from that
+            # iteration's slice, so `li` is no index here.
+            B = tokens.shape[1]
 
             # ---- prefill: full causal pass over the padded prompt ----
-            x = params["embed"][tokens] + params["pos"][:B]
+            x = self.embed(params, tokens, jnp.arange(B))
 
             def pre_layer(x, lp):
-                x, k, v = self._prefill_layer(x, lp, cache_len)
-                return x, (k, v)
+                cache = _PromptCache(cache_len)
+                return self.layer(None, lp, x, None, cache), \
+                    (cache.k, cache.v)
 
             x, (ck, cv) = lax.scan(pre_layer, x, params["layers"])
             # last REAL token (causal: its row never saw the padding)
-            logits0 = self._logits(
+            logits0 = self.next_logits(
                 params, lax.dynamic_slice_in_dim(x, t0 - 1, 1,
                                                  axis=1)[:, 0])  # (N, V)
 
@@ -333,18 +385,17 @@ class GPTDecoder:
             def step(carry, step_key):
                 ck, cv, pos, tok = carry
 
-                x = (params["embed"][tok][:, None]
-                     + lax.dynamic_slice_in_dim(params["pos"], pos, 1))
+                x = self.embed(params, tok[:, None], pos[None])
 
                 def dec_layer(x, layer):
                     lp, ck_l, cv_l = layer
-                    x, ck_l, cv_l = self._decode_layer(x, lp, ck_l, cv_l,
-                                                       pos)
-                    return x, (ck_l, cv_l)
+                    cache = _StepCache(ck_l, cv_l, pos)
+                    return self.layer(None, lp, x, None, cache), \
+                        (cache.k, cache.v)
 
                 x, (ck, cv) = lax.scan(dec_layer, x,
                                        (params["layers"], ck, cv))
-                logits = self._logits(params, x[:, 0])
+                logits = self.next_logits(params, x[:, 0])
                 nxt = self._sample(logits, step_key, temperature, top_k,
                                    do_sample)
                 return (ck, cv, pos + 1, nxt), tok

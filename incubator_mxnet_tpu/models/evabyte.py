@@ -25,7 +25,7 @@ which stores the rows ``k, v`` of layer ``li`` and returns the attention of
 
 The output head has ``num_pred_heads * vocab`` rows; head ``p`` (rows ``p V
 ... (p+1) V - 1``) predicts byte ``t + 1 + p``. The served token is head 0's
-(`next_byte_logits`); the other heads' weights are held, and drafting several
+(`next_logits`); the other heads' weights are held, and drafting several
 bytes a step with them is not written (ROADMAP R7).
 """
 from __future__ import annotations
@@ -166,18 +166,26 @@ class EvaByteDecoder:
 
     # -- the mathematics (traced) --------------------------------------------
 
-    def embed(self, params, tokens):
+    def embed(self, params, tokens, pos):  # noqa: ARG002
+        """``tokens`` (N, T), N sequences of T new rows each, or (N,), one
+        each: ``x`` (N T, C) float32, rows in that order (positions enter in
+        `layer`, rotary)."""
         import jax.numpy as jnp
 
-        return params["embed"][tokens].astype(jnp.float32)
+        return params["embed"][tokens.reshape(-1)].astype(jnp.float32)
+
+    def layer_params(self, params, li):
+        return params["layers"][li]
 
     def layer(self, li, lp, x, pos, cache):
-        """One block: ``x`` (T, C) float32, ``pos`` (T,) the rows' positions,
-        `cache` as the module docstring says. Returns ``x'`` (T, C) float32."""
+        """One block: ``x`` (T, C) float32, ``pos`` the rows' positions (T
+        of them, in any shape), `cache` as the module docstring says. Returns
+        ``x'`` (T, C) float32."""
         import jax
         import jax.numpy as jnp
 
         cfg, dt = self.config, self.dtype
+        pos = pos.reshape(-1)
         t = x.shape[0]
         hd = (t, cfg.num_attention_heads, cfg.head_dim)
 
@@ -195,14 +203,6 @@ class EvaByteDecoder:
         return h + mm(jax.nn.silu(mm(u, lp["w_gate"])) * mm(u, lp["w_up"]),
                       lp["w_down"])
 
-    def forward(self, params, tokens, pos, cache):
-        """Every layer over ``tokens`` (T,) at positions ``pos``; returns the
-        last layer's residual stream (T, C)."""
-        x = self.embed(params, tokens)
-        for li, lp in enumerate(params["layers"]):
-            x = self.layer(li, lp, x, pos, cache)
-        return x
-
     def logits(self, params, x):
         """All prediction heads: ``(..., P V)`` float32."""
         import jax.numpy as jnp
@@ -211,6 +211,6 @@ class EvaByteDecoder:
         return jnp.matmul(z.astype(self.dtype), params["head"],
                           preferred_element_type=jnp.float32)
 
-    def next_byte_logits(self, params, x):
-        """Head 0: what is served."""
+    def next_logits(self, params, x):
+        """Head 0, the next byte's: what is served."""
         return self.logits(params, x)[..., :self.config.vocab_size]

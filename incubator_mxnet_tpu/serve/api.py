@@ -41,15 +41,23 @@ _DRIVER_MAX_CONSECUTIVE_FAILURES = 3
 
 
 class ServeEngine:
-    """Continuous-batching inference engine over a GPT Block (or a
-    prebuilt `GPTDecoder`).
+    """Continuous-batching inference engine over a decoder: a GPT Block
+    (or a prebuilt `GPTDecoder`), or another family's decoder object.
 
     Parameters
     ----------
     block_or_decoder : Block | GPTDecoder | EvaByteDecoder
-        The model to serve. An `EvaByteDecoder` is served by
-        `serve.eva.EvaSlotDecoder` (window and summary pages; no
-        speculative decoding, int8 pages or prefix reuse).
+        The model to serve. Every family runs the same prefill-chunk and
+        decode programs (`serve.engine.SlotDecoder`) over its own block,
+        ``decoder.layer(li, lp, x, pos, cache)``, where
+        ``cache.attend(li, q, k, v)`` writes the rows into the page pool
+        and returns the attention output (`serve/pages.py`). A family
+        brings its decoder (``embed`` / ``layer_params`` / ``layer`` /
+        ``next_logits`` / ``kv_geometry``) and a slots subclass with its
+        page arithmetic and chunk cache: an `EvaByteDecoder` (``family =
+        "evabyte"``) is served by `serve.eva.EvaSlotDecoder` (window and
+        summary pages; no speculative decoding, int8 pages or prefix
+        reuse).
     max_slots : int
         In-flight request capacity (static decode batch width).
     max_len : int, optional

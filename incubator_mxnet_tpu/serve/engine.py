@@ -1,11 +1,9 @@
 """Paged slot-cache compiled decode programs (the device half of `mx.serve`).
 
-PR 4's engine kept one monolithic KV slot per request — shape
-``(L, max_slots, H, max_len, d)`` — so every slot reserved ``max_len``
-HBM regardless of actual request length and every prompt paid a full
-prefill. This module replaces it with a **paged** pool, the
-vLLM/PagedAttention block-allocation idea re-expressed TPU-natively
-(static shapes, gather-by-page-table, zero steady-state recompiles):
+A **paged** KV pool, the vLLM/PagedAttention block-allocation idea
+re-expressed TPU-natively (static shapes, gather-by-page-table, zero
+steady-state recompiles): a slot holds the pages its request has reached,
+not ``max_len`` rows of HBM, and a shared prompt prefix is prefilled once:
 
 - **page pool** — one persistent device array per K and V **per
   layer**: a tuple of L arrays of ``(n_pages, H, page_tokens, d)``
@@ -46,6 +44,25 @@ vLLM/PagedAttention block-allocation idea re-expressed TPU-natively
   lands beyond every shared page), so shared pages need no copies and no
   write-protection machinery.
 
+**One skeleton per kind of step, whatever the family and the page format.**
+`SlotDecoder` is family-free. Each program embeds its rows, runs the
+decoder's block layer by layer (`_run_layers`), samples, and returns the
+pools; the block (`GPTDecoder.layer`, `EvaByteDecoder.layer`) is written once
+per family against a **cache-access object** (`serve/pages.py`)::
+
+    cache.attend(li, q, k, v) -> o
+
+which writes the rows ``k, v`` of layer ``li`` into the pool and returns the
+attention of ``q`` over what the cache holds for them. The cache objects
+alone know what a stored page is (float packed to 128 lanes, or int8 with a
+scale per (page, head)); the pools travel through every program as ONE
+donated pytree argument, so a program has one signature and one jitted
+wrapper (`_observed`) whatever the format. A new family brings a decoder with
+``embed`` / ``layer_params`` / ``layer`` / ``next_logits`` / ``kv_geometry``
+and a slots subclass with its page arithmetic (`pages_needed`, `pages_at`,
+`_table_width`, `_row_of`, `_count_rows`) and its chunk's cache access
+(`_chunk_pages`, `_chunk_cache`): `serve/eva.py` is one.
+
 Two compiled program families in the base configuration:
 
 - **chunked prefill** (one program per chunk-length bucket,
@@ -53,16 +70,17 @@ Two compiled program families in the base configuration:
   request's prompt — embeds the chunk at its true positions (traced
   ``t_start``), writes the chunk's K/V pages into the pool, attends the
   chunk's queries against the slot's gathered view (prefix pages +
-  itself) under a causal-with-offset mask, and samples a first token
-  from the chunk's last real row (used by the host only on the final
-  chunk). Splitting long prompts into chunks lets the scheduler
+  itself) under a causal-with-offset mask (`pages.ChunkCache`), and samples
+  a first token from the chunk's last real row (used by the host only on
+  the final chunk). Splitting long prompts into chunks lets the scheduler
   interleave decode steps between chunks, so a long-prompt arrival no
   longer stalls every running request for a whole monolithic prefill.
 - **decode** (ONE program): one token for ALL slots — per-slot write
-  of the new K/V at ``page_table[s, pos//page_tokens]`` (inactive slots
+  of the new K/V at the page and offset `_row_of` names (inactive slots
   are redirected to the trash page), attention over each decoding
-  slot's live pages, per-slot sampling. The attention is one op with two
-  implementations, chosen from what the process observes and counted in
+  slot's live pages (`pages.TokenCache`), per-slot sampling. The attention
+  is one op with two implementations, chosen from what the process
+  observes and counted in
   ``mx_kernel_dispatch_total{op="paged_decode_attention",impl=}``: on one
   TPU device with float pools the pallas kernel ``mx_paged_decode``,
   which reads the pages below ``pos`` straight from the layer's pool
@@ -73,21 +91,21 @@ Two compiled program families in the base configuration:
   ``mx_serve_decode_pages_total{kind="live"|"view"}`` says what share of
   that view a step's attention covers.
 
-With **speculative decoding** armed (``spec_k > 0``), decode is
-replaced by two more families that advance up to ``k + 1`` tokens per
-round instead of one per launch:
+With **speculative decoding** armed (``spec_k > 0``; the GPT block only),
+decode is replaced by two more families that advance up to ``k + 1`` tokens
+per round instead of one per launch:
 
 - **verify** (ONE program): the target model runs ``k + 1`` token rows
-  for ALL slots in one batched pass — row ``i`` consumes
-  ``[last, d_1..d_k][i]`` at position ``pos + i``, writes its K/V to
-  the slot's pages (beyond-budget rows are redirected to the trash
+  for ALL slots in one batched pass (`pages.RowsCache`) — row ``i``
+  consumes ``[last, d_1..d_k][i]`` at position ``pos + i``, writes its K/V
+  to the slot's pages (beyond-budget rows are redirected to the trash
   page) and emits the greedy next token. Because row ``i`` only
   attends positions ``<= pos + i``, the batched pass is mathematically
   identical to ``k + 1`` sequential decode steps — the same identity
   chunked prefill already relies on — which is what makes greedy spec
   decode token-for-token equal to the non-spec engine.
 - **draft** (ONE program, model drafts only): ``k`` unrolled greedy
-  decode steps of the small draft model against its OWN per-layer pool
+  decode steps of the small draft model against its OWN pools
   (same page table and allocator, so draft pages track target pages
   exactly). The ``draft="ngram"`` fallback drafts on the host
   (`models.decoding.NgramProposer`) and adds NO device program.
@@ -97,13 +115,11 @@ prefix matching the verify row outputs commits (plus the bonus token
 from the first mismatching row), and pages speculatively extended for
 rejected suffixes roll back through `PageAllocator.decref`.
 
-All families donate the pool buffers (``donate_argnums``) so XLA
-updates them in place. Optional **int8 KV**
-(``MXNET_SERVE_KV_DTYPE=int8``) stores each layer's pool as int8 with
-one scale per (page, head) — the symmetric ±127 convention of
-`contrib.quantization` (`quantize_symmetric`) — halving resident KV
-bytes per slot; decode re-quantizes only the single page it writes
-(grow-only per-page scale).
+All families donate the pools so XLA updates them in place. Optional
+**int8 KV** (``MXNET_SERVE_KV_DTYPE=int8``) stores each layer's pool as
+int8 with one scale per (page, head), halving resident KV bytes per slot;
+decode re-quantizes only the single page it writes (grow-only per-page
+scale).
 
 Stale-row safety (unchanged argument, now per page): position ``p`` of a
 slot only enters the attention mask once the slot's ``pos`` reaches
@@ -115,7 +131,6 @@ from __future__ import annotations
 
 import contextlib
 import hashlib
-import math
 import os
 import weakref
 
@@ -126,6 +141,8 @@ from ..models.decoding import (GPTDecoder, NgramProposer, bucket_chunk,
 from ..telemetry import compiles as _compiles
 from ..telemetry import hbm as _hbm
 from ..telemetry import registry, tracing
+from .pages import (ChunkCache, PageCache, RowsCache, TokenCache,
+                    make_pools, page_bytes)
 
 __all__ = ["SlotDecoder", "PageAllocator", "PrefixCache",
            "PagePoolExhausted", "DEFAULT_PAGE_TOKENS",
@@ -393,8 +410,10 @@ class PrefixCache:
 
 
 class SlotDecoder:
-    """Paged slot-cache decoder over a `GPTDecoder` (or the
-    `GPTModel`-shaped Block it wraps).
+    """Paged slot-cache decoder: the family-free programs (module
+    docstring), here over a `GPTDecoder` (or the `GPTModel`-shaped Block it
+    wraps) with pages mapped by position; `serve.eva.EvaSlotDecoder`
+    subclasses it for its family.
 
     Parameters
     ----------
@@ -481,7 +500,6 @@ class SlotDecoder:
                 f"kv_dtype must be 'fp' or 'int8', got {kv_dtype!r} "
                 "(MXNET_SERVE_KV_DTYPE)")
         self.kv_dtype = kv_dtype
-        self._int8 = kv_dtype == "int8"
 
         default_pages = self.max_slots * self.pages_per_slot + 1
         self.n_pages = int(n_pages) if n_pages is not None else default_pages
@@ -504,9 +522,8 @@ class SlotDecoder:
         self._table_dev = None
         self._table_dirty = True
 
-        # per-layer paged K/V: tuples of L arrays (n_pages, H, pt, d)
-        self._pk = self._pv = None
-        self._sk = self._sv = None          # int8 per-(page, H) scales
+        # the pools pytree (`serve/pages.py`): one leaf a layer and kind
+        self._pools = None
         self._prefill_jit = None
         self._decode_jit = None
         # the tokens of the last decode launch, on the device: the next
@@ -552,8 +569,7 @@ class SlotDecoder:
                         "drafted token ids index the target embedding")
                 self.draft_kind = "model"
                 self._draft_dec = dd
-        self._dpk = self._dpv = None        # draft-model per-layer pools
-        self._dsk = self._dsv = None
+        self._draft_pools = None            # the draft model's own pools
         self._verify_jit = None
         self._draft_jit = None
         self._draft_prefill_jit = None
@@ -568,27 +584,13 @@ class SlotDecoder:
 
     def _resolve_decoder(self, source):
         """The decoder object this engine's programs are built for."""
-        if getattr(source, "family", None) == "evabyte":
-            raise NotImplementedError(
-                f"{type(self).__name__} does not serve the evabyte family "
-                "(window and summary pages, the roll): `serve.eva."
-                "EvaSlotDecoder` does, on one device; a sharded engine for "
-                "it is not written")
         if isinstance(source, GPTDecoder):
             return source
         if hasattr(source, "blocks") and hasattr(source, "position_embed"):
             return GPTDecoder(source)
         raise TypeError(
-            "SlotDecoder needs a GPTDecoder or a GPT-shaped Block "
+            f"{type(self).__name__} needs a GPTDecoder or a GPT-shaped Block "
             f"(blocks + position_embed), got {type(source).__name__}")
-
-    def _kv_geometry(self, dec):
-        """``(layers, heads, head size, float dtype)`` of `dec`'s K/V rows."""
-        if hasattr(dec, "kv_geometry"):
-            return dec.kv_geometry()
-        layers = dec._params["layers"]
-        return (int(layers["ln1_g"].shape[0]), dec._n_heads,
-                dec._units // dec._n_heads, layers["qkv_w"].dtype)
 
     # -- page arithmetic (the scheduler asks; it keeps none of its own) -----
 
@@ -604,6 +606,18 @@ class SlotDecoder:
     def pages_at(self, n):
         """Pages a slot holds with positions ``0 .. n-1`` mapped."""
         return -(-int(n) // self.page_tokens)
+
+    def _row_of(self, pos):
+        """Where position `pos` of a slot lives (traced): ``(entry of the
+        slot's row of the page table, offset in that page, rows the position
+        attends, its own among them)``."""
+        pt = self.page_tokens
+        return pos // pt, pos % pt, pos + 1
+
+    def _count_rows(self, at):
+        """Count what a decode step with slots at positions `at` (host
+        array) attends; returns the pages it reads."""
+        return int((at // self.page_tokens + 1).sum())
 
     # -- page table ---------------------------------------------------------
 
@@ -631,32 +645,10 @@ class SlotDecoder:
     # -- pool ---------------------------------------------------------------
 
     def _make_pools(self, dec):
-        """Per-layer page pools for `dec`: TUPLES of L device arrays of
-        ``(n_pages, H, page_tokens, d)`` values, a float leaf packed to
-        128 lanes (int8 adds per-layer ``(n_pages, H)`` scale planes).
-        Separate leaves — not one
-        stacked 5-D array — so every compiled program's donation map
-        aliases each layer's pool in place; see the module docstring
-        for why the stacked layout forces an O(L × n_pages) rewrite."""
-        jnp = _j().numpy
-        L, H, d, dtype = self._kv_geometry(dec)
-        shape = (self.n_pages, H, self.page_tokens, d)
-        if self._int8:
-            pk = tuple(jnp.zeros(shape, jnp.int8) for _ in range(L))
-            pv = tuple(jnp.zeros(shape, jnp.int8) for _ in range(L))
-            sk = tuple(jnp.zeros((self.n_pages, H), jnp.float32)
-                       for _ in range(L))
-            sv = tuple(jnp.zeros((self.n_pages, H), jnp.float32)
-                       for _ in range(L))
-            return pk, pv, sk, sv
-        # float pages are stored packed to the TPU's 128 lanes
-        # (ops.paged_attention, "a page as it is stored")
-        from ..ops.paged_attention import page_store_shape
-
-        shape = (self.n_pages, H) + page_store_shape(self.page_tokens, d)
-        pk = tuple(jnp.zeros(shape, dtype) for _ in range(L))
-        pv = tuple(jnp.zeros(shape, dtype) for _ in range(L))
-        return pk, pv, None, None
+        """`dec`'s pools pytree (`pages.make_pools`: what a stored page is,
+        is decided there and known to the cache-access objects alone)."""
+        return make_pools(self.n_pages, self.page_tokens, dec.kv_geometry(),
+                          self.kv_dtype)
 
     # -- sharding seams (overridden by serve.sharded.ShardedSlotDecoder) ----
 
@@ -674,12 +666,19 @@ class SlotDecoder:
         for the one-device engine."""
         return contextlib.nullcontext()
 
-    def _constrain_pools(self, pk, pv, sk, sv):
+    def _place_pools(self, pools):
+        """Placement seam for pools made outside a program (fresh zeros, a
+        migration's eager scatters): the base engine keeps them as they
+        are; the sharded engine pins them to the pool layout so donation
+        aliasing matches."""
+        return pools
+
+    def _constrain_pools(self, pools):
         """Traced seam at the tail of every pool-updating program: the
         base engine is layout-free (identity), the sharded engine pins
         each updated pool leaf to its input sharding so XLA's donation
-        map still aliases all ``2L`` leaves in place."""
-        return pk, pv, sk, sv
+        map still aliases every leaf in place."""
+        return pools
 
     def _pin_tokens(self, tokens):
         """Seam for a decode launch's ``(max_slots,)`` tokens, which the
@@ -690,26 +689,26 @@ class SlotDecoder:
         return tokens
 
     def _shardcheck_specs(self):
-        """Per-argument shardcheck spec entries for ``(params, *pools)``,
-        or None (unconstrained — the single-chip default). The sharded
-        engine returns its `ServeLayout`-derived entries so SC001 sees
-        every ≥1 MiB leaf explicitly placed."""
-        return None
-
-    def _shardcheck_out_specs(self):
-        """Spec entries for the builders' ``(pk, pv[, sk, sv], tok)``
-        outputs, or None. The sharded engine pins the pool outputs so
-        the SC004 donation audit sees matching in/out placements."""
-        return None
+        """``(spec entries for (params, pools), spec entries for the
+        builders' (pools, tok) outputs)`` for the shardcheck pre-flight, or
+        ``(None, None)`` (unconstrained — the single-chip default). The
+        sharded engine returns its `ServeLayout`-derived entries so SC001
+        sees every ≥1 MiB leaf explicitly placed and the SC004 donation
+        audit sees matching in/out placements."""
+        return None, None
 
     def _ensure_pool(self):
-        if self._pk is not None:
+        if self._pools is not None:
             return
-        self._pk, self._pv, self._sk, self._sv = self._make_pools(self._dec)
+        self._pools = self._place_pools(self._make_pools(self._dec))
         if self._draft_dec is not None:
-            (self._dpk, self._dpv,
-             self._dsk, self._dsv) = self._make_pools(self._draft_dec)
+            self._draft_pools = self._place_pools(
+                self._make_pools(self._draft_dec))
         self._register_hbm_owners()
+
+    def _pool_leaves(self):
+        """Every device array of the pools, target and draft."""
+        return _j().tree.leaves((self._pools, self._draft_pools))
 
     def _register_hbm_owners(self):
         """Attribute this engine's device memory to named HBM-census
@@ -721,14 +720,9 @@ class SlotDecoder:
 
         def _pool_probe():
             eng = ref()
-            if eng is None or eng._pk is None:
+            if eng is None or eng._pools is None:
                 return None
-            arrays = []
-            for leaves in (eng._pk, eng._pv, eng._sk, eng._sv,
-                           eng._dpk, eng._dpv, eng._dsk, eng._dsv):
-                if leaves is not None:
-                    arrays.extend(leaves)
-            arrays.append(eng._table_dev)
+            arrays = eng._pool_leaves() + [eng._table_dev]
             page_bytes = eng.cache_bytes / eng.n_pages if eng.n_pages else 0
             cached = eng.prefix_cache.cached_pages
             return {
@@ -744,17 +738,14 @@ class SlotDecoder:
             eng = ref()
             if eng is None:
                 return None
-            import jax.tree_util as jtu
-
-            return {"arrays": jtu.tree_leaves(eng._dec._params)}
+            return {"arrays": _j().tree.leaves(eng._dec._params)}
 
         _hbm.register_owner(f"{self.census_name}.kv_pool", _pool_probe)
         _hbm.register_owner(f"{self.census_name}.params", _params_probe)
 
     def release(self):
         """Drop the device pool (shutdown); the next prefill reallocates."""
-        self._pk = self._pv = self._sk = self._sv = None
-        self._dpk = self._dpv = self._dsk = self._dsv = None
+        self._pools = self._draft_pools = None
         self._tokens = None
         self._table_dev = None
         self._table_dirty = True
@@ -763,21 +754,12 @@ class SlotDecoder:
     def cache_bytes(self):
         """Device bytes held by the persistent KV pools — target and
         (when a model draft is armed) draft — 0 if released."""
-        if self._pk is None:
-            return 0
-        n = 0
-        for leaves in (self._pk, self._pv, self._sk, self._sv,
-                       self._dpk, self._dpv, self._dsk, self._dsv):
-            if leaves is not None:
-                n += sum(a.size * a.dtype.itemsize for a in leaves)
-        return n
+        return sum(a.size * a.dtype.itemsize for a in self._pool_leaves())
 
     @property
     def kv_bytes_per_slot(self):
         """Resident pool bytes per decode slot — the HBM cost a slot
         actually pays under paging (int8 halves it)."""
-        if self._pk is None:
-            return 0
         return self.cache_bytes / self.max_slots
 
     @property
@@ -787,13 +769,8 @@ class SlotDecoder:
         disaggregation plane's ``mx_serve_page_migration_bytes_total``
         is exactly pages-moved × this. Derived from shapes, so it needs
         no allocated pool."""
-        L, H, d, dtype = self._kv_geometry(self._dec)
-        if self._int8:
-            # int8 K + V page slabs plus two f32 per-(page, H) scales
-            per_layer = 2 * H * self.page_tokens * d + 2 * H * 4
-        else:
-            per_layer = 2 * H * self.page_tokens * d * dtype.itemsize
-        return L * per_layer
+        return page_bytes(self.page_tokens, self._dec.kv_geometry(),
+                          self.kv_dtype)
 
     # -- page migration (the disaggregation transfer seam) -------------------
 
@@ -810,17 +787,11 @@ class SlotDecoder:
         prefill/decode families are untouched either way)."""
         jnp = _j().numpy
         self._ensure_pool()
-        payload = {}
-        for name, leaves in (("k", self._pk), ("v", self._pv),
-                             ("sk", self._sk), ("sv", self._sv)):
-            if leaves is None:
-                continue
-            payload[name] = [
-                [onp.asarray(jnp.take(pool_l, jnp.asarray(p, jnp.int32),
-                                      axis=0))
-                 for p in pages]
-                for pool_l in leaves]
-        return payload
+        return {name: [[onp.asarray(jnp.take(leaf, jnp.asarray(p, jnp.int32),
+                                             axis=0))
+                        for p in pages]
+                       for leaf in leaves]
+                for name, leaves in self._pools.items()}
 
     def copy_pages_in(self, pages, payload):
         """Write a peer engine's `copy_pages_out` payload into this pool
@@ -830,45 +801,53 @@ class SlotDecoder:
         shape-stable across migrations."""
         jnp = _j().numpy
         self._ensure_pool()
-        for name, attr in (("k", "_pk"), ("v", "_pv"),
-                           ("sk", "_sk"), ("sv", "_sv")):
-            leaves = getattr(self, attr)
-            if leaves is None:
-                if payload.get(name):
-                    raise ValueError(
-                        f"payload carries {name!r} planes but this engine "
-                        f"has none (kv_dtype mismatch across replicas?)")
-                continue
-            blocks = payload[name]
-            new = []
-            for pool_l, per_page in zip(leaves, blocks):
-                for p, blk in zip(pages, per_page):
-                    pool_l = pool_l.at[jnp.asarray(p, jnp.int32)].set(
-                        jnp.asarray(blk))
-                new.append(pool_l)
-            setattr(self, attr, self._place_migrated(tuple(new), name))
+        if set(payload) != set(self._pools):
+            raise ValueError(
+                f"payload carries {sorted(payload)} planes but this engine "
+                f"holds {sorted(self._pools)} (kv_dtype mismatch across "
+                "replicas?)")
 
-    def _place_migrated(self, leaves, name):  # noqa: ARG002
-        """Placement seam after a migration write: the base engine keeps
-        the eager scatter results as-is; the sharded engine re-pins them
-        to the pool layout so donation aliasing still matches."""
-        return leaves
+        def write(leaf, per_page):
+            for p, blk in zip(pages, per_page):
+                leaf = leaf.at[jnp.asarray(p, jnp.int32)].set(
+                    jnp.asarray(blk))
+            return leaf
 
-    # -- shared attention helpers (traced) ----------------------------------
+        self._pools = self._place_pools({
+            name: tuple(map(write, leaves, payload[name]))
+            for name, leaves in self._pools.items()})
 
-    def _dequant_view(self, pool_l, scale_l, idx):
-        """Gather pages `idx` from one layer's pool and return the real-
-        valued view ``(..., n_idx * page_tokens, d)`` (leading dims follow
-        `idx`'s shape). fp pools gather straight through."""
-        jnp = _j().numpy
-        from ..ops.paged_attention import unpack_pages
+    # -- the programs' skeleton ---------------------------------------------
 
-        v = jnp.take(pool_l, idx, axis=0)
-        v = unpack_pages(v, v.shape[-2] * v.shape[-1] // self.page_tokens)
-        if self._int8:
-            sc = jnp.take(scale_l, idx, axis=0)
-            v = v.astype(jnp.float32) * sc[..., None, None]
-        return v
+    def _run_layers(self, dec, params, tokens, pos, cache):
+        """`dec`'s block over ``tokens`` (N, T) — N sequences, T new rows
+        each; or (N,), one new row each — at positions `pos`, every layer
+        handed `cache`; returns the last layer's residual rows ``(N T, C)``.
+
+        Python-unrolled over layers: each iteration reads/writes ITS OWN
+        donated pool leaf, so XLA's donation map aliases every leaf in place
+        (a scan over a stacked pool re-stacks the whole pool per call — the
+        O(L x n_pages) rewrite the per-layer pools exist to remove)."""
+        x = dec.embed(params, tokens, pos)
+        for li in range(dec.kv_geometry()[0]):
+            x = dec.layer(li, dec.layer_params(params, li), x, pos, cache)
+        return x.reshape(-1, x.shape[-1])
+
+    def _observed(self, fn, kind, tokens_idx=None, **jit_kwargs):
+        """The one way a program of this engine is jitted: its pools,
+        argument 1, donated, and the program in the compile ledger as
+        family ``<census_name>.<kind>`` — recompiles past the first get
+        forensics, and bucketed prefill growth (a new chunk bucket seen at
+        `tokens_idx`) is classified `new_bucket`. The wrapper passes
+        `_cache_size` through, so `xla_program_count` and the shardcheck
+        pre-flight see the raw jitted object's introspection surface."""
+        bucket = None
+        if tokens_idx is not None:
+            def bucket(args, kwargs, _i=tokens_idx):  # noqa: ARG001
+                return int(args[_i].shape[1])
+        return _compiles.ledgered_jit(
+            fn, family=f"{self.census_name}.{kind}", bucket=bucket,
+            donate_argnums=(1,), **jit_kwargs)
 
     # -- chunked prefill ----------------------------------------------------
 
@@ -877,151 +856,49 @@ class SlotDecoder:
         the draft model gets its own family writing its own pools)."""
         jax = _j()
         jnp = jax.numpy
-        lax = jax.lax
         dec = self._dec if dec is None else dec
-        H = dec._n_heads
-        pt = self.page_tokens
-        int8 = self._int8
 
-        from ..contrib.quantization import quantize_symmetric
-        from ..models.decoding import _dense, _ln, _split_qkv
-        from ..ops.paged_attention import pack_pages
-
-        def to_pages(t):
-            # (1, H, C, d) -> (C//pt pages, H, pt, d)
-            _, _, C, d = t.shape
-            return jnp.transpose(
-                t[0].transpose(1, 0, 2).reshape(C // pt, pt, H, d),
-                (0, 2, 1, 3))
-
-        def run(params, pk, pv, sk, sv, tokens, pages_row, chunk_pages,
-                t_start, t_len, key, temperature, top_k, do_sample):
-            C = tokens.shape[1]
-            PT = pages_row.shape[0] * pt
-            pos_tab = params["pos"]
-            pos_idx = jnp.clip(t_start + jnp.arange(C), 0,
-                               pos_tab.shape[0] - 1)
-            x = params["embed"][tokens] + pos_tab[pos_idx]
-            qpos = t_start + jnp.arange(C)
-            # causal-with-offset validity: key position j is visible to
-            # chunk row i iff j <= t_start + i — this covers BOTH the
-            # prefix pages (j < t_start) and in-chunk causality, and
-            # masks stale/trash/padding pages in one stroke
-            mask = jnp.arange(PT)[None, :] <= qpos[:, None]
-            sm_scale = 1.0 / math.sqrt(dec._units // H)
-            d = dec._units // H
-
-            # Python-unrolled over layers: each iteration reads/writes
-            # ITS OWN donated pool leaf, so XLA's donation map aliases
-            # every leaf in place (a scan over a stacked pool re-stacks
-            # the whole pool per call — the O(L × n_pages) rewrite this
-            # layout exists to remove)
-            L = len(pk)
-            pk, pv = list(pk), list(pv)
-            sk = list(sk) if int8 else [None] * L
-            sv = list(sv) if int8 else [None] * L
-            for li in range(L):
-                lp = {n: a[li] for n, a in params["layers"].items()}
-                pk_l, pv_l = pk[li], pv[li]
-                sk_l, sv_l = sk[li], sv[li]
-                h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-                q, k, v = _split_qkv(_dense(h, lp["qkv_w"], lp["qkv_b"]), H)
-                kp, vp = to_pages(k), to_pages(v)
-                if int8:
-                    kq, ks = quantize_symmetric(kp, axes=(2, 3))
-                    vq, vs = quantize_symmetric(vp, axes=(2, 3))
-                    pk_l = pk_l.at[chunk_pages].set(kq)
-                    pv_l = pv_l.at[chunk_pages].set(vq)
-                    sk_l = sk_l.at[chunk_pages].set(ks[:, :, 0, 0])
-                    sv_l = sv_l.at[chunk_pages].set(vs[:, :, 0, 0])
-                else:
-                    pk_l = pk_l.at[chunk_pages].set(
-                        pack_pages(kp.astype(pk_l.dtype)))
-                    pv_l = pv_l.at[chunk_pages].set(
-                        pack_pages(vp.astype(pv_l.dtype)))
-                # slot view: (P, H, pt, d) -> (1, H, P*pt, d)
-                vk = self._dequant_view(pk_l, sk_l, pages_row)
-                vv = self._dequant_view(pv_l, sv_l, pages_row)
-                vk = jnp.transpose(vk, (1, 0, 2, 3)).reshape(H, PT, d)[None]
-                vv = jnp.transpose(vv, (1, 0, 2, 3)).reshape(H, PT, d)[None]
-                if int8:
-                    # the chunk attends to its OWN K/V exactly (pre-
-                    # quantization) — only the prefix pays quantization
-                    vk = lax.dynamic_update_slice(vk, k.astype(vk.dtype),
-                                                  (0, 0, t_start, 0))
-                    vv = lax.dynamic_update_slice(vv, v.astype(vv.dtype),
-                                                  (0, 0, t_start, 0))
-                # mirror ops/flash_attention._xla_attention exactly (the
-                # impl the unpaged GPTDecoder prefill resolves to at
-                # serving sizes) so paged output stays bit-identical
-                s = jnp.einsum("bhqd,bhkd->bhqk", q, vk) * sm_scale
-                neg = jnp.asarray(jnp.finfo(s.dtype).min / 2, s.dtype)
-                s = jnp.where(mask[None, None], s, neg)
-                p = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("bhqk,bhkd->bhqd", p, vv)
-                o = jnp.transpose(o, (0, 2, 1, 3)).reshape(1, C, H * d)
-                x = x + _dense(o, lp["proj_w"], lp["proj_b"])
-                h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-                ffn = _dense(
-                    jax.nn.gelu(_dense(h, lp["ffn1_w"], lp["ffn1_b"])),
-                    lp["ffn2_w"], lp["ffn2_b"])
-                x = x + ffn
-                pk[li], pv[li] = pk_l, pv_l
-                sk[li], sv[li] = sk_l, sv_l
-            pk, pv = tuple(pk), tuple(pv)
-            sk = tuple(sk) if int8 else None
-            sv = tuple(sv) if int8 else None
+        def prefill(params, pools, tokens, pages, t_start, t_len, key,
+                    temperature, *, top_k, do_sample):
+            n = tokens.shape[1]
+            cache = self._chunk_cache(pools, pages, t_start)
+            x = self._run_layers(dec, params, tokens,
+                                 (t_start + jnp.arange(n))[None, :], cache)
             # the chunk's last REAL row (padding beyond t_len is causally
             # downstream of it and cannot touch it)
-            h_last = lax.dynamic_slice_in_dim(x, t_len - 1, 1,
-                                              axis=1)[:, 0]
-            logits = dec._logits(params, h_last)               # (1, V)
-            first = dec._sample(logits, key, temperature, top_k, do_sample)
-            pk, pv, sk, sv = self._constrain_pools(pk, pv, sk, sv)
-            return pk, pv, sk, sv, first[0]
+            last = jax.lax.dynamic_slice_in_dim(x, t_len - 1, 1, axis=0)
+            first = self._sample_slots(
+                dec.next_logits(params, last), key, temperature[None],
+                top_k, do_sample)                              # (1,)
+            return cache.pools(), first[0]
 
-        # the int8 pools carry per-page scale planes as extra donated
-        # state; the fp signature omits them entirely (donating an
-        # unused placeholder would invalidate its buffer)
-        if int8:
-            def prefill(params, pk, pv, sk, sv, tokens, pages_row,
-                        chunk_pages, t_start, t_len, key, temperature, *,
-                        top_k, do_sample):
-                return run(params, pk, pv, sk, sv, tokens, pages_row,
-                           chunk_pages, t_start, t_len, key, temperature,
-                           top_k, do_sample)
+        return self._observed(prefill, kind, tokens_idx=2,
+                              static_argnames=("top_k", "do_sample"))
 
-            return self._observed(
-                jax.jit(prefill, static_argnames=("top_k", "do_sample"),
-                        donate_argnums=(1, 2, 3, 4)),
-                kind, donate=(1, 2, 3, 4), tokens_idx=5)
+    def _chunk_pages(self, slot, t_start, bucket):
+        """Host half of a chunk's cache access: where the chunk of `bucket`
+        tokens at `t_start` of `slot` is written and what it attends, as the
+        device arrays `_chunk_cache` takes (the launch's own copies)."""
+        jnp = _j().numpy
+        pt = self.page_tokens
+        if t_start % pt:
+            raise ValueError(
+                f"chunk start {t_start} is not page-aligned "
+                f"(page_tokens={pt})")
+        # the chunk's pages, padded with the trash page where the
+        # bucket overshoots the slot's mapped range (pad-token K/V is
+        # discarded)
+        row = self._table[slot].copy()
+        first_page = t_start // pt
+        chunk_pages = onp.zeros(bucket // pt, onp.int32)
+        avail = row[first_page:first_page + bucket // pt]
+        chunk_pages[:avail.size] = avail
+        return jnp.asarray(row), jnp.asarray(chunk_pages)
 
-        def prefill(params, pk, pv, tokens, pages_row, chunk_pages,
-                    t_start, t_len, key, temperature, *, top_k, do_sample):
-            pk, pv, _, _, first = run(params, pk, pv, None, None, tokens,
-                                      pages_row, chunk_pages, t_start,
-                                      t_len, key, temperature, top_k,
-                                      do_sample)
-            return pk, pv, first
-
-        return self._observed(
-            jax.jit(prefill, static_argnames=("top_k", "do_sample"),
-                    donate_argnums=(1, 2)),
-            kind, donate=(1, 2), tokens_idx=3)
-
-    def _observed(self, fn, kind, donate, tokens_idx=None):
-        """Compile-observatory wrapper for a program family: recompiles
-        past the first get forensics, and bucketed prefill growth (a new
-        chunk bucket seen at `tokens_idx`) is classified `new_bucket`.
-        `instrument_jit` passes `_cache_size` through, so
-        `xla_program_count` and the shardcheck pre-flight see the raw
-        jitted object's introspection surface."""
-        bucket = None
-        if tokens_idx is not None:
-            def bucket(args, kwargs, _i=tokens_idx):  # noqa: ARG001
-                return int(args[_i].shape[1])
-        return _compiles.instrument_jit(
-            fn, f"{self.census_name}.{kind}", bucket=bucket, donate=donate)
+    def _chunk_cache(self, pools, pages, t_start):
+        """Traced half: the cache-access object of a chunk at `t_start`
+        whose `pages` are `_chunk_pages`'s."""
+        return ChunkCache(self, pools, *pages, t_start)
 
     def _to_bucket(self, chunk_tokens):
         """``(tokens padded to their bucket, real length, bucket, pad)`` of
@@ -1037,17 +914,17 @@ class SlotDecoder:
 
     def prefill_chunk_step(self, slot, chunk_tokens, t_start, key,
                            temperature=1.0):
-        """Run ONE page-aligned prefill chunk for `slot`.
+        """Run ONE prefill chunk for `slot`.
 
         `chunk_tokens` is the 1D host slice ``prompt[t_start:t_start+n]``
-        with ``t_start`` page-aligned (0 or a multiple of `page_tokens`,
-        e.g. the shared-prefix boundary). The chunk is LAUNCHED, not waited
-        for. Returns ``(first_token, bucket, pad)`` — the sampled token as
-        the program gives it, a device scalar not yet fetched: it is
-        meaningful only when this was the prompt's final chunk, and only
-        then does the caller fetch it (``int(first)``, which blocks until
-        the chunk ran); `bucket`/`pad` feed the caller's span
-        annotations. `key` is a PRNG key or a callable that makes one: the
+        with ``t_start`` aligned as the family's pages ask (`_chunk_pages`:
+        to a page here, e.g. the shared-prefix boundary). The chunk is
+        LAUNCHED, not waited for. Returns ``(first_token, bucket, pad)`` —
+        the sampled token as the program gives it, a device scalar not yet
+        fetched: it is meaningful only when this was the prompt's final
+        chunk, and only then does the caller fetch it (``int(first)``,
+        which blocks until the chunk ran); `bucket`/`pad` feed the caller's
+        span annotations. `key` is a PRNG key or a callable that makes one: the
         scheduler hands its key maker in, so that the eager ``fold_in``
         runs inside the launch span with the rest of the host's work.
         """
@@ -1057,37 +934,16 @@ class SlotDecoder:
             self._ensure_pool()
             if self._prefill_jit is None:
                 self._prefill_jit = self._build_prefill()
-            pt = self.page_tokens
-            if t_start % pt:
-                raise ValueError(
-                    f"chunk start {t_start} is not page-aligned "
-                    f"(page_tokens={pt})")
             chunk, n, bucket, pad = self._to_bucket(chunk_tokens)
-            # the chunk's pages, padded with the trash page where the
-            # bucket overshoots the slot's mapped range (pad-token K/V is
-            # discarded)
-            first_page = t_start // pt
-            row = self._table[slot].copy()    # the launch's own (`_upload`)
-            cp = bucket // pt
-            chunk_pages = onp.zeros(cp, onp.int32)
-            avail = row[first_page:first_page + cp]
-            chunk_pages[:avail.size] = avail
+            pages = self._chunk_pages(slot, t_start, bucket)
             if callable(key):
                 key = key()
-            args = (jnp.asarray(chunk)[None, :], jnp.asarray(row),
-                    jnp.asarray(chunk_pages), jnp.int32(t_start),
+            args = (jnp.asarray(chunk)[None, :], pages, jnp.int32(t_start),
                     jnp.int32(n), key,
                     jnp.float32(max(float(temperature), 1e-6)))
-            if self._int8:
-                (self._pk, self._pv, self._sk, self._sv,
-                 first) = self._prefill_jit(
-                    self._dec._params, self._pk, self._pv, self._sk,
-                    self._sv, *args, top_k=self._top_k,
-                    do_sample=self._do_sample)
-            else:
-                self._pk, self._pv, first = self._prefill_jit(
-                    self._dec._params, self._pk, self._pv, *args,
-                    top_k=self._top_k, do_sample=self._do_sample)
+            self._pools, first = self._prefill_jit(
+                self._dec._params, self._pools, *args, top_k=self._top_k,
+                do_sample=self._do_sample)
             if self._draft_dec is not None:
                 # the draft model prefills the SAME chunk into its own
                 # pools (same pages — table/allocator are shared), so
@@ -1097,17 +953,9 @@ class SlotDecoder:
                 if self._draft_prefill_jit is None:
                     self._draft_prefill_jit = self._build_prefill(
                         self._draft_dec, "draft_prefill")
-                if self._int8:
-                    (self._dpk, self._dpv, self._dsk, self._dsv,
-                     _) = self._draft_prefill_jit(
-                        self._draft_dec._params, self._dpk, self._dpv,
-                        self._dsk, self._dsv, *args, top_k=self._top_k,
-                        do_sample=self._do_sample)
-                else:
-                    self._dpk, self._dpv, _ = self._draft_prefill_jit(
-                        self._draft_dec._params, self._dpk, self._dpv,
-                        *args, top_k=self._top_k,
-                        do_sample=self._do_sample)
+                self._draft_pools, _ = self._draft_prefill_jit(
+                    self._draft_dec._params, self._draft_pools, *args,
+                    top_k=self._top_k, do_sample=self._do_sample)
         return first, bucket, pad
 
     # -- decode -------------------------------------------------------------
@@ -1126,164 +974,28 @@ class SlotDecoder:
                 idx, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
         return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
-    def _make_write_token(self):
-        """Traced helper shared by the decode/verify/draft programs:
-        scatter one token's K or V ``(S, H, d)`` at each slot's write
-        page/offset; int8 re-quantizes just the written page under a
-        grow-only scale."""
-        jnp = _j().numpy
-        int8 = self._int8
-        S = self.max_slots
-
-        from ..contrib.quantization import quantize_symmetric
-        from ..ops.paged_attention import pack_pages, unpack_pages
-
-        def write_token(pool_l, scale_l, wpage, woff, t):
-            if not int8:
-                # whole pages out, the token's row set, whole pages back:
-                # a page is one contiguous block of the leaf, so the
-                # update runs in place. (A scatter of (H, d) rows makes
-                # the TPU's compiler turn the whole leaf to a layout with
-                # H beside d, and back.)
-                page = unpack_pages(jnp.take(pool_l, wpage, axis=0),
-                                    t.shape[-1])               # (S,H,pt,d)
-                row = jnp.arange(page.shape[2])[None, None, :, None]
-                page = jnp.where(row == woff[:, None, None, None],
-                                 t.astype(pool_l.dtype)[:, :, None, :], page)
-                return pool_l.at[wpage].set(pack_pages(page)), scale_l
-            old = jnp.take(scale_l, wpage, axis=0)             # (S, H)
-            amax = jnp.max(jnp.abs(t), axis=-1)                # (S, H)
-            new = jnp.maximum(old, jnp.maximum(amax, 1e-8) / 127.0)
-            page = jnp.take(pool_l, wpage, axis=0)             # (S,H,pt,d)
-            page = jnp.clip(
-                jnp.round(page.astype(jnp.float32)
-                          * (old / new)[:, :, None, None]),
-                -127, 127)
-            tq, _ = quantize_symmetric(t, axes=(), scale=new[:, :, None])
-            page = page.at[jnp.arange(S), :, woff].set(tq)
-            pool_l = pool_l.at[wpage].set(page.astype(jnp.int8))
-            scale_l = scale_l.at[wpage].set(new)
-            return pool_l, scale_l
-
-        return write_token
-
-    def _decode_layer_step(self, dec, lp, x, pools, table, wpage, woff,
-                           lengths, write_token):
-        """One layer of the single-token decode body — shared verbatim
-        by the decode program and each unrolled step of the draft
-        program so all three stay bit-identical. `pools` is the layer's
-        ``(pk_l, pv_l, sk_l, sv_l)``; `lengths` is how many tokens of
-        each slot the new token attends (its own included; 0 for a slot
-        that does not decode); returns updated ``(x, pools)``."""
-        jax = _j()
-        from ..models.decoding import _dense, _ln, _split_qkv
-        from ..ops.paged_attention import paged_decode_attention
-
-        H = dec._n_heads
-        d = dec._units // H
-        S = self.max_slots
-        pk_l, pv_l, sk_l, sv_l = pools
-        h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-        q, k, v = _split_qkv(_dense(h, lp["qkv_w"], lp["qkv_b"]), H)
-        # the new token's K/V is in the pool before attention reads it
-        pk_l, sk_l = write_token(pk_l, sk_l, wpage, woff, k[:, :, 0])
-        pv_l, sv_l = write_token(pv_l, sv_l, wpage, woff, v[:, :, 0])
-        with self._mesh_scope():
-            o = paged_decode_attention(q[:, :, 0], pk_l, pv_l, table,
-                                       lengths, k_scale=sk_l, v_scale=sv_l)
-        x = x + _dense(o.reshape(S, 1, H * d), lp["proj_w"], lp["proj_b"])
-        h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-        ffn = _dense(
-            jax.nn.gelu(_dense(h, lp["ffn1_w"], lp["ffn1_b"])),
-            lp["ffn2_w"], lp["ffn2_b"])
-        return x + ffn, (pk_l, pv_l, sk_l, sv_l)
-
     def _build_decode(self):
-        jax = _j()
-        jnp = jax.numpy
+        jnp = _j().numpy
         dec = self._dec
-        pt = self.page_tokens
-        int8 = self._int8
-        S = self.max_slots
-        write_token = self._make_write_token()
 
-        def run(params, pk, pv, sk, sv, table, last_tok, prev_tok, pos,
-                active, key, temperature, top_k, do_sample):
+        def decode(params, pools, table, last_tok, prev_tok, pos, active,
+                   key, temperature, *, top_k, do_sample):
             # a slot that goes on from the launch before takes the token
             # that launch gave it, which the host may not have seen yet
             last_tok = jnp.where(last_tok < 0, prev_tok, last_tok)
-            x = (params["embed"][last_tok][:, None, :]
-                 + params["pos"][pos][:, None, :])              # (S, 1, C)
-            # each slot writes at its own page/offset; slots that are
-            # free or still prefilling are redirected to the trash page
-            # and attend nothing
-            wpage = table[jnp.arange(S), pos // pt]
-            wpage = jnp.where(active, wpage, 0)
-            woff = pos % pt
-            lengths = jnp.where(active, pos + 1, 0)
-
-            # unrolled over layers — each pool leaf aliases its donated
-            # input (see _make_pools)
-            L = len(pk)
-            pk, pv = list(pk), list(pv)
-            sk = list(sk) if int8 else [None] * L
-            sv = list(sv) if int8 else [None] * L
-            for li in range(L):
-                lp = {n: a[li] for n, a in params["layers"].items()}
-                x, (pk[li], pv[li], sk[li], sv[li]) = \
-                    self._decode_layer_step(
-                        dec, lp, x, (pk[li], pv[li], sk[li], sv[li]),
-                        table, wpage, woff, lengths, write_token)
-            pk, pv = tuple(pk), tuple(pv)
-            sk = tuple(sk) if int8 else None
-            sv = tuple(sv) if int8 else None
-            logits = dec._logits(params, x[:, 0])               # (S, V)
-            nxt = self._sample_slots(logits, key, temperature, top_k,
-                                     do_sample)
+            cache = TokenCache(self, pools, table, *self._row_of(pos),
+                               active)
+            x = self._run_layers(dec, params, last_tok, pos, cache)
+            nxt = self._sample_slots(dec.next_logits(params, x), key,
+                                     temperature, top_k, do_sample)
             # free/prefilling slots carry their last token forward — the
             # host never reads them, but a defined value keeps the
             # program deterministic
             nxt = self._pin_tokens(jnp.where(active, nxt, last_tok))
-            pk, pv, sk, sv = self._constrain_pools(pk, pv, sk, sv)
-            return pk, pv, sk, sv, nxt
+            return cache.pools(), nxt
 
-        if int8:
-            def decode(params, pk, pv, sk, sv, table, last_tok, prev_tok,
-                       pos, active, key, temperature, *, top_k, do_sample):
-                return run(params, pk, pv, sk, sv, table, last_tok,
-                           prev_tok, pos, active, key, temperature, top_k,
-                           do_sample)
-
-            return self._observed(
-                jax.jit(decode, static_argnames=("top_k", "do_sample"),
-                        donate_argnums=(1, 2, 3, 4)),
-                "decode", donate=(1, 2, 3, 4))
-
-        def decode(params, pk, pv, table, last_tok, prev_tok, pos, active,
-                   key, temperature, *, top_k, do_sample):
-            pk, pv, _, _, nxt = run(params, pk, pv, None, None, table,
-                                    last_tok, prev_tok, pos, active, key,
-                                    temperature, top_k, do_sample)
-            return pk, pv, nxt
-
-        return self._observed(
-            jax.jit(decode, static_argnames=("top_k", "do_sample"),
-                    donate_argnums=(1, 2)),
-            "decode", donate=(1, 2))
-
-    def _decode_args(self, last_tok, pos, active, key, temperature):
-        """What a decode program takes after the pools: the table, the
-        launch's own copies of the host's arrays, and the tokens of the
-        launch before (`decode_step`)."""
-        jnp = _j().numpy
-        if callable(key):
-            key = key()
-        if self._tokens is None:
-            self._tokens = self._pin_tokens(
-                jnp.zeros(self.max_slots, jnp.int32))
-        return (self._table_device(), _upload(last_tok, onp.int32),
-                self._tokens, _upload(pos, onp.int32), _upload(active, bool),
-                key, _upload(temperature, onp.float32))
+        return self._observed(decode, "decode",
+                              static_argnames=("top_k", "do_sample"))
 
     def decode_step(self, last_tok, pos, active, key, temperature):
         """LAUNCH one decode step for every DECODE-ACTIVE slot. `last_tok`
@@ -1300,24 +1012,27 @@ class SlotDecoder:
         needs the tokens makes it (`Scheduler._land`). `key`: a PRNG key, or
         a callable that makes one (called inside the launch span, as in
         `prefill_chunk_step`)."""
+        jnp = _j().numpy
         with tracing.phase("mx.serve.decode.launch", "decode_launch"):
             self._refresh_params()
             self._ensure_pool()
             if self._decode_jit is None:
                 self._decode_jit = self._build_decode()
-            args = self._decode_args(last_tok, pos, active, key, temperature)
-            if self._int8:
-                (self._pk, self._pv, self._sk, self._sv,
-                 self._tokens) = self._decode_jit(
-                    self._dec._params, self._pk, self._pv, self._sk,
-                    self._sv, *args, top_k=self._top_k,
-                    do_sample=self._do_sample)
-            else:
-                self._pk, self._pv, self._tokens = self._decode_jit(
-                    self._dec._params, self._pk, self._pv, *args,
-                    top_k=self._top_k, do_sample=self._do_sample)
-            on = onp.asarray(active, bool)
-            live = int((onp.asarray(pos)[on] // self.page_tokens + 1).sum())
+            if callable(key):
+                key = key()
+            if self._tokens is None:
+                self._tokens = self._pin_tokens(
+                    jnp.zeros(self.max_slots, jnp.int32))
+            # after the pools: the table, the launch's own copies of the
+            # host's arrays, and the tokens of the launch before
+            self._pools, self._tokens = self._decode_jit(
+                self._dec._params, self._pools, self._table_device(),
+                _upload(last_tok, onp.int32), self._tokens,
+                _upload(pos, onp.int32), _upload(active, bool), key,
+                _upload(temperature, onp.float32), top_k=self._top_k,
+                do_sample=self._do_sample)
+            live = self._count_rows(
+                onp.asarray(pos, onp.int64)[onp.asarray(active, bool)])
             view = self.max_slots * self.pages_per_slot
             DECODE_PAGES["live"].inc(live)
             DECODE_PAGES["view"].inc(view)
@@ -1336,169 +1051,54 @@ class SlotDecoder:
         decode. Rows past a slot's mapped pages (``p > limit``) are
         redirected to the trash page; the scheduler never commits their
         outputs."""
-        jax = _j()
-        jnp = jax.numpy
+        jnp = _j().numpy
         dec = self._dec
-        H = dec._n_heads
         pt = self.page_tokens
-        int8 = self._int8
         S = self.max_slots
         K1 = self.spec_k + 1
-        write_token = self._make_write_token()
 
-        from ..models.decoding import _dense, _ln, _split_qkv
-
-        def run(params, pk, pv, sk, sv, table, toks, pos, active, limit):
-            P = table.shape[1]
-            PT = P * pt
-            d = dec._units // H
-            offs = jnp.arange(K1)
-            p_abs = pos[:, None] + offs[None, :]               # (S, K1)
-            pmax = params["pos"].shape[0]
-            x = (params["embed"][toks]
-                 + params["pos"][jnp.clip(p_abs, 0, pmax - 1)])
+        def verify(params, pools, table, toks, pos, active, limit):
+            p_abs = pos[:, None] + jnp.arange(K1)[None, :]     # (S, K1)
             writable = active[:, None] & (p_abs <= limit[:, None])
             wpage = jnp.take_along_axis(
-                table, jnp.clip(p_abs // pt, 0, P - 1), axis=1)
-            wpage = jnp.where(writable, wpage, 0)
-            woff = p_abs % pt
-            # (S, K1, PT) causal-per-row validity
-            mask = jnp.arange(PT)[None, None, :] <= p_abs[:, :, None]
-
-            L = len(pk)
-            pk, pv = list(pk), list(pv)
-            sk = list(sk) if int8 else [None] * L
-            sv = list(sv) if int8 else [None] * L
-            for li in range(L):
-                lp = {n: a[li] for n, a in params["layers"].items()}
-                pk_l, pv_l = pk[li], pv[li]
-                sk_l, sv_l = sk[li], sv[li]
-                h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-                q, k, v = _split_qkv(
-                    _dense(h, lp["qkv_w"], lp["qkv_b"]), H)    # (S,H,K1,d)
-                kt = jnp.transpose(k, (0, 2, 1, 3))            # (S,K1,H,d)
-                vt = jnp.transpose(v, (0, 2, 1, 3))
-                # column-at-a-time writes reuse the decode write_token
-                # exactly (int8 grow-only rescale order preserved)
-                for i in range(K1):
-                    pk_l, sk_l = write_token(pk_l, sk_l, wpage[:, i],
-                                             woff[:, i], kt[:, i])
-                    pv_l, sv_l = write_token(pv_l, sv_l, wpage[:, i],
-                                             woff[:, i], vt[:, i])
-                vk = self._dequant_view(pk_l, sk_l, table)
-                vv = self._dequant_view(pv_l, sv_l, table)
-                vk = jnp.transpose(vk, (0, 2, 1, 3, 4)).reshape(S, H, PT, d)
-                vv = jnp.transpose(vv, (0, 2, 1, 3, 4)).reshape(S, H, PT, d)
-                s = jnp.einsum("shqd,shkd->shqk", q, vk,
-                               preferred_element_type=jnp.float32)
-                s = s / math.sqrt(d)
-                s = jnp.where(mask[:, None, :, :], s, -jnp.inf)
-                p = jax.nn.softmax(s, axis=-1).astype(vv.dtype)
-                o = jnp.einsum("shqk,shkd->shqd", p, vv)
-                o = jnp.transpose(o, (0, 2, 1, 3)).reshape(S, K1, H * d)
-                x = x + _dense(o, lp["proj_w"], lp["proj_b"])
-                h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-                ffn = _dense(
-                    jax.nn.gelu(_dense(h, lp["ffn1_w"], lp["ffn1_b"])),
-                    lp["ffn2_w"], lp["ffn2_b"])
-                x = x + ffn
-                pk[li], pv[li] = pk_l, pv_l
-                sk[li], sv[li] = sk_l, sv_l
-            pk, pv = tuple(pk), tuple(pv)
-            sk = tuple(sk) if int8 else None
-            sv = tuple(sv) if int8 else None
-            logits = dec._logits(
-                params, x.reshape(S * K1, -1)).reshape(S, K1, -1)
+                table, jnp.clip(p_abs // pt, 0, table.shape[1] - 1), axis=1)
+            cache = RowsCache(self, pools, table,
+                              jnp.where(writable, wpage, 0), p_abs % pt,
+                              p_abs)
+            x = self._run_layers(dec, params, toks, p_abs, cache)
+            logits = dec.next_logits(params, x).reshape(S, K1, -1)
             tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            tgt = jnp.where(active[:, None], tgt, toks)
-            pk, pv, sk, sv = self._constrain_pools(pk, pv, sk, sv)
-            return pk, pv, sk, sv, tgt
+            return cache.pools(), jnp.where(active[:, None], tgt, toks)
 
-        if int8:
-            def verify(params, pk, pv, sk, sv, table, toks, pos, active,
-                       limit):
-                return run(params, pk, pv, sk, sv, table, toks, pos,
-                           active, limit)
-
-            return self._observed(
-                jax.jit(verify, donate_argnums=(1, 2, 3, 4)),
-                "verify", donate=(1, 2, 3, 4))
-
-        def verify(params, pk, pv, table, toks, pos, active, limit):
-            pk, pv, _, _, tgt = run(params, pk, pv, None, None, table,
-                                    toks, pos, active, limit)
-            return pk, pv, tgt
-
-        return self._observed(
-            jax.jit(verify, donate_argnums=(1, 2)),
-            "verify", donate=(1, 2))
+        return self._observed(verify, "verify")
 
     def _build_draft(self):
         """ONE draft-model program: k unrolled greedy decode steps
         (each step identical in structure to the decode program, against
         the draft's own per-layer pools) — k drafted tokens per launch,
         feeding the target's verify program."""
-        jax = _j()
-        jnp = jax.numpy
+        jnp = _j().numpy
         dec = self._draft_dec
-        pt = self.page_tokens
-        int8 = self._int8
-        S = self.max_slots
-        K = self.spec_k
-        write_token = self._make_write_token()
 
-        def run(params, pk, pv, sk, sv, table, last_tok, pos, active,
-                limit):
-            P = table.shape[1]
-            pmax = params["pos"].shape[0]
-            L = len(pk)
-            pk, pv = list(pk), list(pv)
-            sk = list(sk) if int8 else [None] * L
-            sv = list(sv) if int8 else [None] * L
-            cur = last_tok
+        def draft(params, pools, table, last_tok, pos, active, limit):
+            cur, leaves = last_tok, pools
             outs = []
-            for i in range(K):
+            for i in range(self.spec_k):
                 p_i = pos + i
-                wpage = table[jnp.arange(S), jnp.clip(p_i // pt, 0, P - 1)]
-                wpage = jnp.where(active & (p_i <= limit), wpage, 0)
-                woff = p_i % pt
-                lengths = jnp.where(active, p_i + 1, 0)
-                x = (params["embed"][cur][:, None, :]
-                     + params["pos"][jnp.clip(p_i, 0, pmax - 1)][:, None, :])
-                for li in range(L):
-                    lp = {n: a[li] for n, a in params["layers"].items()}
-                    x, (pk[li], pv[li], sk[li], sv[li]) = \
-                        self._decode_layer_step(
-                            dec, lp, x, (pk[li], pv[li], sk[li], sv[li]),
-                            table, wpage, woff, lengths, write_token)
-                logits = dec._logits(params, x[:, 0])
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                col, woff, rows = self._row_of(p_i)
+                cache = TokenCache(
+                    self, leaves, table,
+                    jnp.clip(col, 0, table.shape[1] - 1), woff, rows,
+                    active, active & (p_i <= limit))
+                x = self._run_layers(dec, params, cur, p_i, cache)
+                nxt = jnp.argmax(dec.next_logits(params, x),
+                                 axis=-1).astype(jnp.int32)
                 cur = jnp.where(active, nxt, cur)
                 outs.append(cur)
-            pk, pv = tuple(pk), tuple(pv)
-            sk = tuple(sk) if int8 else None
-            sv = tuple(sv) if int8 else None
-            pk, pv, sk, sv = self._constrain_pools(pk, pv, sk, sv)
-            return pk, pv, sk, sv, jnp.stack(outs, axis=1)      # (S, K)
+                leaves = cache.leaves
+            return cache.pools(), jnp.stack(outs, axis=1)       # (S, K)
 
-        if int8:
-            def draft(params, pk, pv, sk, sv, table, last_tok, pos,
-                      active, limit):
-                return run(params, pk, pv, sk, sv, table, last_tok, pos,
-                           active, limit)
-
-            return self._observed(
-                jax.jit(draft, donate_argnums=(1, 2, 3, 4)),
-                "draft", donate=(1, 2, 3, 4))
-
-        def draft(params, pk, pv, table, last_tok, pos, active, limit):
-            pk, pv, _, _, toks = run(params, pk, pv, None, None, table,
-                                     last_tok, pos, active, limit)
-            return pk, pv, toks
-
-        return self._observed(
-            jax.jit(draft, donate_argnums=(1, 2)),
-            "draft", donate=(1, 2))
+        return self._observed(draft, "draft")
 
     def spec_propose(self, seqs):
         """Host n-gram drafts: `seqs` is a per-slot list (None for
@@ -1512,28 +1112,24 @@ class SlotDecoder:
                     out[s] = self._ngram.propose(seq)
         return out
 
+    def _spec_args(self, toks, pos, active, limit):
+        """What the draft and verify programs take after the pools."""
+        jnp = _j().numpy
+        return (self._table_device(), jnp.asarray(toks, jnp.int32),
+                jnp.asarray(pos, jnp.int32), jnp.asarray(active, bool),
+                jnp.asarray(limit, jnp.int32))
+
     def spec_draft_step(self, last_tok, pos, active, limit):
         """Run the draft model's k-step program; returns drafted tokens
         ``(max_slots, spec_k)`` as host numpy."""
-        jnp = _j().numpy
         with tracing.phase("mx.serve.spec.draft.launch", "decode_launch"):
             self._draft_dec._auto_refresh()
             self._ensure_pool()
             if self._draft_jit is None:
                 self._draft_jit = self._build_draft()
-            args = (self._table_device(),
-                    jnp.asarray(last_tok, jnp.int32),
-                    jnp.asarray(pos, jnp.int32),
-                    jnp.asarray(active, bool),
-                    jnp.asarray(limit, jnp.int32))
-            if self._int8:
-                (self._dpk, self._dpv, self._dsk, self._dsv,
-                 toks) = self._draft_jit(
-                    self._draft_dec._params, self._dpk, self._dpv,
-                    self._dsk, self._dsv, *args)
-            else:
-                self._dpk, self._dpv, toks = self._draft_jit(
-                    self._draft_dec._params, self._dpk, self._dpv, *args)
+            self._draft_pools, toks = self._draft_jit(
+                self._draft_dec._params, self._draft_pools,
+                *self._spec_args(last_tok, pos, active, limit))
         with tracing.phase("mx.serve.spec.draft.readback",
                            "decode_readback"):
             return onp.asarray(toks)
@@ -1546,7 +1142,6 @@ class SlotDecoder:
         ``[last, d_1..d_i]`` — the scheduler accepts the longest drafted
         prefix matching rows ``0..m-1`` plus row ``m`` as the bonus
         token (>= 1 token of guaranteed progress per round)."""
-        jnp = _j().numpy
         with tracing.phase("mx.serve.spec.verify.launch", "decode_launch"):
             self._refresh_params()
             self._ensure_pool()
@@ -1557,19 +1152,9 @@ class SlotDecoder:
             toks = onp.concatenate(
                 [onp.asarray(last_tok, onp.int32)[:, None],
                  onp.asarray(drafts, onp.int32)], axis=1)
-            args = (self._table_device(),
-                    jnp.asarray(toks),
-                    jnp.asarray(pos, jnp.int32),
-                    jnp.asarray(active, bool),
-                    jnp.asarray(limit, jnp.int32))
-            if self._int8:
-                (self._pk, self._pv, self._sk, self._sv,
-                 tgt) = self._verify_jit(
-                    self._dec._params, self._pk, self._pv, self._sk,
-                    self._sv, *args)
-            else:
-                self._pk, self._pv, tgt = self._verify_jit(
-                    self._dec._params, self._pk, self._pv, *args)
+            self._pools, tgt = self._verify_jit(
+                self._dec._params, self._pools,
+                *self._spec_args(toks, pos, active, limit))
         with tracing.phase("mx.serve.spec.verify.readback",
                            "decode_readback"):
             return onp.asarray(tgt)
@@ -1616,20 +1201,12 @@ class SlotDecoder:
         under int8) — parity/tolerance checks in tests, not a hot path."""
         jnp = _j().numpy
         self._ensure_pool()
+        cache = PageCache(self, self._pools)
         idx = jnp.asarray(self._table[slot])
-        outs = []
-        for pool, scale in ((self._pk, self._sk), (self._pv, self._sv)):
-            views = []
-            L = len(pool)
-            for layer in range(L):
-                v = self._dequant_view(pool[layer],
-                                       None if scale is None
-                                       else scale[layer], idx)
-                P, H, pt, d = v.shape
-                views.append(jnp.transpose(v, (1, 0, 2, 3))
-                             .reshape(H, P * pt, d)[:, :n_tokens])
-            outs.append(onp.asarray(jnp.stack(views), onp.float32))
-        return outs[0], outs[1]
+        rows = [cache.rows(li, idx) for li in range(len(self._pools["k"]))]
+        return tuple(
+            onp.asarray(jnp.stack([r[kv][:, :n_tokens] for r in rows]),
+                        onp.float32) for kv in (0, 1))
 
     def xla_program_count(self):
         """Number of compiled programs across every family this engine
@@ -1654,10 +1231,9 @@ class SlotDecoder:
         (analyzed at `bucket`, default the largest chunk bucket) and the
         decode jit, which is audited as a latency hot path.
 
-        The engine runs single-chip today, so with the default
-        ``mesh=None`` this is a per-device byte budget (SC006) plus the
-        donation audit (SC004); pass a mesh once pod-scale serving lands
-        and the same call re-validates the layout against it. Returns
+        With the default ``mesh=None`` (one device) this is a per-device
+        byte budget (SC006) plus the donation audit (SC004); the sharded
+        engine passes its mesh and layout (`_shardcheck_specs`). Returns
         ``{"prefill": ShardReport, "decode": ShardReport}``.
         """
         import functools
@@ -1673,44 +1249,35 @@ class SlotDecoder:
             self._prefill_jit = self._build_prefill()
         if self._decode_jit is None:
             self._decode_jit = self._build_decode()
-        params = self._dec._params
-        pools = (self._pk, self._pv) + ((self._sk, self._sv)
-                                        if self._int8 else ())
-        donate = (1, 2, 3, 4) if self._int8 else (1, 2)
         S = self.max_slots
         key = next_key()
-        i32, f32 = _j().numpy.int32, _j().numpy.float32
-        statics = {"top_k": self._top_k, "do_sample": self._do_sample}
-
+        i32, f32 = jax.numpy.int32, jax.numpy.float32
         bucket = int(bucket) if bucket is not None else self.chunk_buckets[-1]
-        head_specs = self._shardcheck_specs()
-        out_specs = self._shardcheck_out_specs()
-        prefill_args = (params,) + pools + (
-            sds((1, bucket), i32),                      # tokens
-            sds((self.pages_per_slot,), i32),           # pages_row
-            sds((bucket // self.page_tokens,), i32),    # chunk_pages
-            sds((), i32), sds((), i32),                 # t_start, t_len
-            key, sds((), f32))                          # key, temperature
-        pf_specs = None if head_specs is None else head_specs + (
-            (None,) * (len(prefill_args) - len(head_specs)))
-        prefill = shardcheck(
-            functools.partial(self._prefill_jit, **statics), *prefill_args,
-            mesh=mesh, specs=pf_specs, out_specs=out_specs,
-            donate_argnums=donate, hbm_budget_gb=hbm_budget_gb,
-            name=f"SlotDecoder.prefill[b{bucket}]")
+        head_specs, out_specs = self._shardcheck_specs()
 
-        decode_args = (params,) + pools + (
+        def check(jitted, args, name, **kw):
+            args = (self._dec._params, self._pools) + args
+            specs = None if head_specs is None else head_specs + (
+                (None,) * (len(args) - len(head_specs)))
+            return shardcheck(
+                functools.partial(jitted, top_k=self._top_k,
+                                  do_sample=self._do_sample),
+                *args, mesh=mesh, specs=specs, out_specs=out_specs,
+                donate_argnums=(1,), hbm_budget_gb=hbm_budget_gb, name=name,
+                **kw)
+
+        prefill = check(self._prefill_jit, (
+            sds((1, bucket), i32),                      # tokens
+            jax.eval_shape(lambda: self._chunk_pages(0, 0, bucket)),
+            sds((), i32), sds((), i32),                 # t_start, t_len
+            key, sds((), f32)),                         # key, temperature
+            f"SlotDecoder.prefill[b{bucket}]")
+        decode = check(self._decode_jit, (
             sds((S, self.pages_per_slot), i32),         # page table
             sds((S,), i32), sds((S,), i32),             # last_tok, prev_tok
             sds((S,), i32), sds((S,), bool),            # pos, active
-            key, sds((S,), f32))                        # key, temperature
-        dc_specs = None if head_specs is None else head_specs + (
-            (None,) * (len(decode_args) - len(head_specs)))
-        decode = shardcheck(
-            functools.partial(self._decode_jit, **statics), *decode_args,
-            mesh=mesh, specs=dc_specs, out_specs=out_specs,
-            donate_argnums=donate, hbm_budget_gb=hbm_budget_gb,
-            hot_path=True, name="SlotDecoder.decode")
+            key, sds((S,), f32)),                       # key, temperature
+            "SlotDecoder.decode", hot_path=True)
         return {"prefill": prefill, "decode": decode}
 
     def hbm_crosscheck(self, mesh=None):

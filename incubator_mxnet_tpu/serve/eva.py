@@ -25,9 +25,14 @@ multiples, so none straddles a roll), in decode before the step that needs
 it; both inside the step, as span ``mx.serve.eva.roll`` and step-record
 field ``eva_roll``.
 
-The block itself is `models.evabyte.EvaByteDecoder.layer`; the prefill-chunk
-and decode programs here hand it a cache-access object each (`_ChunkCache`,
-`_TokenCache`) and carry no copy of it.
+The programs are `SlotDecoder`'s own (one prefill-chunk and one decode
+skeleton for every family, `serve/engine.py`), run over this family's block,
+`models.evabyte.EvaByteDecoder.layer`. What is brought here is what is the
+family's: the page arithmetic (`pages_needed`, `pages_at`, `_table_width`,
+`_row_of`: where a position's row lies in ``summary pages ++ window pages`` and
+how many rows it attends; `_count_rows`), a chunk's cache access (`_chunk_pages`
+on the host, `_ChunkCache` traced: attention over summary and window rows;
+decode's is the shared `pages.TokenCache`), the roll, and the refusals.
 
 Not served for this family, each refused with `NotImplementedError`:
 speculative decoding (``spec_k > 0``), int8 pages, prefix reuse (what may be
@@ -42,9 +47,10 @@ import weakref
 
 import numpy as onp
 
-from ..telemetry import compiles as _compiles
+from ..models.evabyte import EvaByteDecoder, summarize
 from ..telemetry import registry, tracing
-from .engine import DECODE_PAGES, SlotDecoder, _j
+from .engine import SlotDecoder, _j
+from .pages import PageCache
 
 __all__ = ["EvaSlotDecoder"]
 
@@ -59,35 +65,31 @@ DECODE_ROWS = {kind: registry.counter("mx_serve_decode_rows_total",
                for kind in ("window", "summary")}
 
 
-class _ChunkCache:
+class _ChunkCache(PageCache):
     """Cache access of one prefill chunk of one slot: the chunk's rows go
     into its pages, and its queries attend every summary row (no mask
     beyond their count) and the window's rows up to themselves."""
 
-    def __init__(self, eng, pk, pv, sum_pages, n_sum, win_pages, chunk_pages,
+    def __init__(self, eng, pools, sum_pages, n_sum, win_pages, chunk_pages,
                  r0):
-        self.eng, self.pk, self.pv = eng, list(pk), list(pv)
+        super().__init__(eng, pools)
         self.sum_pages, self.n_sum = sum_pages, n_sum
         self.win_pages, self.chunk_pages, self.r0 = win_pages, chunk_pages, r0
 
     def attend(self, li, q, k, v):
         jax = _j()
         jnp = jax.numpy
-        from ..ops.paged_attention import pack_pages
-
-        eng = self.eng
-        pt = eng.page_tokens
+        pt = self.eng.page_tokens
         t, h, d = q.shape
-        dt = self.pk[li].dtype
+        dt = self.leaves["k"][li].dtype
 
-        def to_pages(x):           # (T, H, d) -> (T / pt, H, pt, d), stored
-            x = x.astype(dt).reshape(t // pt, pt, h, d)
-            return pack_pages(jnp.transpose(x, (0, 2, 1, 3)))
+        def to_pages(x):           # (T, H, d) -> (T / pt, H, pt, d)
+            return jnp.transpose(x.astype(dt).reshape(t // pt, pt, h, d),
+                                 (0, 2, 1, 3))
 
-        pk = self.pk[li] = self.pk[li].at[self.chunk_pages].set(to_pages(k))
-        pv = self.pv[li] = self.pv[li].at[self.chunk_pages].set(to_pages(v))
-        ks, vs = eng._rows(pk, self.sum_pages), eng._rows(pv, self.sum_pages)
-        kw, vw = eng._rows(pk, self.win_pages), eng._rows(pv, self.win_pages)
+        self.write_pages(li, self.chunk_pages, to_pages(k), to_pages(v))
+        ks, vs = self.rows(li, self.sum_pages)
+        kw, vw = self.rows(li, self.win_pages)
         qd = q.astype(dt)
         s_r = jnp.einsum("thd,hjd->htj", qd, ks,
                          preferred_element_type=jnp.float32)
@@ -105,26 +107,6 @@ class _ChunkCache:
                            preferred_element_type=jnp.float32)
                 + jnp.einsum("htm,hmd->thd", p[..., n_r:], vw,
                              preferred_element_type=jnp.float32))
-
-
-class _TokenCache:
-    """Cache access of one decode step: each slot's new row goes to its
-    window page, and its query attends the slot's table row (summary pages,
-    then window pages) as far as `lengths`."""
-
-    def __init__(self, eng, pk, pv, table, wpage, woff, lengths, write_token):
-        self.eng, self.pk, self.pv = eng, list(pk), list(pv)
-        self.table, self.wpage, self.woff = table, wpage, woff
-        self.lengths, self.write_token = lengths, write_token
-
-    def attend(self, li, q, k, v):
-        from ..ops.paged_attention import paged_decode_attention
-
-        pk, _ = self.write_token(self.pk[li], None, self.wpage, self.woff, k)
-        pv, _ = self.write_token(self.pv[li], None, self.wpage, self.woff, v)
-        self.pk[li], self.pv[li] = pk, pv
-        with self.eng._mesh_scope():
-            return paged_decode_attention(q, pk, pv, self.table, self.lengths)
 
 
 class EvaSlotDecoder(SlotDecoder):
@@ -200,7 +182,7 @@ class EvaSlotDecoder(SlotDecoder):
                 labels={"kind": kind})
 
     def _resolve_decoder(self, source):
-        if getattr(source, "family", None) != "evabyte":
+        if not isinstance(source, EvaByteDecoder):
             raise TypeError("EvaSlotDecoder needs an EvaByteDecoder, got "
                             f"{type(source).__name__}")
         return source
@@ -251,6 +233,23 @@ class EvaSlotDecoder(SlotDecoder):
         """One past the last position of `pos`'s window."""
         return (pos // self.window + 1) * self.window
 
+    def _row_of(self, pos):
+        """As `SlotDecoder._row_of`: a position's row lies in its window's
+        pages, after the summary pages of the windows before, and attends
+        their summary rows and its window's rows up to itself."""
+        pt = self.page_tokens
+        w, r = pos // self.window, pos % self.window
+        return (self.summary_pages * w + r // pt, r % pt,
+                (self.window // self.chunk) * w + r + 1)
+
+    def _count_rows(self, at):
+        rows_s = int((at // self.window).sum()) * (self.window // self.chunk)
+        rows_w = int((at % self.window + 1).sum())
+        DECODE_ROWS["window"].inc(rows_w)
+        DECODE_ROWS["summary"].inc(rows_s)
+        return int((-(-(at % self.window + 1) // self.page_tokens)).sum()) \
+            + rows_s // self.page_tokens
+
     # -- page table -----------------------------------------------------------
 
     def set_slot_pages(self, slot, pages):
@@ -261,43 +260,28 @@ class EvaSlotDecoder(SlotDecoder):
         super().clear_slot(slot)
         self._held[slot] = self._rolled[slot] = 0
 
-    def _rows(self, pool_l, pages):
-        """Pages `pages` of one layer's leaf as rows: ``(H, n * pt, d)``."""
-        jnp = _j().numpy
-        v = self._dequant_view(pool_l, None, pages)         # (n, H, pt, d)
-        n, h, pt, d = v.shape
-        return jnp.transpose(v, (1, 0, 2, 3)).reshape(h, n * pt, d)
-
     # -- the roll -------------------------------------------------------------
 
     def _build_roll(self):
         jax = _j()
         jnp = jax.numpy
-        from ..models.evabyte import summarize
-        from ..ops.paged_attention import pack_pages
-
         c, pt, n_sum = self.chunk, self.page_tokens, self.summary_pages
 
-        def mx_eva_roll(features, pk, pv, win_pages, new_pages):
+        def mx_eva_roll(features, pools, win_pages, new_pages):
             with jax.named_scope("mx_eva_roll"):
-                pk, pv = list(pk), list(pv)
+                cache = PageCache(self, pools)
                 for li, (phi, mu) in enumerate(features):
-                    k, v = self._rows(pk[li], win_pages), \
-                        self._rows(pv[li], win_pages)          # (H, W, d)
+                    k, v = cache.rows(li, win_pages)           # (H, W, d)
                     h, w, d = k.shape
                     kh, vh = summarize(k.reshape(h, w // c, c, d),
                                        v.reshape(h, w // c, c, d),
                                        phi[:, None, :], mu[:, None, :])
-                    for pool, rows in ((pk, kh), (pv, vh)):
-                        pages = jnp.transpose(
-                            rows.reshape(h, n_sum, pt, d), (1, 0, 2, 3))
-                        pool[li] = pool[li].at[new_pages].set(
-                            pack_pages(pages.astype(pool[li].dtype)))
-                return tuple(pk), tuple(pv)
+                    cache.write_pages(li, new_pages, *(
+                        jnp.transpose(rows.reshape(h, n_sum, pt, d),
+                                      (1, 0, 2, 3)) for rows in (kh, vh)))
+                return cache.pools()
 
-        return _compiles.ledgered_jit(
-            mx_eva_roll, family=f"{self.census_name}.eva_roll",
-            donate_argnums=(1, 2))
+        return self._observed(mx_eva_roll, "eva_roll")
 
     def roll_step(self, slot, win_pages, new_pages):
         """Turn `slot`'s finished window (pool pages `win_pages`, whole and
@@ -316,134 +300,36 @@ class EvaSlotDecoder(SlotDecoder):
                     f"and {len(new_pages)}")
             features = tuple((lp["phi"], lp["mu"])
                              for lp in self._dec._params["layers"])
-            self._pk, self._pv = self._roll_jit(
-                features, self._pk, self._pv,
-                jnp.asarray(win_pages, jnp.int32),
+            self._pools = self._roll_jit(
+                features, self._pools, jnp.asarray(win_pages, jnp.int32),
                 jnp.asarray(new_pages, jnp.int32))
             self._rolled[slot] += 1
             ROLLS.inc()
 
-    # -- chunked prefill ------------------------------------------------------
+    # -- a prefill chunk's cache access (`SlotDecoder._build_prefill`) --------
 
-    def _build_prefill(self):
-        jax = _j()
-        jnp = jax.numpy
-        dec = self._dec
-
-        def prefill(params, pk, pv, tokens, sum_pages, n_sum, win_pages,
-                    chunk_pages, t_start, t_len, key, temperature, *, top_k,
-                    do_sample):
-            n = tokens.shape[1]
-            cache = _ChunkCache(self, pk, pv, sum_pages, n_sum, win_pages,
-                                chunk_pages, t_start % self.window)
-            x = dec.forward(params, tokens[0], t_start + jnp.arange(n), cache)
-            last = jax.lax.dynamic_slice_in_dim(x, t_len - 1, 1, axis=0)
-            logits = dec.next_byte_logits(params, last)          # (1, V)
-            first = self._sample_slots(logits, key, temperature[None],
-                                       top_k, do_sample)
-            return tuple(cache.pk), tuple(cache.pv), first[0]
-
-        def bucket(args, kwargs):  # noqa: ARG001
-            return int(args[3].shape[1])
-
-        return _compiles.ledgered_jit(
-            prefill, family=f"{self.census_name}.prefill", bucket=bucket,
-            static_argnames=("top_k", "do_sample"), donate_argnums=(1, 2))
-
-    def prefill_chunk_step(self, slot, chunk_tokens, t_start, key,
-                           temperature=1.0):
-        """One prefill chunk for `slot`, as `SlotDecoder`'s (launched, its
-        first token returned unfetched); `t_start` is a multiple of
+    def _chunk_pages(self, slot, t_start, bucket):
+        """As `SlotDecoder._chunk_pages`; `t_start` is a multiple of
         `prefill_chunk`, and the chunk's window pages are mapped (the
         scheduler saw to both)."""
         jnp = _j().numpy
-        with tracing.phase("mx.serve.prefill.launch", "prefill_launch"):
-            self._ensure_pool()
-            if self._prefill_jit is None:
-                self._prefill_jit = self._build_prefill()
-            pt = self.page_tokens
-            if t_start % self.prefill_chunk:
-                raise ValueError(
-                    f"chunk start {t_start} is not a multiple of "
-                    f"prefill_chunk ({self.prefill_chunk})")
-            chunk, n, bucket, pad = self._to_bucket(chunk_tokens)
-            row = self._table[slot].copy()    # the launch's own
-            n_sum = self.summary_pages * (t_start // self.window)
-            sum_pages = onp.zeros(max(1, self.pages_per_slot
-                                      - self.window_pages), onp.int32)
-            sum_pages[:n_sum] = row[:n_sum]
-            win_pages = row[n_sum:n_sum + self.window_pages]
-            first_page = (t_start % self.window) // pt
-            chunk_pages = onp.zeros(bucket // pt, onp.int32)
-            avail = win_pages[first_page:first_page + bucket // pt]
-            chunk_pages[:avail.size] = avail
-            if callable(key):
-                key = key()
-            self._pk, self._pv, first = self._prefill_jit(
-                self._dec._params, self._pk, self._pv,
-                jnp.asarray(chunk)[None, :], jnp.asarray(sum_pages),
-                jnp.int32(n_sum * pt), jnp.asarray(win_pages),
-                jnp.asarray(chunk_pages), jnp.int32(t_start), jnp.int32(n),
-                key, jnp.float32(max(float(temperature), 1e-6)),
-                top_k=self._top_k, do_sample=self._do_sample)
-        return first, bucket, pad
+        pt = self.page_tokens
+        if t_start % self.prefill_chunk:
+            raise ValueError(
+                f"chunk start {t_start} is not a multiple of "
+                f"prefill_chunk ({self.prefill_chunk})")
+        row = self._table[slot].copy()    # the launch's own
+        n_sum = self.summary_pages * (t_start // self.window)
+        sum_pages = onp.zeros(max(1, self.pages_per_slot
+                                  - self.window_pages), onp.int32)
+        sum_pages[:n_sum] = row[:n_sum]
+        win_pages = row[n_sum:n_sum + self.window_pages]
+        first_page = (t_start % self.window) // pt
+        chunk_pages = onp.zeros(bucket // pt, onp.int32)
+        avail = win_pages[first_page:first_page + bucket // pt]
+        chunk_pages[:avail.size] = avail
+        return (jnp.asarray(sum_pages), jnp.int32(n_sum * pt),
+                jnp.asarray(win_pages), jnp.asarray(chunk_pages))
 
-    # -- decode ---------------------------------------------------------------
-
-    def _build_decode(self):
-        jax = _j()
-        jnp = jax.numpy
-        dec = self._dec
-        pt, S = self.page_tokens, self.max_slots
-        summary_rows = self.window // self.chunk
-        write_token = self._make_write_token()
-
-        def decode(params, pk, pv, table, last_tok, prev_tok, pos, active,
-                   key, temperature, *, top_k, do_sample):
-            # a slot that goes on from the launch before takes its token
-            last_tok = jnp.where(last_tok < 0, prev_tok, last_tok)
-            w, r = pos // self.window, pos % self.window
-            # free or prefilling slots write to the trash page and attend
-            # nothing
-            wpage = table[jnp.arange(S), self.summary_pages * w + r // pt]
-            wpage = jnp.where(active, wpage, 0)
-            lengths = jnp.where(active, summary_rows * w + r + 1, 0)
-            cache = _TokenCache(self, pk, pv, table, wpage, r % pt, lengths,
-                                write_token)
-            x = dec.forward(params, last_tok, pos, cache)
-            logits = dec.next_byte_logits(params, x)             # (S, V)
-            nxt = self._sample_slots(logits, key, temperature, top_k,
-                                     do_sample)
-            return (tuple(cache.pk), tuple(cache.pv),
-                    jnp.where(active, nxt, last_tok))
-
-        return _compiles.ledgered_jit(
-            decode, family=f"{self.census_name}.decode",
-            static_argnames=("top_k", "do_sample"), donate_argnums=(1, 2))
-
-    def decode_step(self, last_tok, pos, active, key, temperature):
-        """Launch one decode step for every decode-active slot, as
-        `SlotDecoder`'s (tokens returned unfetched; a negative `last_tok`
-        is the launch before's); a slot whose `pos` opens a window was
-        rolled before (the scheduler saw to it)."""
-        with tracing.phase("mx.serve.decode.launch", "decode_launch"):
-            self._ensure_pool()
-            if self._decode_jit is None:
-                self._decode_jit = self._build_decode()
-            self._pk, self._pv, self._tokens = self._decode_jit(
-                self._dec._params, self._pk, self._pv,
-                *self._decode_args(last_tok, pos, active, key, temperature),
-                top_k=self._top_k, do_sample=self._do_sample)
-            at = onp.asarray(pos, onp.int64)[onp.asarray(active, bool)]
-            rows_s = int((at // self.window).sum()) \
-                * (self.window // self.chunk)
-            rows_w = int((at % self.window + 1).sum())
-            live = int((-(-(at % self.window + 1) // self.page_tokens)).sum()) \
-                + rows_s // self.page_tokens
-            view = self.max_slots * self.pages_per_slot
-            DECODE_PAGES["live"].inc(live)
-            DECODE_PAGES["view"].inc(view)
-            DECODE_ROWS["window"].inc(rows_w)
-            DECODE_ROWS["summary"].inc(rows_s)
-            tracing.count(pages_live=live, pages_view=view)
-        return self._tokens
+    def _chunk_cache(self, pools, pages, t_start):
+        return _ChunkCache(self, pools, *pages, t_start % self.window)

@@ -6,8 +6,9 @@ pools. This module scales one replica *within* a host by tensor
 parallelism: a :class:`ServeLayout` of partition rules places every
 param and pool leaf onto a device mesh, and :class:`ShardedSlotDecoder`
 threads those placements through the inherited program families via the
-three seams the base engine exposes (`_refresh_params`,
-`_constrain_pools`, `_shardcheck_specs`) — the programs themselves are
+seams the base engine exposes (`_refresh_params`, `_place_pools`,
+`_constrain_pools`, `_pin_tokens`, `_shardcheck_specs`) — the programs
+themselves are
 untouched, so every single-chip invariant survives sharding:
 
 - exactly two compiled program families per replica (prefill growth by
@@ -23,10 +24,10 @@ untouched, so every single-chip invariant survives sharding:
 Layout (the `ServeLayout` defaults, after SNIPPETS.md [2] fmengine
 ``match_partition_rules`` and [3] fsdp×tp ``SpecLayout``):
 
-- attention K/V pools ``(n_pages, H, page_tokens, d)`` →
-  ``P(None, tp, None, None)``: heads-sharded, so each device holds its
-  heads' pages for the WHOLE pool — per-device KV HBM drops by the TP
-  degree (int8 scale planes ``(n_pages, H)`` shard the same way);
+- every leaf of the pools pytree (`serve/pages.py`: pages ``(n_pages, H,
+  ...)``, int8 scale planes ``(n_pages, H)``) → ``P(None, tp)``:
+  heads-sharded, so each device holds its heads' pages for the WHOLE
+  pool — per-device KV HBM drops by the TP degree;
 - matmuls Megatron-style with one deliberate twist: ffn1 is
   column-parallel / ffn2 row-parallel (the classic pair, one
   all-reduce), but the FUSED qkv matmul runs row-parallel rather than
@@ -200,13 +201,9 @@ class ServeLayout:
         )
 
     def pool_spec(self):
-        """K/V pool leaves ``(n_pages, H, page_tokens, d)``: heads on
-        the TP axis."""
-        P = _j().sharding.PartitionSpec
-        return P(None, self.tp_axis, None, None)
-
-    def scale_spec(self):
-        """int8 per-page scale planes ``(n_pages, H)``: same H axis."""
+        """Every leaf of the pools pytree (`serve/pages.py`), pages
+        ``(n_pages, H, ...)`` and int8 scale planes ``(n_pages, H)``
+        alike: heads on the TP axis."""
         P = _j().sharding.PartitionSpec
         return P(None, self.tp_axis)
 
@@ -260,31 +257,19 @@ class ServeLayout:
             lambda x, s: jax.device_put(x, self.sharding(s)),
             params, specs)
 
-    def place_pools(self, pk, pv, sk, sv):
-        """device_put the per-layer pool (and int8 scale) leaves."""
+    def place_pools(self, pools):
+        """device_put every leaf of the pools pytree."""
         jax = _j()
         ps = self.sharding(self.pool_spec())
-        ss = self.sharding(self.scale_spec())
-        pk = tuple(jax.device_put(x, ps) for x in pk)
-        pv = tuple(jax.device_put(x, ps) for x in pv)
-        if sk is not None:
-            sk = tuple(jax.device_put(x, ss) for x in sk)
-            sv = tuple(jax.device_put(x, ss) for x in sv)
-        return pk, pv, sk, sv
+        return jax.tree.map(lambda x: jax.device_put(x, ps), pools)
 
-    def constrain_pools(self, pk, pv, sk, sv):
+    def constrain_pools(self, pools):
         """Inside a traced program: pin updated pool leaves back to the
         input placement so donation aliasing survives compilation."""
         jax = _j()
-        wsc = jax.lax.with_sharding_constraint
         ps = self.sharding(self.pool_spec())
-        ss = self.sharding(self.scale_spec())
-        pk = tuple(wsc(x, ps) for x in pk)
-        pv = tuple(wsc(x, ps) for x in pv)
-        if sk is not None:
-            sk = tuple(wsc(x, ss) for x in sk)
-            sv = tuple(wsc(x, ss) for x in sv)
-        return pk, pv, sk, sv
+        return jax.tree.map(
+            lambda x: jax.lax.with_sharding_constraint(x, ps), pools)
 
     def describe(self):
         """Human-readable rule table (docs/tests)."""
@@ -363,57 +348,32 @@ class ShardedSlotDecoder(SlotDecoder):
 
         return mesh_scope(self.layout.mesh)
 
-    def _make_pools(self, dec):
-        pk, pv, sk, sv = super()._make_pools(dec)
-        return self.layout.place_pools(pk, pv, sk, sv)
+    def _place_pools(self, pools):
+        """Fresh pools, and a disagg page-migration's eager scatters
+        (whose outputs carry whatever sharding the eager op picked): pin
+        them to the pool layout, or the next donated program would see
+        mismatched input placements (the same trap `ServeLayout.sharding`
+        closes)."""
+        return self.layout.place_pools(pools)
 
-    def _constrain_pools(self, pk, pv, sk, sv):
-        return self.layout.constrain_pools(pk, pv, sk, sv)
+    def _constrain_pools(self, pools):
+        return self.layout.constrain_pools(pools)
 
     def _pin_tokens(self, tokens):
         jax = _j()
         return jax.lax.with_sharding_constraint(
             tokens, self.layout.sharding(jax.sharding.PartitionSpec()))
 
-    def _place_migrated(self, leaves, name):
-        """A disagg page-migration scatter runs eagerly, so its outputs
-        carry whatever sharding the eager op picked — re-pin them to the
-        pool layout, or the next donated program would see mismatched
-        input placements (the same trap `ServeLayout.sharding` closes
-        for fresh pools)."""
-        import jax
-
-        spec = self.layout.scale_spec() if name in ("sk", "sv") \
-            else self.layout.pool_spec()
-        s = self.layout.sharding(spec)
-        return tuple(jax.device_put(x, s) for x in leaves)
-
     def _shardcheck_specs(self):
-        """Explicit spec entries for ``(params, *pools)`` so the
+        """Explicit spec entries for ``(params, pools)`` so the
         shardcheck pre-flight judges the REAL layout (SC001 silent
         replication, SC006 per-device HBM) instead of assuming
-        single-chip."""
-        param_specs = self.layout.param_specs(self._dec._params)
-        ps, ss = self.pool_specs()
-        L = len(self._pk)
-        entries = (param_specs, (ps,) * L, (ps,) * L)
-        if self._int8:
-            entries += ((ss,) * L, (ss,) * L)
-        return entries
-
-    def _shardcheck_out_specs(self):
-        """Output-side spec entries matching the builders' return
-        structure ``(pk, pv[, sk, sv], tok)`` — without them the
-        donation audit (SC004) would compare the pinned input pools
-        against unconstrained outputs and cry wolf."""
-        ps, ss = self.pool_specs()
-        L = len(self._pk)
-        if self._int8:
-            return ((ps,) * L, (ps,) * L, (ss,) * L, (ss,) * L, None)
-        return ((ps,) * L, (ps,) * L, None)
-
-    def pool_specs(self):
-        return self.layout.pool_spec(), self.layout.scale_spec()
+        single-chip, and for the builders' ``(pools, tok)`` outputs —
+        without them the donation audit (SC004) would compare the pinned
+        input pools against unconstrained outputs and cry wolf."""
+        pool = self.layout.pool_spec()
+        return ((self.layout.param_specs(self._dec._params), pool),
+                (pool, None))
 
     def shardcheck_report(self, mesh=None, hbm_budget_gb=None, bucket=None):
         if mesh is None:

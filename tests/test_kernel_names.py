@@ -89,11 +89,11 @@ def test_the_roll_is_a_named_program_and_scope():
     params["layers"] = [{n: jnp.zeros(s) for n, s in layer.items()}]
     slots = EvaSlotDecoder(EvaByteDecoder(cfg, params), max_slots=2,
                            page_tokens=4, prefill_chunk=16)
-    pool = (jax.ShapeDtypeStruct((9, 2, 4, 128), jnp.bfloat16),)
+    leaf = (jax.ShapeDtypeStruct((9, 2, 4, 128), jnp.bfloat16),)
     feats = ((jax.ShapeDtypeStruct((2, 128), jnp.float32),) * 2,)
     pages = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)  # noqa: E731
     text = slots._build_roll()._fn.trace(
-        feats, pool, pool, pages(16), pages(4)).lower(
+        feats, {"k": leaf, "v": leaf}, pages(16), pages(4)).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
     assert "jit_mx_eva_roll" in text
     assert text.count("mx_eva_roll/") > 10

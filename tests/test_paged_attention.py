@@ -240,10 +240,10 @@ def test_engine_serves_the_same_tokens_through_the_kernel(net, monkeypatch):
 
 def test_draft_program_takes_the_kernel_and_spec_tokens_hold(net,
                                                              monkeypatch):
-    """`_decode_layer_step` is also each unrolled step of the draft
-    program: with the target as its own draft (everything accepted, the
-    draft pool tracking the committed prefix) the kernel path serves the
-    tokens the XLA path serves."""
+    """The decode step's cache access (`serve.pages.TokenCache`) is also
+    each unrolled step of the draft program's: with the target as its own
+    draft (everything accepted, the draft pool tracking the committed
+    prefix) the kernel path serves the tokens the XLA path serves."""
     from incubator_mxnet_tpu.models.decoding import GPTDecoder
 
     def spec(prompts):

@@ -873,56 +873,120 @@ def test_spec_page_rollback_refcounts(net):
     assert alloc.used_pages == 0                   # cache cleared too
 
 
-def test_per_layer_pool_ledger_decode_cost_flat(net, monkeypatch):
-    """What ROADMAP S3 promises of the decode program, read from its TPU
-    lowering (from shapes, on the CPU: nothing compiles for a chip and
-    nothing runs) at two pool sizes: attention is the paged kernel, no
-    tensor of a gathered view's shape — ``(S, P, H, pt, d)`` out of the
-    gather, ``(S, H, view_tokens, d)`` into the einsums — is left in it
-    whatever the pool's size, and every per-layer pool leaf is still
-    donated. (The CPU's own decode program keeps the XLA expression and
-    its view: its scratch is no measure of the TPU's.)"""
-    import re
-
+def _decode_lowering(slots, platform="tpu"):
+    """The decode program's lowering for `platform`, from shapes (nothing
+    compiles for a chip and nothing runs): one call whatever the family
+    and the page format."""
     import jax
     import jax.numpy as jnp
 
+    slots._ensure_pool()
+    S, P = slots.max_slots, slots.pages_per_slot
+    sds = jax.ShapeDtypeStruct
+    return slots._build_decode().trace(
+        slots._dec._params, slots._pools,
+        sds((S, P), jnp.int32), sds((S,), jnp.int32),
+        sds((S,), jnp.int32), sds((S,), jnp.int32),
+        sds((S,), jnp.bool_),
+        jax.random.PRNGKey(0), sds((S,), jnp.float32),
+        top_k=None, do_sample=False).lower(
+        lowering_platforms=(platform,)).as_text()
+
+
+def _donated_args(text):
+    import re
+
+    args = re.search(r"@main\((.*?)\) ->", text, re.S).group(1)
+    return [a for a in args.split("%arg")
+            if "tf.aliasing_output" in a or "jax.buffer_donor" in a]
+
+
+def _eva_slots(n_pages):
+    import jax.numpy as jnp
+
+    from incubator_mxnet_tpu.models.evabyte import (EvaByteConfig,
+                                                    EvaByteDecoder)
+    from incubator_mxnet_tpu.serve.eva import EvaSlotDecoder
+
+    cfg = EvaByteConfig(num_hidden_layers=2, hidden_size=256,
+                        num_attention_heads=2, intermediate_size=64,
+                        vocab_size=8, num_pred_heads=1, window_size=64,
+                        chunk_size=4, max_position_embeddings=256)
+    top, layer = cfg.leaf_shapes()
+    params = {n: jnp.zeros(s) for n, s in top.items()}
+    params["layers"] = [{n: jnp.zeros(s) for n, s in layer.items()}
+                        for _ in range(2)]
+    return EvaSlotDecoder(EvaByteDecoder(cfg, params, "float32"),
+                          max_slots=3, page_tokens=4, prefill_chunk=16,
+                          n_pages=n_pages)
+
+
+@pytest.mark.parametrize("family", ["gpt", "evabyte"])
+def test_per_layer_pool_ledger_decode_cost_flat(family, net, monkeypatch):
+    """What ROADMAP S3 promises of the decode program of either family,
+    read from its TPU lowering (from shapes, on the CPU) at two pool
+    sizes: attention is the paged kernel, called once a layer, no
+    tensor of a gathered view's shape — ``(S, P, H, pt, d)`` out of the
+    gather, ``(S, H, view rows, d)`` into the einsums — is left in it
+    whatever the pool's size, and every per-layer pool leaf is still
+    donated. (The CPU's own decode program keeps the XLA expression and
+    its view: its scratch is no measure of the TPU's.)"""
     from incubator_mxnet_tpu.ops import _dispatch
 
     monkeypatch.setattr(_dispatch, "use_pallas", lambda: True)
     monkeypatch.setattr(_dispatch, "interpret_default", lambda: False)
-    n_layers, H, d = 2, 4, 16                      # gpt_tiny
+    n_layers = 2
     pools = []
-    for n_pages in (12, 48):
-        e = serve.ServeEngine(net, max_slots=3, max_len=64, max_queue=8,
-                              n_pages=n_pages)
-        try:
+    for n_pages in (40, 160):
+        if family == "gpt":
+            e = serve.ServeEngine(net, max_slots=3, max_len=64, max_queue=8,
+                                  n_pages=n_pages)
             slots = e._sched.slots
-            slots._ensure_pool()
-            S, P, pt = (slots.max_slots, slots.pages_per_slot,
-                        slots.page_tokens)
-            sds = jax.ShapeDtypeStruct
-            text = slots._build_decode().trace(
-                slots._dec._params, slots._pk, slots._pv,
-                sds((S, P), jnp.int32), sds((S,), jnp.int32),
-                sds((S,), jnp.int32), sds((S,), jnp.int32),
-                sds((S,), jnp.bool_),
-                jax.random.PRNGKey(0), sds((S,), jnp.float32),
-                top_k=None, do_sample=False).lower(
-                lowering_platforms=("tpu",)).as_text()
+        else:
+            e, slots = None, _eva_slots(n_pages)
+        try:
+            text = _decode_lowering(slots)
             pools.append(slots.cache_bytes)
-            leaf_shape = slots._pk[0].shape
+            leaf_shape = slots._pools["k"][0].shape
         finally:
-            e.shutdown(drain=False)
+            if e is not None:
+                e.shutdown(drain=False)
+        S, P, pt = slots.max_slots, slots.pages_per_slot, slots.page_tokens
+        _, H, d, _ = slots._dec.kv_geometry()
         # one kernel, lowered once, called once a layer
         assert "mx_paged_decode" in text and "tpu_custom_call" in text
         assert text.count("call @_pallas_paged_decode") == n_layers
-        for view in ((S, P, H, pt, d), (S, H, slots.view_tokens, d)):
+        for view in ((S, P, H, pt, d), (S, H, P * pt, d)):
             assert "x".join(map(str, view)) + "xf32" not in text, view
         # all 2L pool leaves are donated
         leaf = "tensor<" + "x".join(map(str, leaf_shape)) + "xf32>"
-        args = re.search(r"@main\((.*?)\) ->", text, re.S).group(1)
-        donated = [a for a in args.split("%arg") if leaf in a
-                   and ("tf.aliasing_output" in a or "jax.buffer_donor" in a)]
+        donated = [a for a in _donated_args(text) if leaf in a]
         assert len(donated) == 2 * n_layers, len(donated)
     assert pools[1] >= 3.5 * pools[0]              # the pool really grew
+
+
+def test_float_and_int8_engines_expose_one_program_signature(net):
+    """The page format is the cache-access objects' alone: each of the
+    four programs takes the pools as ONE argument, donated leaf for leaf,
+    and reads the same signature whether the pages are float or int8."""
+    import inspect
+
+    import jax
+
+    seen = {}
+    for kv in ("fp", "int8"):
+        e = serve.ServeEngine(net, max_slots=2, max_len=64, max_queue=8,
+                              kv_dtype=kv, spec_k=2, draft=net)
+        try:
+            slots = e._sched.slots
+            seen[kv] = [
+                str(inspect.signature(build()._fn))
+                for build in (slots._build_prefill, slots._build_decode,
+                              slots._build_verify, slots._build_draft)]
+            leaves = jax.tree.leaves(slots._make_pools(slots._dec))
+            assert len(_donated_args(_decode_lowering(slots, "cpu"))) \
+                == len(leaves) == (4 if kv == "fp" else 8)
+        finally:
+            e.shutdown(drain=False)
+    assert seen["fp"] == seen["int8"]
+    assert all(sig.startswith("(params, pools, ") for sig in seen["fp"])

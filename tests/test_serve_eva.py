@@ -305,7 +305,9 @@ def test_what_the_family_is_not_served_with_raises(dec, kw):
 
 @pytest.mark.parametrize("family", [SlotDecoder, ShardedSlotDecoder])
 def test_other_engines_refuse_the_family(dec, family):
-    with pytest.raises(NotImplementedError, match="evabyte"):
+    """They name what they serve and what they were handed, not whom else
+    to ask."""
+    with pytest.raises(TypeError, match="GPTDecoder.*got EvaByteDecoder"):
         family(dec, max_slots=2)
 
 
@@ -331,6 +333,6 @@ def test_bfloat16_pages_are_counted_as_bfloat16():
                            prefill_chunk=8)
     assert slots.page_bytes == 2 * (2 * 4 * 4 * 16 * 2)
     slots._ensure_pool()
-    assert slots._pk[0].dtype == jnp.bfloat16
+    assert slots._pools["k"][0].dtype == jnp.bfloat16
     assert slots.cache_bytes == slots.n_pages * slots.page_bytes
     assert slots.kv_bytes_per_slot == slots.cache_bytes / 2

@@ -1003,7 +1003,7 @@ def test_fl020_tree_is_clean():
 
 def test_fl021_flags_cross_replica_pool_access():
     src = ("def steal(dst, src, pages, payload, prompt):\n"
-           "    k = src.slots._pk\n"
+           "    k = src.slots._pools\n"
            "    payload = src.slots.copy_pages_out(pages)\n"
            "    dst.slots.copy_pages_in(pages, payload)\n"
            "    dst.slots.allocator.alloc(3)\n"
@@ -1030,7 +1030,7 @@ def test_fl021_exempts_choke_point_self_and_reads():
     # an engine touching ITS OWN pool is the normal serving path
     own = ("class SlotDecoder:\n"
            "    def _gather(self, pages):\n"
-           "        k = self.slots._pk\n"
+           "        k = self.slots._pools\n"
            "        self.slots.allocator.decref(pages)\n")
     assert not [f for f in _lint_src(
         own, "incubator_mxnet_tpu/serve/gateway.py") if f.rule == "FL021"]

@@ -478,11 +478,14 @@ def test_slo_latency_burn_math():
     assert r3["compliance"] is None and r3["ok"]
 
 
-def test_slo_throughput_windows():
+def test_slo_throughput_windows(monkeypatch):
     c = registry.counter("t_slo_tokens_total")
     s = slo.tracker().throughput("tput", "t_slo_tokens_total",
                                  min_rate=100.0, target=0.5)
     now = [1000.0]
+    # `_measure` reads the clock itself: the test's, not a host's whose
+    # monotonic clock (its uptime) may still stand under 1000 s
+    monkeypatch.setattr(slo.time, "monotonic", lambda: now[0])
     s.observe_window(now[0])           # prime
     c.inc(500)
     now[0] += 1.0
@@ -491,6 +494,7 @@ def test_slo_throughput_windows():
     c.inc(10)
     now[0] += 1.0
     s.observe_window(now[0])           # 10/s: bad window
+    now[0] += 1.0
     comp, detail = s._measure()        # adds one more (bad) window
     assert detail["windows"] == 3 and detail["good"] == 1
     assert comp == pytest.approx(1 / 3)

@@ -226,7 +226,7 @@ FL020  replica-set choke point (scoped to ``serve/`` module bodies,
 FL021  migration choke point (scoped to ``serve/`` module bodies,
        excluding ``serve/disagg.py`` — the choke point itself):
        cross-replica KV pool access — reading or writing a pool leaf
-       through ``<other>.slots._pk/_pv/_sk/_sv``, calling
+       through ``<other>.slots._pools/_draft_pools``, calling
        ``<other>.slots.copy_pages_out/copy_pages_in``, mutating
        refcounts via ``<other>.slots.allocator.alloc/incref/decref``,
        or filling a prefix cache via
@@ -352,7 +352,7 @@ RULES = {
              "`# noqa: FL020` with a reason",
     "FL021": "serve/ cross-replica pool access outside the "
              "serve/disagg.py migration choke point: touching another "
-             "replica's pool leaves (`.slots._pk/_pv/_sk/_sv`), page "
+             "replica's pool leaves (`.slots._pools/_draft_pools`), page "
              "copies (`.slots.copy_pages_out/copy_pages_in`), allocator "
              "refcounts (`.slots.allocator.alloc/incref/decref`) or "
              "prefix-cache fills (`.slots.prefix_cache.register`) "
@@ -1172,7 +1172,7 @@ def _check_replica_choke_point(tree, path, findings, src_lines):
 # FL021 — migration choke point (serve/ modules, except serve/disagg.py)
 # ---------------------------------------------------------------------------
 
-_MIGRATION_POOL_LEAVES = ("_pk", "_pv", "_sk", "_sv")
+_MIGRATION_POOL_LEAVES = ("_pools", "_draft_pools")
 _MIGRATION_COPY_CALLS = ("copy_pages_out", "copy_pages_in")
 _MIGRATION_REFCOUNT_CALLS = ("alloc", "incref", "decref")
 
