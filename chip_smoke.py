@@ -534,7 +534,7 @@ def sharded_serve_phase(cfg, seed, n_chips):
             for s in x.addressable_shards:
                 per_dev[s.device.id] = per_dev.get(s.device.id, 0) \
                     + s.data.nbytes
-        ffn1 = sh._dec._params["layers"]["ffn1_w"]  # noqa: SLF001
+        ffn1 = sh._dec._params["layers"][0]["ffn1_w"]  # noqa: SLF001
         say(f"KV pool bytes total {total}, per device {per_dev}; pool leaf "
             f"on devices {shard_devices(pools[0])}, ffn1_w "
             f"{ffn1.sharding.spec} on devices {shard_devices(ffn1)} with "

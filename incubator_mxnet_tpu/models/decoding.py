@@ -11,9 +11,9 @@ program:
   max_new_tokens) signature, not one per decoded length;
 - prefill = one causal flash-attention pass over the prompt that also
   writes the prompt's K/V into the cache;
-- decode = `lax.scan` over steps; each step runs a scan-over-layers
-  single-token forward against the cache (O(T) work per token instead
-  of the O(T²) full re-forward) and samples the next token in-graph;
+- decode = `lax.scan` over steps; each step runs a single-token forward,
+  layer by layer, against the cache (O(T) work per token instead of the
+  O(T²) full re-forward) and samples the next token in-graph;
 - sampling (temperature / top-k) uses the framework RNG key so
   `mx.random.seed` reproduces generations.
 
@@ -137,8 +137,9 @@ def _ln(x, g, b, eps=1e-5):
 
 
 def _dense(x, w, b=None):
-    """`npx.fully_connected(flatten=False)`: y = x @ W^T (+ b)."""
-    y = x @ w.T
+    """`npx.fully_connected(flatten=False)` over a matrix the decoder holds
+    ``(in, out)``: y = x @ W (+ b)."""
+    y = x @ w
     return y if b is None else y + b
 
 
@@ -153,6 +154,39 @@ def _split_qkv(h, n_heads):
     k = jnp.transpose(qkv[:, :, 1], (0, 2, 1, 3))
     v = jnp.transpose(qkv[:, :, 2], (0, 2, 1, 3))
     return q, k, v
+
+
+#: the chip's lanes: it keeps a 2-D array row-major where the last axis is a
+#: multiple of this, else whichever way round pads less (`_rows`)
+_LANES = 128
+
+
+def _own(leaves):
+    """`leaves` of a Gluon block as buffers of the caller's own: a matrix
+    ``(out, in)`` comes back as the transposed copy ``(in, out)``, a vector
+    as a copy."""
+    jnp = _j().numpy
+    return {n: a.T if a.ndim == 2 else jnp.copy(a) for n, a in leaves.items()}
+
+
+def _rows(leaves):
+    """The tables ``(n, C)`` (rows are gathered from them; the logits
+    contract over their last axis), their rows padded with zeros to whole
+    lanes. The chip keeps such an array row-major; GPT-2 XL's ``(50257,
+    1600)`` it keeps column-major (less padding), and every step's gather
+    then begins with a re-layout of all 322 MB (`PERF.md` §6, PR 32)."""
+    jnp = _j().numpy
+    return {n: jnp.pad(a, ((0, 0), (0, -a.shape[1] % _LANES)))
+            for n, a in leaves.items()}
+
+
+@functools.cache
+def _stored(form):
+    """`_own` / `_rows` as one jitted call over a dict of leaves (a layer's
+    twelve are one dispatch, not twelve)."""
+    from ..telemetry.compiles import ledgered_jit
+
+    return ledgered_jit(form, family=f"gpt.params.{form.__name__.strip('_')}")
 
 
 class _PromptCache:
@@ -205,9 +239,20 @@ class _StepCache:
 class GPTDecoder:
     """Compiled KV-cache text generation over a (trained) `GPTModel`.
 
-    Parameters are read from the model at construction (zero-copy jax
-    references); the jit cache persists across calls, so repeated
-    generation with the same shapes never recompiles.
+    Parameters are read from the model at construction, and again by
+    `refresh()`, into the form every program reads them in:
+    ``_params["layers"]`` is a tuple of one dict a layer whose leaves are
+    buffers the decoder owns (the block's may be deleted once the decoder
+    is built), float32 as the block holds them, each matrix the transposed
+    copy ``(in, out)`` so that a product is ``x @ W``; ``embed`` and ``pos``
+    (and ``head``, where the logits' matrix is not the tied embedding) are
+    tables ``(n, C)`` with their rows padded to whole lanes (`_rows`): rows
+    are gathered from them and the logits contract over their last axis;
+    the final norm is the block's own buffers. No program slices,
+    transposes or re-lays out a weight. The
+    jit cache persists across calls, so repeated generation with the same
+    shapes never recompiles; `generate` is unrolled over the layers, so
+    its compile grows with the depth (`CHANGES.md`, PR 32).
     """
 
     def __init__(self, model):
@@ -227,37 +272,30 @@ class GPTDecoder:
         return p.data()._data  # noqa: SLF001 — jax value, zero-copy
 
     def _extract_params(self, model):
-        jnp = _j().numpy
-        per_layer = []
-        for blk in model.blocks:
-            per_layer.append({
-                "ln1_g": self._leaf(blk.ln1.gamma),
-                "ln1_b": self._leaf(blk.ln1.beta),
-                "qkv_w": self._leaf(blk.attn.qkv.weight),
-                "qkv_b": self._leaf(blk.attn.qkv.bias),
-                "proj_w": self._leaf(blk.attn.proj.weight),
-                "proj_b": self._leaf(blk.attn.proj.bias),
-                "ln2_g": self._leaf(blk.ln2.gamma),
-                "ln2_b": self._leaf(blk.ln2.beta),
-                "ffn1_w": self._leaf(blk.ffn.ffn1.weight),
-                "ffn1_b": self._leaf(blk.ffn.ffn1.bias),
-                "ffn2_w": self._leaf(blk.ffn.ffn2.weight),
-                "ffn2_b": self._leaf(blk.ffn.ffn2.bias),
-            })
-        # stack per-layer leaves on a leading L axis: scan-over-layers
-        # keeps compile time flat in depth (one traced layer body)
-        stacked = {k: jnp.stack([lp[k] for lp in per_layer])
-                   for k in per_layer[0]}
-        params = {
-            "layers": stacked,
-            "embed": self._leaf(model.word_embed.weight),
-            "pos": self._leaf(model.position_embed),
+        layers = tuple(_stored(_own)({
+            "ln1_g": self._leaf(blk.ln1.gamma),
+            "ln1_b": self._leaf(blk.ln1.beta),
+            "qkv_w": self._leaf(blk.attn.qkv.weight),
+            "qkv_b": self._leaf(blk.attn.qkv.bias),
+            "proj_w": self._leaf(blk.attn.proj.weight),
+            "proj_b": self._leaf(blk.attn.proj.bias),
+            "ln2_g": self._leaf(blk.ln2.gamma),
+            "ln2_b": self._leaf(blk.ln2.beta),
+            "ffn1_w": self._leaf(blk.ffn.ffn1.weight),
+            "ffn1_b": self._leaf(blk.ffn.ffn1.bias),
+            "ffn2_w": self._leaf(blk.ffn.ffn2.weight),
+            "ffn2_b": self._leaf(blk.ffn.ffn2.bias),
+        }) for blk in model.blocks)
+        tables = {"embed": self._leaf(model.word_embed.weight),
+                  "pos": self._leaf(model.position_embed)}
+        if not self._tie:
+            tables["head"] = self._leaf(model.lm_head.weight)
+        return {
+            "layers": layers,
+            **_stored(_rows)(tables),
             "lnf_g": self._leaf(model.ln_f.gamma),
             "lnf_b": self._leaf(model.ln_f.beta),
         }
-        if not self._tie:
-            params["head_w"] = self._leaf(model.lm_head.weight)
-        return params
 
     def _current_ids(self):
         """Identity fingerprint of every live parameter buffer — jax
@@ -268,8 +306,8 @@ class GPTDecoder:
 
     def refresh(self):
         """Re-read parameters from the model if any changed since the
-        last stack (cheap identity walk; the O(model) re-stack only runs
-        after an actual update — serving calls stay zero-copy)."""
+        last read (cheap identity walk; the O(model) copy into the stored
+        form only runs after an actual update)."""
         ids = self._current_ids()
         if ids != self._param_ids:
             self._params = self._extract_params(self._model)
@@ -281,8 +319,8 @@ class GPTDecoder:
         """``(layers, heads, head size, dtype)`` of the K/V rows a cache
         holds for this model."""
         layers = self._params["layers"]
-        return (int(layers["ln1_g"].shape[0]), self._n_heads,
-                self._units // self._n_heads, layers["qkv_w"].dtype)
+        return (len(layers), self._n_heads,
+                self._units // self._n_heads, layers[0]["qkv_w"].dtype)
 
     def embed(self, params, tokens, pos):
         """``tokens`` (N, T) at positions ``pos`` (N, T) or (T,), clamped to
@@ -293,15 +331,13 @@ class GPTDecoder:
         `PERF.md` §6, PR 31)."""
         jnp = _j().numpy
         pos = jnp.clip(pos, 0, params["pos"].shape[0] - 1)
-        e, p = params["embed"][tokens], params["pos"][pos]
-        if tokens.ndim == 1:
-            e, p = e[:, None, :], p[:, None, :]
-        return e + p
+        x = params["embed"][tokens] + params["pos"][pos]
+        x = x[..., :self._units]            # the tables' rows are whole lanes
+        return x[:, None, :] if tokens.ndim == 1 else x
 
     def layer_params(self, params, li):
-        """Layer `li`'s leaves: the ONE place that slices the stacked
-        weights (a scan over ``params["layers"]`` hands the same dict)."""
-        return {n: a[li] for n, a in params["layers"].items()}
+        """Layer `li`'s leaves, as they are stored."""
+        return params["layers"][li]
 
     def layer(self, li, lp, x, pos, cache):  # noqa: ARG002
         """One pre-norm block over ``x`` (N, T, C): the family's ONE layer
@@ -326,11 +362,16 @@ class GPTDecoder:
         return x + ffn
 
     def next_logits(self, params, x):
-        """Next-token logits of residual rows ``x`` (..., C)."""
+        """Next-token logits of residual rows ``x`` (..., C): the contraction
+        over the last axis of the table as it is stored, ``(V, C)`` with its
+        rows padded to whole lanes (``x`` is padded to match; the tied
+        embedding, else ``head``). Row-major is how the chip keeps that
+        table, so neither this nor `embed`'s gather re-lays it out."""
+        jnp = _j().numpy
+        w = params["embed" if self._tie else "head"]
         x = _ln(x, params["lnf_g"], params["lnf_b"])
-        if self._tie:
-            return x @ params["embed"].T
-        return x @ params["head_w"].T
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, w.shape[1] - x.shape[-1])])
+        return jnp.einsum("...c,vc->...v", x, w)
 
     def _sample(self, logits, key, temperature, top_k, do_sample):
         jax = _j()
@@ -362,20 +403,20 @@ class GPTDecoder:
             # `arange <= pos` mask ever admits it, so the junk is never
             # attended.
             # Both halves run `layer`, the block the serving programs run,
-            # scanned over the stacked layers (one traced body whatever the
-            # depth): a dense cache is built in the scan body from that
-            # iteration's slice, so `li` is no index here.
+            # a layer at a time over the per-layer leaves as those programs
+            # do (one traced body a layer: the compile grows with the depth).
+            # Each layer gets a dense cache of its own, so `li` is no index.
             B = tokens.shape[1]
+            layers = params["layers"]
 
             # ---- prefill: full causal pass over the padded prompt ----
             x = self.embed(params, tokens, jnp.arange(B))
-
-            def pre_layer(x, lp):
+            ck, cv = [], []
+            for lp in layers:
                 cache = _PromptCache(cache_len)
-                return self.layer(None, lp, x, None, cache), \
-                    (cache.k, cache.v)
-
-            x, (ck, cv) = lax.scan(pre_layer, x, params["layers"])
+                x = self.layer(None, lp, x, None, cache)
+                ck.append(cache.k)
+                cv.append(cache.v)
             # last REAL token (causal: its row never saw the padding)
             logits0 = self.next_logits(
                 params, lax.dynamic_slice_in_dim(x, t0 - 1, 1,
@@ -386,19 +427,14 @@ class GPTDecoder:
                 ck, cv, pos, tok = carry
 
                 x = self.embed(params, tok[:, None], pos[None])
-
-                def dec_layer(x, layer):
-                    lp, ck_l, cv_l = layer
-                    cache = _StepCache(ck_l, cv_l, pos)
-                    return self.layer(None, lp, x, None, cache), \
-                        (cache.k, cache.v)
-
-                x, (ck, cv) = lax.scan(dec_layer, x,
-                                       (params["layers"], ck, cv))
+                caches = [_StepCache(k, v, pos) for k, v in zip(ck, cv)]
+                for lp, cache in zip(layers, caches):
+                    x = self.layer(None, lp, x, None, cache)
                 logits = self.next_logits(params, x[:, 0])
                 nxt = self._sample(logits, step_key, temperature, top_k,
                                    do_sample)
-                return (ck, cv, pos + 1, nxt), tok
+                return (tuple(c.k for c in caches),
+                        tuple(c.v for c in caches), pos + 1, nxt), tok
 
             first = self._sample(logits0, key, temperature, top_k,
                                  do_sample)
@@ -407,7 +443,8 @@ class GPTDecoder:
             keys = jax.random.split(jax.random.fold_in(key, 1),
                                     max_new)[1:]
             (_, _, _, last), toks = lax.scan(
-                step, (ck, cv, t0.astype(jnp.int32), first), keys)
+                step, (tuple(ck), tuple(cv), t0.astype(jnp.int32), first),
+                keys)
             # toks holds the CARRIED token per step; append the final
             # sample to complete max_new outputs
             out = jnp.concatenate(
@@ -422,7 +459,7 @@ class GPTDecoder:
                                              "do_sample", "cache_len"))
 
     def _auto_refresh(self):
-        """Re-stack parameters if the model was updated since the last
+        """Re-read parameters if the model was updated since the last
         read. `refresh()` after a parameter update is easy to forget, so
         `generate` calls this on every entry (cheap identity walk): stale
         params are re-read automatically, with a one-time warning so the
@@ -434,9 +471,8 @@ class GPTDecoder:
                 _LOG.warning(
                     "GPTDecoder: model parameters changed since the last "
                     "refresh(); auto-refreshing. Call refresh() after "
-                    "parameter updates to make the re-stack explicit.")
-            self._params = self._extract_params(self._model)
-            self._param_ids = ids
+                    "parameter updates to make the re-read explicit.")
+            self.refresh()
 
     def generate(self, tokens, max_new_tokens, temperature=1.0, top_k=None,
                  do_sample=False, seed=None):

@@ -827,7 +827,10 @@ class SlotDecoder:
         Python-unrolled over layers: each iteration reads/writes ITS OWN
         donated pool leaf, so XLA's donation map aliases every leaf in place
         (a scan over a stacked pool re-stacks the whole pool per call — the
-        O(L x n_pages) rewrite the per-layer pools exist to remove)."""
+        O(L x n_pages) rewrite the per-layer pools exist to remove), and
+        takes ITS OWN parameter leaves as they are stored (a slice of stacked
+        weights is a copy the chip re-lays out every step: `PERF.md` §6,
+        PR 32)."""
         x = dec.embed(params, tokens, pos)
         for li in range(dec.kv_geometry()[0]):
             x = dec.layer(li, dec.layer_params(params, li), x, pos, cache)

@@ -41,7 +41,9 @@ Layout (the `ServeLayout` defaults, after SNIPPETS.md [2] fmengine
   axis rides the complementary dim for pod layouts;
 - embeddings / positional tables / norms / page tables replicated —
   explicitly (``P()``), so shardcheck's SC001 "silently replicated
-  ≥1 MiB leaf" rule stays meaningful for everything else.
+  ≥1 MiB leaf" rule stays meaningful for everything else (a layer's
+  leaves are stored one dict a layer, each matrix ``(in, out)``:
+  `models.decoding.GPTDecoder`).
 
 Every leaf MUST match a rule: an unmatched leaf raises instead of
 falling back to replication (lint FL017 enforces the same discipline
@@ -120,7 +122,7 @@ def serve_mesh(spec=None, devices=None):
 
 
 def _path_str(path):
-    """'layers/qkv_w'-style rule key for one pytree leaf path."""
+    """'layers/0/qkv_w'-style rule key for one pytree leaf path."""
     parts = []
     for p in path:
         for attr in ("key", "name", "idx"):
@@ -165,10 +167,10 @@ class ServeLayout:
     def _default_rules(self):
         P = _j().sharding.PartitionSpec
         tp, fs = self.tp_axis, self.fsdp_axis
-        # Weights are stored (L, out, in) and applied as ``y = x @ w.T``
-        # (`models.decoding._dense`), so "row-parallel" = tp on the LAST
-        # dim (input features) and "column-parallel" = tp on the middle
-        # dim (output features).
+        # Weights are stored a layer at a time, (in, out), and applied as
+        # ``y = x @ w`` (`models.decoding._dense`), so "row-parallel" = tp
+        # on the FIRST dim (input features) and "column-parallel" = tp on
+        # the last dim (output features).
         return (
             # attention: the fused qkv output axis is [q|k|v]-contiguous
             # and `_split_qkv` reshapes it to (3, H, d) — a contiguous
@@ -179,25 +181,25 @@ class ServeLayout:
             # row-parallel too: its input is the attention context,
             # which lands head-sharded (= feature-sharded once
             # flattened) straight out of the H-sharded KV pools.
-            (r"layers/qkv_w$", P(None, fs, tp)),
-            (r"layers/qkv_b$", P(None)),
-            (r"layers/proj_w$", P(None, fs, tp)),
-            (r"layers/proj_b$", P(None)),
+            (r"^layers/\d+/qkv_w$", P(tp, fs)),
+            (r"^layers/\d+/qkv_b$", P()),
+            (r"^layers/\d+/proj_w$", P(tp, fs)),
+            (r"^layers/\d+/proj_b$", P()),
             # MLP: the classic Megatron pair — ffn1 column-parallel
             # (output features on tp, bias sharded along), gelu local,
             # ffn2 row-parallel (all-reduce back to replicated)
-            (r"layers/ffn1_w$", P(None, tp, fs)),
-            (r"layers/ffn1_b$", P(None, tp)),
-            (r"layers/ffn2_w$", P(None, fs, tp)),
-            (r"layers/ffn2_b$", P(None)),
+            (r"^layers/\d+/ffn1_w$", P(fs, tp)),
+            (r"^layers/\d+/ffn1_b$", P(tp)),
+            (r"^layers/\d+/ffn2_w$", P(tp, fs)),
+            (r"^layers/\d+/ffn2_b$", P()),
             # small per-layer norm vectors: replicated, explicitly
-            (r"layers/ln[0-9]+_[gb]$", P(None)),
+            (r"^layers/\d+/ln[0-9]+_[gb]$", P()),
             # embeddings / positional / final norm / untied head:
             # replicated (page tables ride along as plain host arrays)
             (r"^embed$", P()),
             (r"^pos$", P()),
             (r"^lnf_[gb]$", P()),
-            (r"^head_w$", P()),
+            (r"^head$", P()),
         )
 
     def pool_spec(self):
@@ -309,12 +311,12 @@ class ShardedSlotDecoder(SlotDecoder):
             raise ValueError(
                 f"ShardedSlotDecoder: n_heads={H} not divisible by "
                 f"tp={tp} — the K/V pools shard on the head axis")
-        layers = self._dec._params["layers"]
-        # row-parallel matmuls shard input features (last dim of the
-        # (L, out, in) weight); column-parallel ffn1 shards its output
-        for name, dim in (("qkv_w", -1), ("proj_w", -1),
-                          ("ffn1_w", 1), ("ffn2_w", -1)):
-            size = int(layers[name].shape[dim])
+        layer = self._dec._params["layers"][0]
+        # row-parallel matmuls shard input features (first dim of the
+        # (in, out) weight); column-parallel ffn1 shards its output
+        for name, dim in (("qkv_w", 0), ("proj_w", 0),
+                          ("ffn1_w", 1), ("ffn2_w", 0)):
+            size = int(layer[name].shape[dim])
             if size % tp:
                 raise ValueError(
                     f"ShardedSlotDecoder: {name} sharded dim {size} "
@@ -323,8 +325,9 @@ class ShardedSlotDecoder(SlotDecoder):
     def _place_params(self):
         """(Re-)place decoder params onto the mesh iff the source
         block's weights changed since the last placement — the
-        hot-swap path: `GPTDecoder._auto_refresh` re-reads host-side
-        refs, then this pins them to the layout. Replacing
+        hot-swap path: `GPTDecoder._auto_refresh` makes the per-layer
+        leaves anew (while the old ones live), then this pins them to
+        the layout. Replacing
         ``dec._params`` does not touch the model's own buffers, so the
         id fingerprint stays stable until the next real swap."""
         dec = self._dec

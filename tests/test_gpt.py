@@ -85,8 +85,7 @@ def test_generate_greedy_extends(net):
     assert int(out3.asnumpy().max()) < 97
 
 
-@pytest.fixture(scope="module")
-def spicy_net():
+def _fresh_spicy():
     """Random-weight net with non-degenerate logits (scaled init breaks
     the argmax collapse of a freshly initialized model, so greedy parity
     actually exercises token-dependent paths)."""
@@ -99,6 +98,11 @@ def spicy_net():
             p.set_data(np.array(
                 r.normal(0, 0.35, p.shape).astype("float32")))
     return m
+
+
+@pytest.fixture(scope="module")
+def spicy_net():
+    return _fresh_spicy()
 
 
 def test_kv_cache_greedy_matches_full_forward(spicy_net):
@@ -230,3 +234,86 @@ def test_kv_cache_sees_updated_params(spicy_net):
         assert not (before == after).all()
     finally:
         p.set_data(np.array(old))
+
+
+def test_decoder_holds_each_layer_in_the_form_the_matmuls_read(spicy_net):
+    """The stored form (PR 32): a tuple of one dict a layer, every leaf
+    float32 as the block holds it (no precision traded), each matrix the
+    transposed copy ``(in, out)`` of the block's ``(out, in)``, and the two
+    tables with their rows padded to whole lanes."""
+    import jax
+
+    from incubator_mxnet_tpu.models.decoding import GPTDecoder
+
+    p = GPTDecoder(spicy_net)._params
+    assert all(leaf.dtype == "float32" for leaf in jax.tree.leaves(p))
+    assert isinstance(p["layers"], tuple)
+    assert len(p["layers"]) == len(spicy_net.blocks) == 2
+    C, F, V = 64, 128, 97
+    shapes = {"qkv_w": (C, 3 * C), "proj_w": (C, C), "ffn1_w": (C, F),
+              "ffn2_w": (F, C), "qkv_b": (3 * C,), "proj_b": (C,),
+              "ffn1_b": (F,), "ffn2_b": (C,), "ln1_g": (C,), "ln1_b": (C,),
+              "ln2_g": (C,), "ln2_b": (C,)}
+    for blk, lp in zip(spicy_net.blocks, p["layers"]):
+        assert {n: a.shape for n, a in lp.items()} == shapes
+        for name, w in (("qkv_w", blk.attn.qkv.weight),
+                        ("proj_w", blk.attn.proj.weight),
+                        ("ffn1_w", blk.ffn.ffn1.weight),
+                        ("ffn2_w", blk.ffn.ffn2.weight)):
+            onp.testing.assert_array_equal(onp.asarray(lp[name]),
+                                           w.data().asnumpy().T)
+        onp.testing.assert_array_equal(onp.asarray(lp["qkv_b"]),
+                                       blk.attn.qkv.bias.data().asnumpy())
+    # the tied embedding is ONE table: rows gathered from it, the logits
+    # contracted over its last axis; no second leaf
+    embed = spicy_net.word_embed.weight.data().asnumpy()
+    assert set(p) == {"layers", "embed", "pos", "lnf_g", "lnf_b"}
+    assert p["embed"].shape == (V, 128) and p["pos"].shape == (64, 128)
+    onp.testing.assert_array_equal(onp.asarray(p["embed"])[:, :C], embed)
+    assert not onp.asarray(p["embed"])[:, C:].any()
+    assert not onp.asarray(p["pos"])[:, C:].any()
+
+
+def test_decoder_owns_its_layers_the_blocks_arrays_may_be_deleted():
+    """`chipbench/runners/serve.py` frees every ``blocks.*`` array of the
+    Gluon block once the engine is built (two float32 copies of GPT-2 XL and
+    the KV pool do not fit one chip): every per-layer leaf, the biases and
+    the LayerNorm gains too, has to be a buffer of the decoder's own."""
+    from incubator_mxnet_tpu.models.decoding import GPTDecoder
+
+    net = _fresh_spicy()
+    x = _tok(2, 9, seed=4)
+    dec = GPTDecoder(net)
+    want = dec.generate(x, 7).asnumpy()
+    n = 0
+    for name, p in net.collect_params().items():
+        if name.startswith("blocks."):
+            p.data()._data.delete()
+            n += 1
+    assert n == 2 * 12
+    onp.testing.assert_array_equal(dec.generate(x, 7).asnumpy(), want)
+
+
+def test_untied_head_is_a_table_of_its_own():
+    """``tie_weights=False``: the logits contract over ``head``, a table
+    stored like `embed` (``lm_head.weight`` ``(V, C)``, rows padded to
+    whole lanes); cached greedy decode gives the full forward's tokens."""
+    from incubator_mxnet_tpu.models.decoding import GPTDecoder
+    from incubator_mxnet_tpu.models.gpt import GPTModel
+
+    mx.random.seed(5)
+    net = GPTModel(97, 64, 128, 2, 4, 64, dropout=0.0, tie_weights=False)
+    net.initialize()
+    r = onp.random.RandomState(7)
+    for _name, p in net.collect_params().items():
+        if p.shape and len(p.shape) >= 2:
+            p.set_data(np.array(
+                r.normal(0, 0.35, p.shape).astype("float32")))
+    p = GPTDecoder(net)._params
+    assert p["head"].shape == (97, 128)
+    onp.testing.assert_array_equal(onp.asarray(p["head"])[:, :64],
+                                   net.lm_head.weight.data().asnumpy())
+    x = _tok(2, 7, seed=3)
+    onp.testing.assert_array_equal(
+        net.generate(x, 6).asnumpy(),
+        net.generate(x, 6, use_cache=False).asnumpy())

@@ -354,8 +354,7 @@ def test_serve_step_fault_seam():
 # real engine over a tiny 2-layer GPT (compiled paged programs)
 # ---------------------------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def net():
+def _spicy_net():
     """Spicy random weights (non-degenerate logits) so greedy parity
     exercises token-dependent paths — same recipe as test_gpt.py."""
     mx.random.seed(11)
@@ -367,6 +366,11 @@ def net():
             p.set_data(np.array(
                 r.normal(0, 0.35, p.shape).astype("float32")))
     return m
+
+
+@pytest.fixture(scope="module")
+def net():
+    return _spicy_net()
 
 
 @pytest.fixture(scope="module")
@@ -963,6 +967,69 @@ def test_per_layer_pool_ledger_decode_cost_flat(family, net, monkeypatch):
         donated = [a for a in _donated_args(text) if leaf in a]
         assert len(donated) == 2 * n_layers, len(donated)
     assert pools[1] >= 3.5 * pools[0]              # the pool really grew
+
+
+def test_engine_serves_after_the_blocks_arrays_are_deleted():
+    """What `chipbench/runners/serve.py` does at GPT-2 XL's size, where two
+    float32 copies and the KV pool do not fit one chip: build the engine,
+    `.delete()` every ``blocks.*`` array of the Gluon block, serve. Every
+    per-layer leaf of the decoder is a buffer of its own (PR 32), so the
+    tokens are those of `generate` on the untouched block."""
+    m = _spicy_net()                 # its own: the arrays are deleted
+    prompts, budgets = _mixed_requests(4, seed=5)
+    want = _references(GPTDecoder(m), prompts, budgets)
+    e = serve.ServeEngine(m, max_slots=2, max_len=64, max_queue=8)
+    try:
+        for name, p in m.collect_params().items():
+            if name.startswith("blocks."):
+                p.data()._data.delete()  # noqa: SLF001
+        handles = [e.submit(p, b) for p, b in zip(prompts, budgets)]
+        while e.step():
+            pass
+        assert [h.result() for h in handles] == want
+    finally:
+        e.shutdown(drain=False)
+
+
+def test_decode_program_reads_the_weights_as_they_are_stored(net):
+    """The decode program lowered for the TPU (from shapes) neither
+    transposes nor slices a parameter: every matrix goes into its
+    ``dot_general`` as the argument it came in as (a weight sliced out of a
+    stack or multiplied transposed is a copy the chip makes, and may
+    re-lay out, every step: `PERF.md` §6, PR 32)."""
+    import re
+
+    import jax
+
+    e = serve.ServeEngine(net, max_slots=3, max_len=64, max_queue=8)
+    try:
+        slots = e._sched.slots
+        text = _decode_lowering(slots)
+        params = slots._dec._params
+    finally:
+        e.shutdown(drain=False)
+    weights = {"tensor<" + "x".join(map(str, a.shape)) + "xf32>"
+               for a in jax.tree.leaves(params) if a.ndim >= 2}
+    assert len(weights) >= 5          # four matrices a layer, two tables
+    main = text[text.index("func.func public @main"):]
+    main = main[:main.index("\n  }\n") + 1]
+    args = dict(re.findall(r"(%arg\d+): (tensor<[^>]*>)", main))
+    ours = {a for a, t in args.items() if t in weights}
+    assert len(ours) == 2 * 4 + 2
+    uses = []
+    for line in main.splitlines():
+        m = re.search(r'= "?stablehlo\.(\w+)"?[ (](.*)', line)
+        if not m:
+            continue
+        op, rest = m.groups()
+        used = set(re.findall(r"%arg\d+", rest)) & ours
+        if not used:
+            continue
+        # a matrix is multiplied, a table gathered from: nothing else
+        assert op in ("dot_general", "gather"), line.strip()[:200]
+        uses.append(op)
+    # the tied embedding twice: the rows' gather and the logits' product
+    assert sorted(uses) == ["dot_general"] * (2 * 4 + 1) + ["gather"] * 2
 
 
 def test_float_and_int8_engines_expose_one_program_signature(net):

@@ -93,16 +93,26 @@ def test_every_param_leaf_matches_exactly_one_rule(net):
 def test_unmatched_leaf_raises_no_replicated_fallback():
     layout = ServeLayout(_mesh(1))
     with pytest.raises(ValueError, match="no partition rule"):
-        layout.spec_for("layers/mystery_w")
+        layout.spec_for("layers/0/mystery_w")
+    # the rules are anchored: a path without a layer's index names no leaf
+    with pytest.raises(ValueError, match="no partition rule"):
+        layout.spec_for("layers/qkv_w")
+    with pytest.raises(ValueError, match="no partition rule"):
+        layout.spec_for("head_w")
 
 
 def test_heavy_leaves_and_pools_land_on_tp():
     layout = ServeLayout(_mesh(2))
-    for name in ("layers/qkv_w", "layers/proj_w", "layers/ffn1_w",
-                 "layers/ffn2_w"):
-        assert "tp" in tuple(layout.spec_for(name)), name
-    # norms / embeddings are replicated EXPLICITLY (not a fallback)
-    for name in ("layers/ln1_g", "embed", "pos", "lnf_g"):
+    # matrices are stored (in, out): row-parallel = tp on the FIRST axis,
+    # column-parallel ffn1 = tp on the last, its bias along with it
+    for name in ("layers/0/qkv_w", "layers/11/proj_w", "layers/47/ffn2_w"):
+        assert tuple(layout.spec_for(name))[0] == "tp", name
+    assert tuple(layout.spec_for("layers/3/ffn1_w"))[-1] == "tp"
+    assert tuple(layout.spec_for("layers/3/ffn1_b")) == ("tp",)
+    # norms / embeddings / an untied head are replicated EXPLICITLY (not a
+    # fallback)
+    for name in ("layers/0/ln1_g", "layers/0/qkv_b", "embed", "pos",
+                 "lnf_g", "head"):
         assert "tp" not in tuple(layout.spec_for(name)), name
     # pools shard the head axis; scale planes follow
     # one spec for every leaf of the pools pytree, pages and scale planes
@@ -232,9 +242,18 @@ def test_one_device_mesh_greedy_parity(net):
     assert got == want      # bit-identical greedy stream
 
 
+# SC005 takes an all-gather for a sharded leaf gathered whole when their BYTES
+# agree. A layer's leaves are arrays of their own since PR 32, and at
+# `gpt_tiny`'s widths they are as small as the activations the tp pair
+# gathers before ffn1: 2 slots x 64 features is `ffn1_b`'s 512 bytes, a
+# 64-token chunk x 64 features `proj_w`'s 16 KiB. Three slots and a 32-token
+# chunk agree with no leaf; the engines compared take the same settings.
+_CLEAN = dict(max_slots=3, max_len=64, n_pages=24, prefill_chunk=32)
+
+
 def test_tp_mesh_parity_two_families_and_clean_shardcheck(net):
     prompts = [_prompt(7, seed=1), _prompt(11, seed=2)]
-    base = serve.SlotDecoder(net, max_slots=2, max_len=64, n_pages=24)
+    base = serve.SlotDecoder(net, **_CLEAN)
     try:
         want = _serve_tokens(base, prompts)
     finally:
@@ -245,8 +264,7 @@ def test_tp_mesh_parity_two_families_and_clean_shardcheck(net):
     compiles.enable()
     try:
         compiles.reset()
-        sh = ShardedSlotDecoder(net, mesh=_mesh(2), max_slots=2,
-                                max_len=64, n_pages=24)
+        sh = ShardedSlotDecoder(net, mesh=_mesh(2), **_CLEAN)
         try:
             got = _serve_tokens(sh, prompts)
             assert got == want
@@ -296,8 +314,7 @@ def test_tp_mesh_step_in_flight_one_decode_program(net, kv_dtype):
 
 
 def test_tp_mesh_int8_kv_runs_with_clean_shardcheck(net):
-    sh = ShardedSlotDecoder(net, mesh=_mesh(2), max_slots=2, max_len=64,
-                            n_pages=24, kv_dtype="int8")
+    sh = ShardedSlotDecoder(net, mesh=_mesh(2), kv_dtype="int8", **_CLEAN)
     try:
         toks = _serve_tokens(sh, [_prompt(7, seed=1)])
         assert toks[0] and len(toks[0]) <= 10
