@@ -17,6 +17,14 @@ weights made from ``--seed`` and nothing downloaded:
   boundary and one whose decode does (both kinds of page, the roll program,
   the paged kernel at head size 128), each token checked against the plain
   reference (`chipbench/reference/evabyte.py`).
+* **pangu** — the openPangu-Ultra-MoE family (`serve/mla.py`) at its
+  published widths (7,680 wide, 128 heads, latent row 512 + 64, experts 2,048
+  wide, top-8 of 256 of which 16 are held) and one dense and one expert layer
+  deep, bfloat16: three requests prefilled in chunks and decoded together
+  (the latent pages, the absorbed kernel ``mx_mla_decode`` at 128 heads, the
+  up-projected chunk, the grouped expert product ``mx_moe_experts``), each
+  token checked against the plain reference (`chipbench/reference/pangu.py`),
+  and the expert layer's counts against the routing the reference makes.
 * **train** — ``models.bert.bert_base()`` through
   ``parallel.sharded.DataParallel(...).step`` under ``amp.init("bfloat16")``,
   dropout 0.1, at batch 32 x seq 512 and batch 64 x seq 128, 5 steps each on a
@@ -67,6 +75,31 @@ EVA_SIZES = {   # sizes under EvaByte's own keys; (prompt, new tokens) pairs
                         init_std=0.2, max_position_embeddings=96),
                dtype="float32", page_tokens=4, prefill_chunk=8,
                requests=((40, 4), (28, 12)), gap_limit=1e-3),
+}
+_PANGU = dict(num_hidden_layers=2, first_k_dense_replace=1,
+              n_shared_experts=1, routed_scaling_factor=2.5,
+              rope_theta=25600000, rms_norm_eps=1e-5, init_std=0.02)
+PANGU_SIZES = {  # sizes under the release's own keys
+    False: dict(cfg=dict(_PANGU, hidden_size=7680, intermediate_size=18432,
+                         moe_intermediate_size=2048, num_attention_heads=128,
+                         q_lora_rank=1536, kv_lora_rank=512,
+                         qk_nope_head_dim=128, qk_rope_head_dim=64,
+                         v_head_dim=128, n_routed_experts=256,
+                         num_experts_per_tok=8, vocab_size=19200,
+                         experts_held=[0, 16], max_position_embeddings=2048),
+                dtype="bfloat16", page_tokens=16, prefill_chunk=512,
+                max_slots=8, requests=((1100, 24), (300, 24), (700, 16)),
+                gap_limit=0.5),
+    True: dict(cfg=dict(_PANGU, hidden_size=64, intermediate_size=96,
+                        moe_intermediate_size=32, num_attention_heads=4,
+                        q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+                        qk_rope_head_dim=8, v_head_dim=16,
+                        n_routed_experts=16, num_experts_per_tok=4,
+                        vocab_size=50, experts_held=[4, 6],
+                        max_position_embeddings=96),
+               dtype="float32", page_tokens=4, prefill_chunk=16,
+               max_slots=3, requests=((40, 6), (21, 12), (9, 8)),
+               gap_limit=1e-3),
 }
 TRAIN_SIZES = {
     False: dict(model="bert_base", vocab=30522, steps=5,
@@ -342,6 +375,22 @@ def serve_phase(cfg, seed, watch):
 # one chip: train
 # ---------------------------------------------------------------------------
 
+def gaps_to_reference(ref, cfg, seed, prompts, outs):
+    """``(served tokens, by how much each one's logit lies below the plain
+    reference's best)``, the reference run teacher-forced over the prompts
+    and what was served."""
+    tokens = onp.zeros((len(prompts), cfg["max_position_embeddings"]),
+                       onp.int32)
+    rows, served = [], []
+    for b, (p, out) in enumerate(zip(prompts, outs)):
+        seq = onp.concatenate([p, onp.asarray(out, onp.int32)])
+        tokens[b, :seq.size - 1] = seq[:-1]
+        rows += [(b, p.size - 1 + j) for j in range(len(out))]
+        served += out
+    logits = ref.logits_at(cfg, seed, tokens, rows)
+    return served, logits.max(-1) - logits[onp.arange(len(served)), served]
+
+
 def eva_phase(sizes, seed):
     """Two EvaByte requests through `ServeEngine`, one rolling in prefill and
     one in decode; every served token's reference logit within `gap_limit`
@@ -373,22 +422,64 @@ def eva_phase(sizes, seed):
         raise AssertionError(f"expected one roll a request, counted "
                              f"{rolls.value - before}")
     launch_modes(tracing.step_records(t0), "eva")
-    tokens = onp.zeros((len(prompts), cfg["max_position_embeddings"]),
-                       onp.int32)
-    rows, served = [], []
-    for b, (p, out) in enumerate(zip(prompts, outs)):
-        seq = onp.concatenate([p, onp.asarray(out, onp.int32)])
-        tokens[b, :seq.size - 1] = seq[:-1]
-        rows += [(b, p.size - 1 + j) for j in range(len(out))]
-        served += out
-    logits = ref.logits_at(cfg, seed, tokens, rows)
-    gap = logits.max(-1) - logits[onp.arange(len(served)), served]
+    served, gap = gaps_to_reference(ref, cfg, seed, prompts, outs)
     say(f"eva: {len(served)} tokens of {len(prompts)} requests across "
         f"{rolls.value - before} rolls in {time.perf_counter() - t0:.1f} s; "
         f"widest gap to the reference's best logit {gap.max():.4f} "
         f"(limit {sizes['gap_limit']}), kernel branches {kernel_branches({})}")
     if not gap.max() <= sizes["gap_limit"]:
         raise AssertionError(f"eva: a served token lies {gap.max():.4f} "
+                             "below the reference's best")
+
+
+def pangu_phase(sizes, seed):
+    """Three openPangu-Ultra-MoE requests through `ServeEngine`, prefilled
+    in chunks and decoded together; every served token's reference logit
+    within `gap_limit` of the reference's best, and the expert layer's own
+    count of held pairs some, not all, of what was routed."""
+    import incubator_mxnet_tpu as mx
+    from chipbench.reference import pangu as ref
+    from chipbench.runners.serve_pangu import build_decoder
+    from incubator_mxnet_tpu.telemetry import registry, tracing
+
+    cfg = sizes["cfg"]
+    t0 = time.perf_counter()
+    branches0 = kernel_branches({})
+    dec = build_decoder(cfg, seed, ref, sizes["dtype"])
+    eng = mx.serve.ServeEngine(
+        dec, max_slots=sizes["max_slots"],
+        max_len=cfg["max_position_embeddings"],
+        page_tokens=sizes["page_tokens"], prefill_chunk=sizes["prefill_chunk"],
+        prefix_reuse=False)
+    rng = onp.random.default_rng([seed, 0x9A6])
+    prompts = [rng.integers(0, cfg["vocab_size"], n).astype(onp.int32)
+               for n, _ in sizes["requests"]]
+    pairs = {k: registry.counter("mx_serve_moe_pairs_total",
+                                 labels={"kind": k}) for k in ("held", "routed")}
+    before = {k: c.value for k, c in pairs.items()}
+    eng.start()
+    try:
+        handles = [eng.submit(p, new)
+                   for p, (_, new) in zip(prompts, sizes["requests"])]
+        outs = [list(eng.iter_tokens(h, timeout=600.0)) for h in handles]
+    finally:
+        eng.shutdown(drain=False)
+    held, routed = (pairs[k].value - before[k] for k in ("held", "routed"))
+    launch_modes(tracing.step_records(t0), "pangu")
+    eng = dec = None            # the reference runs on an emptied chip
+    served, gap = gaps_to_reference(ref, cfg, seed, prompts, outs)
+    branches = {op: impls for op, impls in kernel_branches({}).items()
+                if impls != branches0.get(op)}
+    say(f"pangu: {len(served)} tokens of {len(prompts)} requests in "
+        f"{time.perf_counter() - t0:.1f} s; held pairs {held} of {routed} "
+        f"routed; widest gap to the reference's best logit {gap.max():.4f} "
+        f"mean {gap.mean():.5f} (limit {sizes['gap_limit']}), kernel "
+        f"branches {branches}")
+    if not 0 < held < routed:
+        raise AssertionError(f"pangu: {held} held pairs of {routed} routed: "
+                             "the held share of the routing is all or nothing")
+    if not gap.max() <= sizes["gap_limit"]:
+        raise AssertionError(f"pangu: a served token lies {gap.max():.4f} "
                              "below the reference's best")
 
 
@@ -631,6 +722,7 @@ def main(argv=None):
     else:
         serve_phase(SERVE_SIZES[args.tiny], args.seed, watch)
         eva_phase(EVA_SIZES[args.tiny], args.seed)
+        pangu_phase(PANGU_SIZES[args.tiny], args.seed)
         # the toy widths are below a lane (128): no kernel site admits them
         train_phase(TRAIN_SIZES[args.tiny], args.seed, watch,
                     expect_kernels=not args.tiny)

@@ -9,8 +9,12 @@
 - `evabyte`: byte-level decoder (rotary, gated, bias-free) whose attention
   keeps one exact window and chunk summaries of everything before it; pure
   jax, served by `mx.serve` (`serve/eva.py`).
+- `pangu`: openPangu-Ultra-MoE's block — latent attention (MLA), sandwich
+  norms, a dropless sigmoid-routed expert layer that may hold a share of the
+  experts; pure jax, served by `mx.serve` (`serve/mla.py`).
 """
 from .bert import BERTClassifier, BERTEncoder, BERTModel, TransformerEncoderCell  # noqa: F401
 from . import evabyte  # noqa: F401
 from . import gpt  # noqa: F401
+from . import pangu  # noqa: F401
 from . import sharded_bert  # noqa: F401

@@ -42,6 +42,22 @@ failure or a knob, and counted as
   kernel — and int8 pools, which dequantise by a per-(page, head) scale):
   the expression the engine has always had — gather every slot's whole
   ``P * page_tokens`` view through the table, mask, softmax, two einsums.
+
+**Latent pages** (``mla_decode_attention``, the MLA family: `serve/mla.py`). A
+page is ONE leaf of ``(page_tokens, W)`` rows ``[c_kv (rank) ; k_rope ;
+zeros]`` shared by every head, ``W`` the row's ``rank + rope`` values rounded
+up to whole 128-lane tiles (`latent_store_width`: 576 -> 640; a bfloat16 row
+of 576 would be padded to 640 lanes by the chip's tiling anyway, so a row
+takes 1,280 B either way, and stored so a page is one contiguous block and a
+row's score is one product). The queries come *absorbed*, ``(S, H, W)``, and
+what goes back is the weighted sum of ``c_kv``, ``(S, H, rank)``. The kernel
+``mx_mla_decode`` walks the same work list as ``mx_paged_decode``, a grid step
+a live block of `_MLA_BLOCK_PAGES` pages: the pages are laid end to end in
+VMEM and the step is two MXU products, ``(H, W) x (W, rows)`` for the scores
+and ``(H, rows) x (rows, rank)`` for the sum, bfloat16 into float32, with the
+online softmax between them in float32. At 128 heads that is 2 x 128 x (576 +
+512) operations a row of 1,152 useful bytes, 242 op/B: on the v5e's ridge.
+The XLA expression (the CPU, a mesh) gathers every slot's view.
 """
 from __future__ import annotations
 
@@ -56,7 +72,8 @@ from jax.experimental.pallas import tpu as pltpu
 from . import _dispatch
 
 __all__ = ["paged_decode_attention", "takes_kernel", "page_store_shape",
-           "pack_pages", "unpack_pages"]
+           "pack_pages", "unpack_pages", "mla_decode_attention",
+           "latent_store_width"]
 
 NEG_INF = -1.0e30   # finite stand-in for -inf: exp() and max() stay NaN-free
 LANES = 128
@@ -290,3 +307,129 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths, *,
     _dispatch.note("paged_decode_attention", "xla")
     return _xla_paged_decode(q, k_pool, v_pool, table, lengths,
                              k_scale, v_scale)
+
+
+# ---------------------------------------------------------------------------
+# latent pages (MLA): one shared row a token, queries absorbed
+# ---------------------------------------------------------------------------
+
+_MLA_BLOCK_PAGES = 32   # pages per grid step, at most (512 rows of 16)
+
+
+def latent_store_width(width):
+    """Lanes a latent row of `width` values is stored in: whole tiles."""
+    return -(-width // LANES) * LANES
+
+
+def _xla_mla_decode(q, pool, table, lengths, rank, sm_scale):
+    S, P = table.shape
+    view = jnp.take(pool, table, axis=0)                   # (S, P, pt, W)
+    view = view.reshape(S, P * view.shape[2], view.shape[3])
+    s = jnp.einsum("shw,srw->shr", q.astype(pool.dtype), view,
+                   preferred_element_type=jnp.float32) * sm_scale
+    mask = jnp.arange(view.shape[1])[None, :] < lengths[:, None]
+    s = jnp.where(mask[:, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(pool.dtype)
+    o = jnp.einsum("shr,src->shc", p, view[..., :rank],
+                   preferred_element_type=jnp.float32)
+    return jnp.where((lengths > 0)[:, None, None], o, 0.0).astype(q.dtype)
+
+
+def _mla_kernel(len_ref, slot_ref, block_ref, _page_ref, q_ref, *refs,
+                block_pages, rank_lanes, sm_scale):
+    G = block_pages
+    k_refs = refs[:G]
+    o_ref, m_scr, l_scr, acc_scr = refs[G:]
+    i = pl.program_id(0)
+    j = block_ref[i]
+    pt = k_refs[0].shape[0]
+    length = len_ref[slot_ref[i]]
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    # the block's pages end to end: (G pt, W). A page past the length was
+    # not fetched (its buffer holds the page before): its rows are masked
+    k = jnp.concatenate([r[...] for r in k_refs], axis=0)
+    s = jax.lax.dot_general(q_ref[...], k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
+    at = j * (G * pt) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(at < length, s, NEG_INF)                    # (H, G pt)
+    m = m_scr[...]                                            # (H, 1)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)                  # a masked row: exp(-1e30) = 0
+    m_scr[...] = m_new
+    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
+        p.astype(k.dtype), k[:, :rank_lanes],
+        preferred_element_type=jnp.float32)
+    # the slot's row of the output stays in VMEM until the slot changes:
+    # what its last block writes is what goes back
+    l = l_scr[...]
+    o_ref[...] = (acc_scr[...] / jnp.where(l > 0.0, l, 1.0)
+                  ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "sm_scale", "interpret"))
+def _pallas_mla_decode(q, pool, table, lengths, rank, sm_scale, interpret):
+    S, H, W = q.shape
+    n_pages, pt, Wp = pool.shape
+    P = table.shape[1]
+    if Wp != W:
+        raise ValueError(f"pool {pool.shape} does not match q {q.shape}")
+    rank_lanes = latent_store_width(rank)
+    G = max(g for g in range(1, _MLA_BLOCK_PAGES + 1) if P % g == 0)
+    table = table.astype(jnp.int32)
+    lengths = jnp.clip(lengths.astype(jnp.int32), 0, P * pt)
+    n, slot, block, page = _work_list(table, lengths, pt, G)
+    pages = [pl.BlockSpec((None, pt, W),
+                          lambda i, lens, slot, block, page, g=g:
+                          (page[i * G + g], 0, 0))
+             for g in range(G)]
+    out = pl.pallas_call(
+        functools.partial(_mla_kernel, block_pages=G, rank_lanes=rank_lanes,
+                          sm_scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n,),
+            in_specs=[pl.BlockSpec((None, H, W),
+                                   lambda i, lens, slot, *_: (slot[i], 0, 0))]
+            + pages,
+            out_specs=pl.BlockSpec((None, H, rank_lanes),
+                                   lambda i, lens, slot, *_: (slot[i], 0, 0)),
+            scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, rank_lanes), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((S, H, rank_lanes), q.dtype),
+        # in order on one core: the softmax state is carried over a slot's
+        # blocks, and a partial block relies on what the step before fetched
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="mx_mla_decode",
+    )(lengths, slot, block, page, q.astype(pool.dtype), *([pool] * G))
+    # a slot with nothing alive has no grid step: its row was never written
+    return jnp.where((lengths > 0)[:, None, None], out[..., :rank],
+                     jnp.zeros((), q.dtype))
+
+
+def mla_decode_attention(q, pool, table, lengths, *, rank, sm_scale,
+                         impl=None):
+    """Attention of one absorbed query a head a slot, ``q`` ``(S, H, W)``
+    (``[q~ (rank) ; q_rope ; zeros]``), over the slot's live latent pages of
+    ``pool`` ``(n_pages, page_tokens, W)``: the weighted sum of the rows'
+    first `rank` values, ``(S, H, rank)`` in `q`'s dtype; zeros for a slot of
+    length 0. `impl`: ``"pallas"`` / ``"xla"`` (tests); None chooses from
+    what the process observes."""
+    if impl is None:
+        impl = "pallas" if _dispatch.use_pallas() else "xla"
+        _dispatch.note("mla_decode_attention", impl)
+    if impl == "pallas":
+        return _pallas_mla_decode(q, pool, table, lengths, rank,
+                                  float(sm_scale),
+                                  _dispatch.interpret_default())
+    return _xla_mla_decode(q, pool, table, lengths, rank, sm_scale)

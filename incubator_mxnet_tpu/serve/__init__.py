@@ -106,6 +106,7 @@ from . import elastic  # noqa: F401
 from . import engine  # noqa: F401
 from . import eva  # noqa: F401
 from . import gateway  # noqa: F401
+from . import mla  # noqa: F401
 from . import router  # noqa: F401
 from . import scheduler  # noqa: F401
 from . import sharded  # noqa: F401
@@ -116,6 +117,7 @@ from .elastic import ReplicaScaleError, ReplicaSetController  # noqa: F401
 from .engine import (PageAllocator, PagePoolExhausted,  # noqa: F401
                      PrefixCache, SlotDecoder)
 from .eva import EvaSlotDecoder  # noqa: F401
+from .mla import MLASlotDecoder  # noqa: F401
 from .gateway import Gateway, GatewayRequest, ModelRegistry  # noqa: F401
 from .router import ReplicaRouter, replica_meshes  # noqa: F401
 from .scheduler import (DeadlineExceeded, EngineClosed,  # noqa: F401
@@ -124,7 +126,8 @@ from .sharded import (ServeLayout, ShardedSlotDecoder,  # noqa: F401
                       serve_mesh)
 from .tenancy import Tenant, TokenBucket, WDRRQueue  # noqa: F401
 
-__all__ = ["ServeEngine", "SlotDecoder", "EvaSlotDecoder", "Scheduler",
+__all__ = ["ServeEngine", "SlotDecoder", "EvaSlotDecoder", "MLASlotDecoder",
+           "Scheduler",
            "Request",
            "PageAllocator", "PrefixCache", "PagePoolExhausted",
            "QueueFull", "DeadlineExceeded", "EngineClosed",
@@ -134,5 +137,5 @@ __all__ = ["ServeEngine", "SlotDecoder", "EvaSlotDecoder", "Scheduler",
            "ReplicaSetController", "ReplicaScaleError",
            "MigrationAborted",
            "Tenant", "TokenBucket", "WDRRQueue",
-           "api", "disagg", "elastic", "engine", "eva", "gateway", "router",
+           "api", "disagg", "elastic", "engine", "eva", "gateway", "mla", "router",
            "scheduler", "sharded", "tenancy"]

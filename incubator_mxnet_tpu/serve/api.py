@@ -40,13 +40,30 @@ _IDLE_SLEEP_S = 0.002     # driver backoff when there is nothing to do
 _DRIVER_MAX_CONSECUTIVE_FAILURES = 3
 
 
+def slots_class(source):
+    """The slots class that serves `source`, by its ``family`` (a source
+    that names none is a GPT Block or `GPTDecoder`)."""
+    from .eva import EvaSlotDecoder
+    from .mla import MLASlotDecoder
+
+    families = {None: SlotDecoder, "evabyte": EvaSlotDecoder,
+                "pangu_moe": MLASlotDecoder}
+    family = getattr(source, "family", None)
+    if family not in families:
+        raise ValueError(
+            f"unknown decoder family {family!r}: mx.serve has slots for "
+            f"{sorted(f for f in families if f)} and, for a source that "
+            "names none, the GPT block")
+    return families[family]
+
+
 class ServeEngine:
     """Continuous-batching inference engine over a decoder: a GPT Block
     (or a prebuilt `GPTDecoder`), or another family's decoder object.
 
     Parameters
     ----------
-    block_or_decoder : Block | GPTDecoder | EvaByteDecoder
+    block_or_decoder : Block | GPTDecoder | EvaByteDecoder | PanguDecoder
         The model to serve. Every family runs the same prefill-chunk and
         decode programs (`serve.engine.SlotDecoder`) over its own block,
         ``decoder.layer(li, lp, x, pos, cache)``, where
@@ -57,7 +74,10 @@ class ServeEngine:
         page arithmetic and chunk cache: an `EvaByteDecoder` (``family =
         "evabyte"``) is served by `serve.eva.EvaSlotDecoder` (window and
         summary pages; no speculative decoding, int8 pages or prefix
-        reuse).
+        reuse), a `PanguDecoder` (``"pangu_moe"``) by
+        `serve.mla.MLASlotDecoder` (latent pages; no speculative decoding,
+        int8 pages or page handoff). The table is `slots_class`'s; a
+        ``family`` it does not name is an error.
     max_slots : int
         In-flight request capacity (static decode batch width).
     max_len : int, optional
@@ -101,10 +121,8 @@ class ServeEngine:
                  spec_k=None, draft=None):
         import os
 
-        family = SlotDecoder
-        if getattr(block_or_decoder, "family", None) == "evabyte":
-            from .eva import EvaSlotDecoder as family
-        slots = family(block_or_decoder, max_slots=max_slots,
+        slots = slots_class(block_or_decoder)(
+                       block_or_decoder, max_slots=max_slots,
                        max_len=max_len, page_tokens=page_tokens,
                        prefill_chunk=prefill_chunk, n_pages=n_pages,
                        kv_dtype=kv_dtype, prefix_reuse=prefix_reuse,

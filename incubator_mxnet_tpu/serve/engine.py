@@ -688,6 +688,26 @@ class SlotDecoder:
         later one see one input placement (no second compile)."""
         return tokens
 
+    #: int32 values a decode or chunk program sends beside its tokens, in
+    #: the same array and so in the same fetch (`_step_out`, `fetch_tokens`)
+    step_extra = 0
+
+    def _step_out(self, tokens, cache):  # noqa: ARG002
+        """Traced seam at the tail of the chunk and decode programs: what
+        goes back to the host beside the pools. The tokens as they are
+        here; a family whose layers count something a step (`serve/mla.py`:
+        the expert layer's held pairs) appends `step_extra` values."""
+        return tokens
+
+    def fetch_tokens(self, out):
+        """A launched decode step's tokens on the host, ``(max_slots,)``:
+        blocks until the step ran (the one host sync of a step)."""
+        return onp.asarray(out)
+
+    def fetch_first(self, out):
+        """A launched final chunk's first token on the host (blocks)."""
+        return int(out)
+
     def _shardcheck_specs(self):
         """``(spec entries for (params, pools), spec entries for the
         builders' (pools, tok) outputs)`` for the shardcheck pre-flight, or
@@ -864,7 +884,7 @@ class SlotDecoder:
         def prefill(params, pools, tokens, pages, t_start, t_len, key,
                     temperature, *, top_k, do_sample):
             n = tokens.shape[1]
-            cache = self._chunk_cache(pools, pages, t_start)
+            cache = self._chunk_cache(pools, pages, t_start, t_len)
             x = self._run_layers(dec, params, tokens,
                                  (t_start + jnp.arange(n))[None, :], cache)
             # the chunk's last REAL row (padding beyond t_len is causally
@@ -873,7 +893,7 @@ class SlotDecoder:
             first = self._sample_slots(
                 dec.next_logits(params, last), key, temperature[None],
                 top_k, do_sample)                              # (1,)
-            return cache.pools(), first[0]
+            return cache.pools(), self._step_out(first[0], cache)
 
         return self._observed(prefill, kind, tokens_idx=2,
                               static_argnames=("top_k", "do_sample"))
@@ -898,9 +918,9 @@ class SlotDecoder:
         chunk_pages[:avail.size] = avail
         return jnp.asarray(row), jnp.asarray(chunk_pages)
 
-    def _chunk_cache(self, pools, pages, t_start):
-        """Traced half: the cache-access object of a chunk at `t_start`
-        whose `pages` are `_chunk_pages`'s."""
+    def _chunk_cache(self, pools, pages, t_start, t_len):  # noqa: ARG002
+        """Traced half: the cache-access object of a chunk of `t_len` real
+        rows at `t_start` whose `pages` are `_chunk_pages`'s."""
         return ChunkCache(self, pools, *pages, t_start)
 
     def _to_bucket(self, chunk_tokens):
@@ -985,20 +1005,26 @@ class SlotDecoder:
                    key, temperature, *, top_k, do_sample):
             # a slot that goes on from the launch before takes the token
             # that launch gave it, which the host may not have seen yet
-            last_tok = jnp.where(last_tok < 0, prev_tok, last_tok)
-            cache = TokenCache(self, pools, table, *self._row_of(pos),
-                               active)
+            last_tok = jnp.where(last_tok < 0,
+                                 prev_tok[:last_tok.shape[0]], last_tok)
+            cache = self._token_cache(pools, table, pos, active)
             x = self._run_layers(dec, params, last_tok, pos, cache)
             nxt = self._sample_slots(dec.next_logits(params, x), key,
                                      temperature, top_k, do_sample)
             # free/prefilling slots carry their last token forward — the
             # host never reads them, but a defined value keeps the
             # program deterministic
-            nxt = self._pin_tokens(jnp.where(active, nxt, last_tok))
+            nxt = self._pin_tokens(self._step_out(
+                jnp.where(active, nxt, last_tok), cache))
             return cache.pools(), nxt
 
         return self._observed(decode, "decode",
                               static_argnames=("top_k", "do_sample"))
+
+    def _token_cache(self, pools, table, pos, active):
+        """Traced: the cache-access object of a decode step whose slots
+        stand at `pos`."""
+        return TokenCache(self, pools, table, *self._row_of(pos), active)
 
     def decode_step(self, last_tok, pos, active, key, temperature):
         """LAUNCH one decode step for every DECODE-ACTIVE slot. `last_tok`
@@ -1011,7 +1037,7 @@ class SlotDecoder:
         launch's output on the device, so the caller can queue this step
         before it has fetched the last one's tokens. Returns the next token
         per slot as the program gives it, a device array NOT yet fetched:
-        ``numpy.asarray`` of it is the one host sync of a step, and whoever
+        `fetch_tokens` of it is the one host sync of a step, and whoever
         needs the tokens makes it (`Scheduler._land`). `key`: a PRNG key, or
         a callable that makes one (called inside the launch span, as in
         `prefill_chunk_step`)."""
@@ -1025,7 +1051,7 @@ class SlotDecoder:
                 key = key()
             if self._tokens is None:
                 self._tokens = self._pin_tokens(
-                    jnp.zeros(self.max_slots, jnp.int32))
+                    jnp.zeros(self.max_slots + self.step_extra, jnp.int32))
             # after the pools: the table, the launch's own copies of the
             # host's arrays, and the tokens of the launch before
             self._pools, self._tokens = self._decode_jit(

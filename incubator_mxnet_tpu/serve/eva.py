@@ -331,5 +331,5 @@ class EvaSlotDecoder(SlotDecoder):
         return (jnp.asarray(sum_pages), jnp.int32(n_sum * pt),
                 jnp.asarray(win_pages), jnp.asarray(chunk_pages))
 
-    def _chunk_cache(self, pools, pages, t_start):
+    def _chunk_cache(self, pools, pages, t_start, t_len):  # noqa: ARG002
         return _ChunkCache(self, pools, *pages, t_start % self.window)

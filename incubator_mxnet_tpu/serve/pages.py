@@ -9,6 +9,10 @@ head's ``(page_tokens, d)`` plane packed to 128 lanes (`ops.paged_attention`,
 ``"sk"`` / ``"sv"``: one float32 scale per (page, head) a layer, the symmetric
 ±127 convention of `contrib.quantization`. Every leaf is ``(n_pages, H, ...)``:
 a layout that shards heads shards them all alike (`serve/sharded.py`).
+A family whose geometry says ``"latent"`` in the place of the heads (MLA:
+`models/pangu.py`) has ONE leaf a layer, ``{"c": leaves}`` of ``(n_pages,
+page_tokens, W)`` rows shared by every head (`ops.paged_attention`, "latent
+pages"); its cache objects are `serve/mla.py`'s.
 
 **A cache-access object** is what a serving program hands a decoder's block
 (`GPTDecoder.layer`, `EvaByteDecoder.layer`) in place of a cache::
@@ -44,6 +48,16 @@ def _leaf_shapes(n_pages, page_tokens, geometry, kv_dtype):
     import numpy as onp
 
     _, H, d, dtype = geometry
+    if H == "latent":
+        from ..ops.paged_attention import latent_store_width
+
+        if kv_dtype == "int8":
+            raise NotImplementedError(
+                "latent pages are not stored as int8: a per-page scale over "
+                "a row whose halves are a normed latent and a rotated key "
+                "is not worked out")
+        return {"c": ((n_pages, page_tokens, latent_store_width(d)),
+                      onp.dtype(dtype))}
     if kv_dtype == "int8":
         page = ((n_pages, H, page_tokens, d), onp.dtype("int8"))
         scale = ((n_pages, H), onp.dtype("float32"))
@@ -56,7 +70,8 @@ def _leaf_shapes(n_pages, page_tokens, geometry, kv_dtype):
 
 def make_pools(n_pages, page_tokens, geometry, kv_dtype):
     """The pools pytree of a model whose K/V rows are `geometry` =
-    ``(layers, heads, head size, float dtype)``, stored as `kv_dtype`
+    ``(layers, heads, head size, float dtype)`` (or ``(layers, "latent", row
+    width, float dtype)``), stored as `kv_dtype`
     (``"fp"`` | ``"int8"``), zeroed. Separate leaves a layer, not one
     stacked 5-D array: `serve/engine.py`'s docstring says why."""
     jnp = _j().numpy

@@ -468,6 +468,11 @@ class Scheduler:
             raise NotImplementedError(
                 "prefill-only handoff moves pages mapped by position; this "
                 "engine's slots take and free pages as they go")
+        if req.prefill_only and not getattr(self.slots, "page_handoff", True):
+            req._trace_close()
+            raise NotImplementedError(
+                "prefill-only handoff is not served for this engine's "
+                "family: its pages are not moved between engines")
         if self._pages_needed(req) > self.slots.allocator.usable_pages:
             req._trace_close()
             raise PagePoolExhausted(
@@ -663,7 +668,7 @@ class Scheduler:
             if last_chunk:
                 with tracing.phase("mx.serve.prefill.readback",
                                    "prefill_readback"):
-                    first = int(first)    # blocks until the chunk ran
+                    first = self.slots.fetch_first(first)  # blocks: it ran
                 w1 = clock.cursor
             else:
                 w1 = clock.lap("prefill_launch")
@@ -820,7 +825,7 @@ class Scheduler:
             self._flight = None
         tokens, rows = flight
         with tracing.phase("mx.serve.decode.readback", "decode_readback"):
-            tokens = onp.asarray(tokens)      # blocks until that step ran
+            tokens = self.slots.fetch_tokens(tokens)  # blocks: it ran
         fetched = tracing.stamp()
         with tracing.phase("mx.serve.emit", "emit"):
             now = time.monotonic()
@@ -1164,6 +1169,10 @@ class Scheduler:
             raise NotImplementedError(
                 "adoption hands over pages mapped by position; this "
                 "engine's slots take and free pages as they go")
+        if not getattr(self.slots, "page_handoff", True):
+            raise NotImplementedError(
+                "adoption is not served for this engine's family: its pages "
+                "are not moved between engines")
         pt = self.slots.page_tokens
         content = -(-prompt_len // pt)
         total = -(-(prompt_len + max_new - 1) // pt)

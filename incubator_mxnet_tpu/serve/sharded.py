@@ -290,6 +290,12 @@ class ShardedSlotDecoder(SlotDecoder):
 
     def __init__(self, source, mesh=None, layout=None, hbm_budget_gb=None,
                  **engine_kwargs):
+        if getattr(source, "family", None) == "pangu_moe":
+            raise NotImplementedError(
+                "the pangu_moe family is not served sharded: `ServeLayout` "
+                "has no rule for a latent pool shared by all heads nor for "
+                "experts held a share a chip, and the exchange between the "
+                "shares is not written (ROADMAP R1)")
         if layout is None:
             if not hasattr(mesh, "shape") or not hasattr(mesh, "devices"):
                 mesh = serve_mesh(mesh)

@@ -17,7 +17,7 @@ import pytest  # noqa: E402
 
 from incubator_mxnet_tpu.ops import dropout as dropout_k  # noqa: E402
 from incubator_mxnet_tpu.ops import (fused_block, layer_norm,  # noqa: E402
-                                     paged_attention)
+                                     moe, paged_attention)
 from incubator_mxnet_tpu.ops.flash_attention import (  # noqa: E402
     flash_attention)
 
@@ -144,3 +144,56 @@ def test_paged_decode_compiles_for_v5e(chip, d, heads):
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "{3,2,1,0:T(8,128)}" in text.split("->")[0]     # pages major
+
+
+def test_mla_decode_compiles_for_v5e(chip):
+    """`mx_mla_decode` at the openPangu-Ultra-MoE cell's sizes: 64 slots x
+    768 pages of 16 rows, 128 heads against latent rows of 576 values
+    stored 640 wide, bfloat16. The pool as the chip lays it out by itself:
+    a page one contiguous block, nothing of the pool's size copied."""
+    S, H, P, pt, W = 64, 128, 768, 16, 640
+    assert paged_attention.latent_store_width(576) == W
+
+    def arg(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    compiled = jax.jit(
+        lambda q, pool, t, n: paged_attention._pallas_mla_decode(
+            q, pool, t, n, 512, 192 ** -0.5, False)).lower(
+        arg((S, H, W)), arg((18240, pt, W)), arg((S, P), jnp.int32),
+        arg((S,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "bf16[18240,16,640]{2,1,0:T(8,128)(2,1)}" in text.split("->")[0]
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
+
+
+@pytest.mark.parametrize("tokens,tile,step", [
+    (64, 16, "decode"), (128, 128, "decode"), (512, 128, "chunk")],
+    ids=["decode-64", "decode-128", "chunk-512"])
+def test_moe_experts_compile_for_v5e(chip, monkeypatch, tokens, tile, step):
+    """The expert layer at the published widths (7,680 -> 2,048 -> 7,680,
+    top-8, 16 experts held): routing's layout, the two launches of the
+    grouped product, the weighted gather back. The kernel's name is the
+    step's, whatever tile the step's batch gives it."""
+    C, F, E, K = 7680, 2048, 16, 8
+    assert moe._tile_rows(tokens * K) == tile
+    # `held_experts` asks `_dispatch.interpret_default()`, which sees the CPU
+    # here: the kernels are to be compiled for the chip
+    monkeypatch.setattr(moe._dispatch, "interpret_default", lambda: False)
+
+    def arg(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    compiled = jax.jit(
+        lambda u, ids, w, wg, wu, wd: moe.held_experts(
+            u, ids, w, (wg, wu, wd), (0, E), None, step=step,
+            impl="pallas"),
+    ).lower(arg((tokens, C)), arg((tokens, K), jnp.int32),
+            arg((tokens, K), F32), arg((E, C, F)), arg((E, C, F)),
+            arg((E, F, C))).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert moe.KERNEL_NAMES[step] in text
+    other, = set(moe.KERNEL_NAMES.values()) - {moe.KERNEL_NAMES[step]}
+    assert other not in text

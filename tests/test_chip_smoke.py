@@ -55,6 +55,17 @@ def test_eva_phase_rehearsal_crosses_a_roll_and_agrees_with_the_reference(capsys
     assert "overshoot rows 0" in line and "(0.0 %)" not in line
 
 
+def test_pangu_phase_rehearsal_agrees_with_the_reference_and_counts_pairs(capsys):
+    """The openPangu-Ultra-MoE phase at its toy size on the CPU: chunked
+    prefill, decode together, tokens the reference's, some pairs held."""
+    chip_smoke.pangu_phase(chip_smoke.PANGU_SIZES[True], 3)
+    out = capsys.readouterr().out
+    line = next(ln for ln in out.splitlines() if "pangu: " in ln
+                and "held pairs" in ln)
+    assert "26 tokens of 3 requests" in line
+    assert "mla_decode_attention" in line and "moe_experts" in line
+
+
 def test_serve_phase_rehearsal_says_how_the_decode_launches_were_made(capsys):
     """The GPT serve phase at its toy size on the CPU: the phases still
     cover the steps' wall with launch and fetch in different iterations,

@@ -161,6 +161,12 @@ class _StubSlots:
         n = len(chunk_tokens)
         return int(t_start) + n, n, 0
 
+    def fetch_tokens(self, out):
+        return onp.asarray(out)
+
+    def fetch_first(self, out):
+        return int(out)
+
     def decode_step(self, last_tok, pos, active, key, temperature):
         return onp.where(active, last_tok + 1, last_tok).astype(onp.int32)
 

@@ -7,7 +7,8 @@ import jax.numpy as jnp
 import pytest
 
 from incubator_mxnet_tpu.ops import dropout as dropout_k
-from incubator_mxnet_tpu.ops import fused_block, layer_norm, paged_attention
+from incubator_mxnet_tpu.ops import (fused_block, layer_norm, moe,
+                                     paged_attention)
 from incubator_mxnet_tpu.ops.flash_attention import flash_attention
 
 ROWS, FEAT = 256, 256
@@ -21,6 +22,22 @@ PAGED_Q = jax.ShapeDtypeStruct((4, 2, 64), jnp.float32)
 POOL = jax.ShapeDtypeStruct((33, 2, 8, 128), jnp.float32)
 TABLE = jax.ShapeDtypeStruct((4, 8), jnp.int32)
 LENGTHS = jax.ShapeDtypeStruct((4,), jnp.int32)
+# the MLA family's decode step: 4 heads against latent rows of 32 + 8 values
+# stored 128 wide, and a grouped expert product over 6 held experts
+MLA_Q = jax.ShapeDtypeStruct((4, 4, 128), jnp.float32)
+MLA_POOL = jax.ShapeDtypeStruct((33, 16, 128), jnp.float32)
+MOE_X = {t: jax.ShapeDtypeStruct((3 * t, 128), jnp.float32) for t in (16, 128)}
+MOE_TILES = jax.ShapeDtypeStruct((3,), jnp.int32)
+MOE_N = jax.ShapeDtypeStruct((), jnp.int32)
+MOE_IN = jax.ShapeDtypeStruct((6, 128, 256), jnp.float32)
+MOE_OUT = jax.ShapeDtypeStruct((6, 256, 128), jnp.float32)
+
+
+def _moe(tile, step):
+    return (lambda x, te, n, wg, wu, wd: moe._pallas_grouped_ffn(
+        x, te, n, wg, wu, wd, tile, moe.KERNEL_NAMES[step], False),
+        (), (MOE_X[tile], MOE_TILES, MOE_N, MOE_IN, MOE_IN, MOE_OUT))
+
 
 # op, its differentiable arguments (none: forward only), the rest
 OPS = {
@@ -39,6 +56,12 @@ OPS = {
         lambda q, k, v, t, n: paged_attention._pallas_paged_decode(
             q, k, v, t, n, False),
         (), (PAGED_Q, POOL, POOL, TABLE, LENGTHS)),
+    "mla_decode": (
+        lambda q, pool, t, n: paged_attention._pallas_mla_decode(
+            q, pool, t, n, 32, 0.2, False),
+        (), (MLA_Q, MLA_POOL, TABLE, LENGTHS)),
+    "moe_experts": _moe(16, "decode"),
+    "moe_chunk_experts": _moe(128, "chunk"),
 }
 # kernel name -> the op whose forward-and-backward program holds it
 KERNELS = {
@@ -46,7 +69,9 @@ KERNELS = {
     "mx_rdln_fwd": "rdln", "mx_rdln_bwd": "rdln",
     "mx_gelu_dropout": "gelu_dropout", "mx_dropout": "dropout",
     "mx_flash_fwd": "flash", "mx_flash_dq": "flash", "mx_flash_dkv": "flash",
-    "mx_paged_decode": "paged_decode",
+    "mx_paged_decode": "paged_decode", "mx_mla_decode": "mla_decode",
+    "mx_moe_experts": "moe_experts",
+    "mx_moe_chunk_experts": "moe_chunk_experts",
 }
 
 
@@ -100,7 +125,8 @@ def test_the_roll_is_a_named_program_and_scope():
 
 
 def test_every_pallas_call_of_the_main_path_is_named():
-    """Ten calls, ten names: a call added without one shows here."""
+    """Every call a name: a call added without one shows here. (`ops/moe.py`
+    has one call, named by its tile: a decode step's and a chunk's.)"""
     import inspect
     import re
     import sys
@@ -114,4 +140,8 @@ def test_every_pallas_call_of_the_main_path_is_named():
         found = re.findall(r'name="(mx_[a-z_]+)"', src)
         assert calls == len(found), mod.__name__
         names += found
+    src = inspect.getsource(moe)
+    assert len(re.findall(r"pl\.pallas_call\(", src)) == 1
+    assert "name=name," in src and "KERNEL_NAMES[step]" in src
+    names += moe.KERNEL_NAMES.values()
     assert sorted(names) == sorted(KERNELS)

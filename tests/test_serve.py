@@ -153,6 +153,12 @@ class _StubSlots:
         self.chunks.append((slot, int(t_start), n))
         return int(t_start) + n, n, 0
 
+    def fetch_tokens(self, out):
+        return onp.asarray(out)
+
+    def fetch_first(self, out):
+        return int(out)
+
     def decode_step(self, last_tok, pos, active, key, temperature):
         return onp.where(active, last_tok + 1, last_tok).astype(onp.int32)
 
@@ -552,6 +558,62 @@ def test_driver_thread_serves_client_submits(eng, ref_dec):
             onp.testing.assert_array_equal(got, ref)
     finally:
         eng.stop()
+
+
+def _failing_steps(e, monkeypatch, n_failures):
+    """`e.step` raises on its first `n_failures` calls (None: on every
+    call), then runs as it is."""
+    real, calls = e.step, []
+
+    def step():
+        calls.append(None)
+        if n_failures is None or len(calls) <= n_failures:
+            raise RuntimeError("planted step fault")
+        return real()
+
+    monkeypatch.setattr(e, "step", step)
+    return calls
+
+
+def test_driver_survives_transient_step_failures(net, ref_dec, monkeypatch,
+                                                 caplog):
+    """Two consecutive failures of `step()` are logged and retried: the
+    driver thread stays alive and serves the request."""
+    e = serve.ServeEngine(net, max_slots=2, max_len=64, max_queue=8)
+    calls = _failing_steps(e, monkeypatch, 2)
+    prompts, _ = _mixed_requests(1, seed=8)
+    with caplog.at_level("ERROR", logger="incubator_mxnet_tpu.serve"):
+        e.start()
+        try:
+            h = e.submit(prompts[0], 4)
+            assert h.wait(timeout=120.0), h.state
+            assert e._driver_running()
+        finally:
+            e.stop()
+    assert len(calls) > 2
+    ref = ref_dec.generate(prompts[0][None, :], 4).asnumpy()[0]
+    got = onp.concatenate([prompts[0], onp.asarray(h.result(), onp.int32)])
+    onp.testing.assert_array_equal(got, ref)
+    failed = [r.getMessage() for r in caplog.records
+              if "step failed" in r.getMessage()]
+    assert len(failed) == 2 and "planted step fault" in failed[0]
+    assert not any("stopping after" in r.getMessage()
+                   for r in caplog.records)
+
+
+def test_driver_stops_after_three_consecutive_step_failures(net, monkeypatch,
+                                                            caplog):
+    """A `step()` that fails every time stops the driver after three
+    tries, with the log line that says so: it does not spin."""
+    e = serve.ServeEngine(net, max_slots=2, max_len=64, max_queue=8)
+    calls = _failing_steps(e, monkeypatch, None)
+    with caplog.at_level("ERROR", logger="incubator_mxnet_tpu.serve"):
+        e.start()
+        e._driver.join(timeout=30.0)
+    assert not e._driver_running()
+    assert len(calls) == 3
+    assert any("stopping after 3 consecutive step failures" in r.getMessage()
+               for r in caplog.records)
 
 
 def test_serve_telemetry_series(eng):

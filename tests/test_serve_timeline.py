@@ -340,6 +340,12 @@ class _StubSlots:
                            temperature=1.0):
         return int(t_start) + len(chunk_tokens), len(chunk_tokens), 0
 
+    def fetch_tokens(self, out):
+        return onp.asarray(out)
+
+    def fetch_first(self, out):
+        return int(out)
+
     def decode_step(self, last, pos, active, key, temps):
         return onp.where(active, last + 1, last).astype(onp.int32)
 

@@ -233,6 +233,12 @@ class _StubSlots:
         n = len(chunk_tokens)
         return int(t_start) + n, n, 0
 
+    def fetch_tokens(self, out):
+        return onp.asarray(out)
+
+    def fetch_first(self, out):
+        return int(out)
+
     def decode_step(self, last, pos, active, key, temps):
         return onp.where(active, last + 1, last).astype(onp.int32)
 
