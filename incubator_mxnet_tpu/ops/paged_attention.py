@@ -51,13 +51,27 @@ of 576 would be padded to 640 lanes by the chip's tiling anyway, so a row
 takes 1,280 B either way, and stored so a page is one contiguous block and a
 row's score is one product). The queries come *absorbed*, ``(S, H, W)``, and
 what goes back is the weighted sum of ``c_kv``, ``(S, H, rank)``. The kernel
-``mx_mla_decode`` walks the same work list as ``mx_paged_decode``, a grid step
-a live block of `_MLA_BLOCK_PAGES` pages: the pages are laid end to end in
-VMEM and the step is two MXU products, ``(H, W) x (W, rows)`` for the scores
-and ``(H, rows) x (rows, rank)`` for the sum, bfloat16 into float32, with the
-online softmax between them in float32. At 128 heads that is 2 x 128 x (576 +
-512) operations a row of 1,152 useful bytes, 242 op/B: on the v5e's ridge.
-The XLA expression (the CPU, a mesh) gathers every slot's view.
+``mx_mla_decode`` has a grid of its own (`_mla_work_list`), a step a live
+block of `_MLA_BLOCK_PAGES` pages, slot after slot, and fetches a block's
+pages ITSELF: the pool is one operand left in HBM, the table is
+scalar-prefetched flat, and a step starts one copy a live page of a LATER
+step's block into one of `_MLA_BUFFERS` ``(G pt, W)`` VMEM blocks (none for
+a page past the slot's length), then waits for its own, started that many
+steps less one earlier. (An operand a page, each pipelined through its own
+`BlockSpec`, cost a grid step more scalar bookkeeping than the products
+took: `tools/kernel_schedule.py`.) The step's body is written once a block,
+for the compiler orders loads after every copy into the same array; the
+copies start after the first product in program order and their address
+arithmetic lies under the products. They carry no range checks
+(`disable_bounds_checks` on this one call): the table is clipped to the pool
+before the call. Rows never fetched hold zeros or an earlier page (every
+block is zeroed at the call's first step), and the mask gives them no
+weight. A step is two MXU products, ``(H, W) x (W, rows)`` for the scores
+and ``(H, rows) x (rows, rank)`` for the sum, bfloat16 into float32, with
+the online softmax between them in float32; a slot's last block divides and
+stores the output row. At 128 heads that is 2 x 128 x (576 + 512) operations
+a row of 1,152 useful bytes, 242 op/B: on the v5e's ridge. The XLA
+expression (the CPU, a mesh) gathers every slot's view.
 """
 from __future__ import annotations
 
@@ -314,6 +328,11 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths, *,
 # ---------------------------------------------------------------------------
 
 _MLA_BLOCK_PAGES = 32   # pages per grid step, at most (512 rows of 16)
+# VMEM blocks the latent kernel fetches into, a block's copies started one
+# fewer steps before it is read: from the first copy's start to the last
+# one's end a block of 32 pages takes 1.3 us on a v5e, a grid step's products
+# as long, so one step ahead the wait still shows (PERF.md, PR 34)
+_MLA_BUFFERS = 3
 
 
 def latent_store_width(width):
@@ -335,15 +354,72 @@ def _xla_mla_decode(q, pool, table, lengths, rank, sm_scale):
     return jnp.where((lengths > 0)[:, None, None], o, 0.0).astype(q.dtype)
 
 
-def _mla_kernel(len_ref, slot_ref, block_ref, _page_ref, q_ref, *refs,
-                block_pages, rank_lanes, sm_scale):
-    G = block_pages
-    k_refs = refs[:G]
-    o_ref, m_scr, l_scr, acc_scr = refs[G:]
+def _mla_work_list(lengths, page_tokens, block_pages, n_blocks):
+    """The latent kernel's grid, made from the lengths (a few scalar-sized
+    XLA ops, the same for every layer of a step): one grid step per LIVE
+    block of `block_pages` pages, slot after slot — ``n`` of them (at least
+    one), then ``slot[i]``, ``block[i]`` and ``pages[i]``, the slot's live
+    pages from the block's first on (more than `block_pages` where the slot
+    goes on). The kernel reads a block's pages from the table itself."""
+    S, G, NB = lengths.shape[0], block_pages, n_blocks
+    n_pages = -(-lengths // page_tokens)                          # (S,)
+    live_block = (jnp.arange(NB)[None, :] * G < n_pages[:, None]).reshape(-1)
+    n = jnp.sum(live_block, dtype=jnp.int32)
+    flat, = jnp.nonzero(live_block, size=S * NB, fill_value=0)
+    flat = flat.astype(jnp.int32)
+    slot, block = flat // NB, flat % NB
+    return jnp.maximum(n, 1), slot, block, n_pages[slot] - block * G
+
+
+def _mla_kernel(len_ref, slot_ref, block_ref, pages_ref, table_ref, q_ref,
+                pool_ref, o_ref, *scratch, block_pages, table_pages,
+                rank_lanes, sm_scale):
+    G, P = block_pages, table_pages
+    bufs = scratch[:_MLA_BUFFERS]
+    sems, m_scr, l_scr, acc_scr = scratch[_MLA_BUFFERS:]
+    ahead = _MLA_BUFFERS - 1    # a block's copies start this many steps early
+    pt = bufs[0].shape[0] // G
     i = pl.program_id(0)
+    n = pl.num_programs(0)
     j = block_ref[i]
-    pt = k_refs[0].shape[0]
     length = len_ref[slot_ref[i]]
+
+    def start(step, buf, sem):
+        """One copy a live page of `step`'s block: none for a page past the
+        slot's length, none for a step past the last."""
+        at = jnp.minimum(step, n - 1)
+        pages = jnp.where(step < n, pages_ref[at], 0)
+        first = slot_ref[at] * P + block_ref[at] * G    # in the flat table
+        for g in range(G):
+            @pl.when(g < pages)
+            def _(g=g):
+                pltpu.make_async_copy(pool_ref.at[table_ref[first + g]],
+                                      buf.at[pl.ds(g * pt, pt)], sem).start()
+
+    def wait(buf, sem):
+        pages = pages_ref[i]
+
+        @pl.when(pages >= G)        # the whole block: one wait for its bytes
+        def _():
+            pltpu.make_async_copy(buf, buf, sem).wait()
+
+        page = buf.at[pl.ds(0, pt)]     # a page's bytes, `pages` times
+
+        def one(_, carry):
+            pltpu.make_async_copy(page, page, sem).wait()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.where(pages < G, pages, 0), one, None)
+
+    # Rows that are never fetched (past a slot's length) must hold numbers:
+    # their weight is exp(-1e30) = 0, and 0 x NaN would poison the sum. So
+    # every block starts as zeros and only real pages are ever written
+    @pl.when(i == 0)
+    def _():
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
+        for step in range(ahead):
+            start(step, bufs[step], sems.at[step])
 
     @pl.when(j == 0)
     def _():
@@ -351,67 +427,89 @@ def _mla_kernel(len_ref, slot_ref, block_ref, _page_ref, q_ref, *refs,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # the block's pages end to end: (G pt, W). A page past the length was
-    # not fetched (its buffer holds the page before): its rows are masked
-    k = jnp.concatenate([r[...] for r in k_refs], axis=0)
-    s = jax.lax.dot_general(q_ref[...], k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-    at = j * (G * pt) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(at < length, s, NEG_INF)                    # (H, G pt)
-    m = m_scr[...]                                            # (H, 1)
-    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m - m_new)
-    p = jnp.exp(s - m_new)                  # a masked row: exp(-1e30) = 0
-    m_scr[...] = m_new
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
-        p.astype(k.dtype), k[:, :rank_lanes],
-        preferred_element_type=jnp.float32)
+    def step(buf, sem, free, free_sem):
+        wait(buf, sem)
+        # the block as one (G pt, W) array, read where it is used: held as
+        # a value it would be spilled between the two products
+        s = jax.lax.dot_general(q_ref[...], buf[...],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * sm_scale
+        # a later step's pages into the block the step before this one
+        # read, while this one is worked on: after the first product in
+        # program order, so that the copies' address arithmetic lies under
+        # the products
+        start(i + ahead, free, free_sem)
+        at = j * (G * pt) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(at < length, s, NEG_INF)                # (H, G pt)
+        m = m_scr[...]                                        # (H, 1)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)              # a masked row: exp(-1e30) = 0
+        m_scr[...] = m_new
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
+            p.astype(buf.dtype), buf[:, :rank_lanes],
+            preferred_element_type=jnp.float32)
+
+    # blocks the compiler can tell apart, the body once for each: with one
+    # array indexed by the step it orders a block's load after every start
+    for r in range(_MLA_BUFFERS):
+        @pl.when(i % _MLA_BUFFERS == r)
+        def _(r=r):
+            free = (r + ahead) % _MLA_BUFFERS
+            step(bufs[r], sems.at[r], bufs[free], sems.at[free])
+
     # the slot's row of the output stays in VMEM until the slot changes:
-    # what its last block writes is what goes back
-    l = l_scr[...]
-    o_ref[...] = (acc_scr[...] / jnp.where(l > 0.0, l, 1.0)
-                  ).astype(o_ref.dtype)
+    # its last block writes what goes back
+    @pl.when((j + 1) * (G * pt) >= length)
+    def _():
+        l = l_scr[...]
+        o_ref[...] = (acc_scr[...] / jnp.where(l > 0.0, l, 1.0)
+                      ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("rank", "sm_scale", "interpret"))
-def _pallas_mla_decode(q, pool, table, lengths, rank, sm_scale, interpret):
+@functools.partial(jax.jit, static_argnames=("rank", "sm_scale", "interpret",
+                                             "block_pages"))
+def _pallas_mla_decode(q, pool, table, lengths, rank, sm_scale, interpret,
+                       block_pages=_MLA_BLOCK_PAGES):
     S, H, W = q.shape
     n_pages, pt, Wp = pool.shape
     P = table.shape[1]
     if Wp != W:
         raise ValueError(f"pool {pool.shape} does not match q {q.shape}")
     rank_lanes = latent_store_width(rank)
-    G = max(g for g in range(1, _MLA_BLOCK_PAGES + 1) if P % g == 0)
-    table = table.astype(jnp.int32)
+    G = max(g for g in range(1, block_pages + 1) if P % g == 0)
+    # no address can leave the pool whatever the table holds: the kernel's
+    # copies carry no range checks of their own (`disable_bounds_checks`)
+    table = jnp.clip(table.astype(jnp.int32), 0, n_pages - 1)
     lengths = jnp.clip(lengths.astype(jnp.int32), 0, P * pt)
-    n, slot, block, page = _work_list(table, lengths, pt, G)
-    pages = [pl.BlockSpec((None, pt, W),
-                          lambda i, lens, slot, block, page, g=g:
-                          (page[i * G + g], 0, 0))
-             for g in range(G)]
+    n, slot, block, pages = _mla_work_list(lengths, pt, G, P // G)
+    row = lambda i, lens, slot, *_: (slot[i], 0, 0)  # noqa: E731
     out = pl.pallas_call(
-        functools.partial(_mla_kernel, block_pages=G, rank_lanes=rank_lanes,
-                          sm_scale=sm_scale),
+        functools.partial(_mla_kernel, block_pages=G, table_pages=P,
+                          rank_lanes=rank_lanes, sm_scale=sm_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(n,),
-            in_specs=[pl.BlockSpec((None, H, W),
-                                   lambda i, lens, slot, *_: (slot[i], 0, 0))]
-            + pages,
-            out_specs=pl.BlockSpec((None, H, rank_lanes),
-                                   lambda i, lens, slot, *_: (slot[i], 0, 0)),
-            scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, rank_lanes), jnp.float32)]),
+            in_specs=[pl.BlockSpec((None, H, W), row),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, H, rank_lanes), row),
+            scratch_shapes=[
+                *[pltpu.VMEM((G * pt, W), pool.dtype)] * _MLA_BUFFERS,
+                pltpu.SemaphoreType.DMA((_MLA_BUFFERS,)),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, rank_lanes), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((S, H, rank_lanes), q.dtype),
         # in order on one core: the softmax state is carried over a slot's
-        # blocks, and a partial block relies on what the step before fetched
+        # blocks, and a step starts the copies a later one waits for
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            disable_bounds_checks=True),
         interpret=interpret,
         name="mx_mla_decode",
-    )(lengths, slot, block, page, q.astype(pool.dtype), *([pool] * G))
+    )(lengths, slot, block, pages, table.reshape(-1), q.astype(pool.dtype),
+      pool)
     # a slot with nothing alive has no grid step: its row was never written
     return jnp.where((lengths > 0)[:, None, None], out[..., :rank],
                      jnp.zeros((), q.dtype))

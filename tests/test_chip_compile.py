@@ -8,6 +8,7 @@ every later PR at no chip time. Nothing runs; a compile that passes is not
 a chip run. Skipped where the topology cannot be described.
 """
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
 
@@ -150,7 +151,10 @@ def test_mla_decode_compiles_for_v5e(chip):
     """`mx_mla_decode` at the openPangu-Ultra-MoE cell's sizes: 64 slots x
     768 pages of 16 rows, 128 heads against latent rows of 576 values
     stored 640 wide, bfloat16. The pool as the chip lays it out by itself:
-    a page one contiguous block, nothing of the pool's size copied."""
+    a page one contiguous block, nothing of the pool's size copied; and the
+    pool ONE operand of the kernel, which fetches a block's pages itself
+    (an operand a page of a block cost more of a grid step than the
+    products)."""
     S, H, P, pt, W = 64, 128, 768, 16, 640
     assert paged_attention.latent_store_width(576) == W
 
@@ -166,6 +170,12 @@ def test_mla_decode_compiles_for_v5e(chip):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "bf16[18240,16,640]{2,1,0:T(8,128)(2,1)}" in text.split("->")[0]
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
+    call, = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    pool, = re.findall(r"(%[\w.]+) = bf16\[18240,16,640\]\S* parameter\(1\)",
+                       text)
+    operands = call.split("custom-call(", 1)[1].split(")", 1)[0]
+    assert re.findall(r"%[\w.]+", operands).count(pool) == 1
 
 
 @pytest.mark.parametrize("tokens,tile,step", [

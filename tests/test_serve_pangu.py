@@ -210,30 +210,74 @@ def test_stored_form_is_the_checkpoints_product(dec):
 
 # -- (c) the kernels in interpret mode ----------------------------------------
 
-@pytest.mark.parametrize("lengths", [(5, 0, 29), (32, 17, 1), (0, 0, 0)],
-                         ids=["ragged", "full-and-one", "nothing-alive"])
-def test_mla_decode_kernel_is_its_xla_expression(lengths):
+# (id, lengths, pages a block at most, pool dtype): of a table of 8 pages of
+# 4 rows a slot. The kernel fetches a block's pages itself into one of a few
+# buffers, so the cases walk every way a buffer can be left and found
+MLA_CASES = [
+    ("ragged", (5, 0, 29), 32, jnp.float32),
+    ("full-and-one", (32, 17, 1), 32, jnp.float32),
+    ("nothing-alive", (0, 0, 0), 32, jnp.float32),
+    # four and eight blocks of one slot: every buffer used, and used again
+    ("four-blocks", (5, 0, 29), 2, jnp.float32),
+    ("eight-blocks-each", (32, 32, 32), 1, jnp.float32),
+    # a short slot straight after a long one whose rows are large: the rows
+    # the long one left in the buffers must not reach the short one's sum
+    ("short-after-long", (32, 3, 6), 4, jnp.float32),
+    ("short-after-long-2", (31, 1, 9), 2, jnp.float32),
+    ("one-whole-block", (0, 16, 0), 4, jnp.float32),
+    ("whole-blocks", (8, 16, 24), 2, jnp.float32),
+    ("one-live-among-free", (0, 9, 0), 2, jnp.float32),
+    ("last-slot-alone", (0, 0, 32), 4, jnp.float32),
+    ("one-row-in-all", (0, 1, 0), 2, jnp.float32),
+    ("bf16-ragged", (5, 0, 29), 2, jnp.bfloat16),
+    ("bf16-full", (32, 17, 1), 4, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("lengths,block_pages,dtype",
+                         [c[1:] for c in MLA_CASES],
+                         ids=[c[0] for c in MLA_CASES])
+def test_mla_decode_kernel_is_its_xla_expression(lengths, block_pages, dtype):
     rng = onp.random.default_rng(0)
     S, H, rank, dr, pt, P, n_pages = 3, 4, 32, 8, 4, 8, 40
     W = paged_attention.latent_store_width(rank + dr)
     assert W == 128 and paged_attention.latent_store_width(576) == 640
-    pool = jnp.asarray(rng.normal(size=(n_pages, pt, W)), jnp.float32)
-    pool = pool.at[..., rank + dr:].set(0)
     table = jnp.asarray(rng.permutation(onp.arange(1, n_pages))[:S * P]
                         .reshape(S, P), jnp.int32)
+    pool = rng.normal(size=(n_pages, pt, W))
+    pool[onp.asarray(table[0])] *= 100.0          # the first slot's rows
+    pool = jnp.asarray(pool, dtype).at[..., rank + dr:].set(0)
     q = jnp.asarray(rng.normal(size=(S, H, W)), jnp.float32)
     q = q.at[..., rank + dr:].set(0)
     n = jnp.asarray(lengths, jnp.int32)
-    kw = dict(rank=rank, sm_scale=0.2)
     a = paged_attention.mla_decode_attention(q, pool, table, n, impl="xla",
-                                             **kw)
-    b = paged_attention.mla_decode_attention(q, pool, table, n,
-                                             impl="pallas", **kw)
-    assert a.shape == (S, H, rank)
-    onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b), atol=1e-5)
+                                             rank=rank, sm_scale=0.2)
+    b = paged_attention._pallas_mla_decode(q, pool, table, n, rank, 0.2,
+                                           True, block_pages=block_pages)
+    assert a.shape == b.shape == (S, H, rank) and b.dtype == q.dtype
+    # bfloat16: the kernel rounds exp(s - m) of a running maximum, the
+    # expression a softmax already normalised
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b), atol=tol,
+                                rtol=tol)
     for s, ln in enumerate(lengths):
         if ln == 0:
             assert not onp.asarray(b[s]).any()
+
+
+def test_mla_decode_op_takes_the_kernel_by_name():
+    """`impl="pallas"` of the op is the kernel at its own block size."""
+    rng = onp.random.default_rng(1)
+    pool = jnp.asarray(rng.normal(size=(9, 4, 128)), jnp.float32)
+    table = jnp.arange(1, 9, dtype=jnp.int32).reshape(1, 8)
+    q = jnp.asarray(rng.normal(size=(1, 2, 128)), jnp.float32)
+    n = jnp.asarray([30], jnp.int32)
+    kw = dict(rank=32, sm_scale=0.2)
+    onp.testing.assert_allclose(
+        onp.asarray(paged_attention.mla_decode_attention(
+            q, pool, table, n, impl="pallas", **kw)),
+        onp.asarray(paged_attention.mla_decode_attention(
+            q, pool, table, n, impl="xla", **kw)), atol=1e-5)
 
 
 def _plain_experts(u, ids, w, ws, held, valid):

@@ -1249,3 +1249,54 @@ def test_bench_regress_direction_and_edge_cases(tmp_path):
     empty = tmp_path / "empty"
     empty.mkdir()
     assert br.main(["--root", str(empty)]) == 2
+
+
+def test_kernel_schedule_reads_a_final_bundles_file():
+    """`tools/kernel_schedule.py`'s parser on a few lines of a real dump:
+    the grid loop, the kinds of work a bundle holds, and a branch's target
+    found by its rank among the marked lines (a target is numbered with
+    the delay slots the file leaves out)."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    try:
+        import kernel_schedule as ks
+    finally:
+        sys.path.pop(0)
+    with open(os.path.join(REPO, "tests", "data",
+                           "final_bundles_sample.txt")) as f:
+        text = f.read()
+    bundles = ks.parse(text)
+    assert [b["bundle"] for b in bundles] == list(range(16))
+    assert bundles[0]["line"] == 7 and bundles[7]["line"] == 16
+    # a range check names the copy it guards in a comment: not a copy
+    assert dict(bundles[5]["kinds"]) == {"check": 1}
+    assert dict(bundles[9]["kinds"]) == {"vmatmul": 1, "dma": 1}
+    assert dict(bundles[11]["kinds"]) == {"vpop": 1}      # not `vpop.eup`
+    assert bundles[3]["target"] == 12 and bundles[2]["mark"] == "LB"
+    # targets 3 < 12 < 40 are the marked lines LB, PF, PF in order
+    where = ks.branch_lines(bundles)
+    assert where == {3: 9, 12: 16, 40: 24}
+    loop = ks.grid_loop(bundles)
+    assert (loop[0]["line"], loop[-1]["line"], len(loop)) == (9, 22, 12)
+    parts = ks.stretches(loop, where)
+    assert [(p["first"], p["last"], p["lines"], p["skip_to"], p["delayed"])
+            for p in parts] == [
+        (9, 10, 2, None, 3),        # the loop's start, up to the branch
+        (11, 12, 2, 16, None),      # walked only where it is not taken
+        (13, 13, 1, None, None),    # the first copy
+        (16, 16, 1, None, 12),      # the branch's target: the one wait
+        (17, 17, 1, None, None),
+        (18, 18, 1, None, None),    # the first product, the last copy
+        (19, 19, 1, None, None),    # the last product, the first result
+        (20, 20, 1, None, None),    # the last result popped
+        (21, 22, 2, None, None),
+    ]
+    assert dict(parts[1]["kinds"]) == {"check": 1}
+    assert sum((p["kinds"] for p in parts), ks.collections.Counter()) == {
+        "check": 1, "dma": 2, "wait": 1, "vmatmul": 2, "vpop": 2}
+    import io
+    out = io.StringIO()
+    ks.report("mx_sample", text, out=out)
+    assert "the grid loop 12 (file lines 9-22)" in out.getvalue()
+    assert "[or skip to 16] 1 check" in out.getvalue()
+    ks.report("mx_none", "     0   :  { %s1 = smov 1 }", out=out)
+    assert "mx_none: 1 bundles, no loop" in out.getvalue()
