@@ -315,6 +315,10 @@ class GPTDecoder:
 
     # -- the mathematics (traced) --------------------------------------------
 
+    def layer_kinds(self):
+        """What each layer keeps in a slot (`serve/engine.py`): pages, all."""
+        return ("pages",) * len(self._params["layers"])
+
     def kv_geometry(self):
         """``(layers, heads, head size, dtype)`` of the K/V rows a cache
         holds for this model."""

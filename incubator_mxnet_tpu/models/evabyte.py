@@ -157,6 +157,10 @@ class EvaByteDecoder:
         """The engine's hot-swap seam: these weights are the decoder's own
         and do not change under it."""
 
+    def layer_kinds(self):
+        """What each layer keeps in a slot (`serve/engine.py`): pages, all."""
+        return ("pages",) * self.config.num_hidden_layers
+
     def kv_geometry(self):
         """``(layers, heads, head size, dtype)`` of the K/V rows a cache
         holds for this model."""

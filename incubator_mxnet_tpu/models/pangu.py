@@ -224,6 +224,10 @@ class PanguDecoder:
         """The engine's hot-swap seam: these weights are the decoder's own
         and do not change under it."""
 
+    def layer_kinds(self):
+        """What each layer keeps in a slot (`serve/engine.py`): pages, all."""
+        return ("pages",) * self.config.num_hidden_layers
+
     def kv_geometry(self):
         """``(layers, "latent", row width, dtype)``: a page of this family
         is one leaf of latent rows shared by all heads (`serve/pages.py`)."""
@@ -296,7 +300,7 @@ class PanguDecoder:
         routed, stats = moe.held_experts(
             u.astype(self.dtype), ids, weights,
             (lp["we_gate"], lp["we_up"], lp["we_down"]), cfg.held, valid,
-            step=step)
+            step=step, routed=cfg.n_routed_experts)
         return gated(lp["ws_gate"], lp["ws_up"], lp["ws_down"]) + routed, stats
 
     def layer(self, li, lp, x, pos, cache):

@@ -6,7 +6,9 @@ engine keeps them (see *a page as it is stored*); ``table`` ``(S, P)`` int32
 maps a slot's token range to pool pages; ``lengths`` ``(S,)`` int32 is how
 many tokens of the slot are alive (``pos + 1`` for a decoding slot, 0 for a
 free or prefilling one). Returns ``(S, H, d)``; a slot of length 0 yields
-zeros.
+zeros. **Grouped heads**: where `q` has ``rep`` times the heads the pools
+store, query head ``h`` attends stored head ``h // rep``; a page is fetched
+once for all of them.
 
 **A page as it is stored.** A page is ``(H, page_tokens, d)`` values. Where
 the head is narrower than the TPU's 128 lanes, a float pool keeps each
@@ -144,6 +146,9 @@ def _xla_paged_decode(q, k_pool, v_pool, table, lengths, k_scale, v_scale):
     d = q.shape[-1]
     vk = _view(k_pool, k_scale, table, d)
     vv = _view(v_pool, v_scale, table, d)
+    if q.shape[1] != vk.shape[1]:       # grouped: every query head its own
+        rep = q.shape[1] // vk.shape[1]
+        vk, vv = (jnp.repeat(t, rep, axis=1) for t in (vk, vv))
     s = jnp.einsum("shqd,shkd->shqk", q[:, :, None, :], vk,
                    preferred_element_type=jnp.float32)
     s = s / math.sqrt(d)
@@ -168,6 +173,7 @@ def _kernel(len_ref, slot_ref, block_ref, _page_ref, q_ref, *refs,
     i = pl.program_id(0)
     j = block_ref[i]                    # which block of its slot's pages
     H, rows, lanes = k_refs[0].shape
+    rep = q_ref.shape[1]                # query heads a stored head
     per_row = lanes // d                # tokens side by side in a row
     length = len_ref[slot_ref[i]]
 
@@ -177,7 +183,7 @@ def _kernel(len_ref, slot_ref, block_ref, _page_ref, q_ref, *refs,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # the query, once per token of a row: (H, 1, lanes)
+    # the query, once per token of a row: (H, rep, lanes)
     q = q_ref[...].astype(jnp.float32) * sm_scale
     row = jax.lax.broadcasted_iota(jnp.int32, (H, rows, 1), 1)
     if per_row > 1:                     # the lanes of a row's c-th token
@@ -194,28 +200,31 @@ def _kernel(len_ref, slot_ref, block_ref, _page_ref, q_ref, *refs,
         def _():
             k = k_refs[g][...].astype(jnp.float32)        # (H, rows, lanes)
             v = v_refs[g][...].astype(jnp.float32)
-            kq = k * q
-            sc = []                     # per token of a row: (H, rows, 1)
-            for c in range(per_row):
-                part = kq if per_row == 1 else jnp.where(seg[c], kq, 0.0)
-                s_c = jnp.sum(part, axis=-1, keepdims=True)
-                alive = first + row * per_row + c < length
-                sc.append(jnp.where(alive, s_c, NEG_INF))
-            m = m_scr[...]
-            m_new = m
-            for s_c in sc:
-                m_new = jnp.maximum(m_new,
-                                    jnp.max(s_c, axis=1, keepdims=True))
-            alpha = jnp.exp(m - m_new)                    # (H, 1, 1)
-            p = [jnp.exp(s_c - m_new) for s_c in sc]      # masked: exp(-1e30)
-            w = p[0]                    # each token's weight on its lanes
-            for c in range(1, per_row):
-                w = jnp.where(seg[c], p[c], w)
-            m_scr[...] = m_new
-            l_scr[...] = alpha * l_scr[...] + sum(
-                jnp.sum(p_c, axis=1, keepdims=True) for p_c in p)
-            acc_scr[...] = alpha * acc_scr[...] + jnp.sum(
-                w * v, axis=1, keepdims=True)             # (H, 1, lanes)
+            for r in range(rep):        # the stored head's r-th query head
+                at = (Ellipsis,) if rep == 1 else (slice(None),
+                                                   slice(r, r + 1))
+                kq = k * (q if rep == 1 else q[at])
+                sc = []                 # per token of a row: (H, rows, 1)
+                for c in range(per_row):
+                    part = kq if per_row == 1 else jnp.where(seg[c], kq, 0.0)
+                    s_c = jnp.sum(part, axis=-1, keepdims=True)
+                    alive = first + row * per_row + c < length
+                    sc.append(jnp.where(alive, s_c, NEG_INF))
+                m = m_scr[at]
+                m_new = m
+                for s_c in sc:
+                    m_new = jnp.maximum(m_new,
+                                        jnp.max(s_c, axis=1, keepdims=True))
+                alpha = jnp.exp(m - m_new)                # (H, 1, 1)
+                p = [jnp.exp(s_c - m_new) for s_c in sc]  # masked: exp(-1e30)
+                w = p[0]                # each token's weight on its lanes
+                for c in range(1, per_row):
+                    w = jnp.where(seg[c], p[c], w)
+                m_scr[at] = m_new
+                l_scr[at] = alpha * l_scr[at] + sum(
+                    jnp.sum(p_c, axis=1, keepdims=True) for p_c in p)
+                acc_scr[at] = alpha * acc_scr[at] + jnp.sum(
+                    w * v, axis=1, keepdims=True)         # (H, 1, lanes)
 
     # the slot's row of the output stays in VMEM until the slot changes:
     # what its last block writes is what goes back. Still one partial sum
@@ -259,12 +268,13 @@ def _work_list(table, lengths, page_tokens, block_pages):
 # kernel once (lowering 48 pallas calls one by one is seconds of set-up)
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _pallas_paged_decode(q, k_pool, v_pool, table, lengths, interpret):
-    S, H, d = q.shape
-    n_pages, Hk, rows, lanes = k_pool.shape
+    S, Hq, d = q.shape
+    n_pages, H, rows, lanes = k_pool.shape
     P = table.shape[1]
-    if Hk != H or lanes % d or v_pool.shape != k_pool.shape:
+    if Hq % H or lanes % d or v_pool.shape != k_pool.shape:
         raise ValueError(
             f"pools {k_pool.shape} / {v_pool.shape} do not match q {q.shape}")
+    rep = Hq // H                       # query heads a stored head
     per_row = lanes // d
     pt = rows * per_row
     # pages per grid step: one-page steps would cost more than the pages
@@ -272,7 +282,7 @@ def _pallas_paged_decode(q, k_pool, v_pool, table, lengths, interpret):
     table = table.astype(jnp.int32)
     lengths = jnp.clip(lengths.astype(jnp.int32), 0, P * pt)
     n, slot, block, page = _work_list(table, lengths, pt, G)
-    row = pl.BlockSpec((None, H, 1, lanes),
+    row = pl.BlockSpec((None, H, rep, lanes),
                        lambda i, lens, slot, *_: (slot[i], 0, 0, 0))
     pages = [pl.BlockSpec((None, H, rows, lanes),
                           lambda i, lens, slot, block, page, g=g:
@@ -286,10 +296,10 @@ def _pallas_paged_decode(q, k_pool, v_pool, table, lengths, interpret):
             grid=(n,),
             in_specs=[row] + pages + pages,
             out_specs=row,
-            scratch_shapes=[pltpu.VMEM((H, 1, 1), jnp.float32),
-                            pltpu.VMEM((H, 1, 1), jnp.float32),
-                            pltpu.VMEM((H, 1, lanes), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((S, H, 1, lanes), q.dtype),
+            scratch_shapes=[pltpu.VMEM((H, rep, 1), jnp.float32),
+                            pltpu.VMEM((H, rep, 1), jnp.float32),
+                            pltpu.VMEM((H, rep, lanes), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((S, H, rep, lanes), q.dtype),
         # in order on one core: the softmax state is carried over a slot's
         # blocks, and a partial block relies on what the step before fetched
         compiler_params=pltpu.CompilerParams(
@@ -297,9 +307,9 @@ def _pallas_paged_decode(q, k_pool, v_pool, table, lengths, interpret):
         interpret=interpret,
         name="mx_paged_decode",
     )(lengths, slot, block, page,
-      jnp.tile(q, (1, 1, per_row)).reshape(S, H, 1, lanes),
+      jnp.tile(q, (1, 1, per_row)).reshape(S, H, rep, lanes),
       *([k_pool] * G), *([v_pool] * G))
-    out = out.reshape(S, H, per_row, d).sum(axis=2)
+    out = out.reshape(S, Hq, per_row, d).sum(axis=2)
     # a slot with nothing alive has no grid step: its row was never written
     return jnp.where((lengths > 0)[:, None, None], out,
                      jnp.zeros((), q.dtype))

@@ -110,6 +110,7 @@ from . import mla  # noqa: F401
 from . import router  # noqa: F401
 from . import scheduler  # noqa: F401
 from . import sharded  # noqa: F401
+from . import ssm  # noqa: F401
 from . import tenancy  # noqa: F401
 from .api import ServeEngine  # noqa: F401
 from .disagg import MigrationAborted  # noqa: F401
@@ -118,6 +119,7 @@ from .engine import (PageAllocator, PagePoolExhausted,  # noqa: F401
                      PrefixCache, SlotDecoder)
 from .eva import EvaSlotDecoder  # noqa: F401
 from .mla import MLASlotDecoder  # noqa: F401
+from .ssm import HybridSlotDecoder  # noqa: F401
 from .gateway import Gateway, GatewayRequest, ModelRegistry  # noqa: F401
 from .router import ReplicaRouter, replica_meshes  # noqa: F401
 from .scheduler import (DeadlineExceeded, EngineClosed,  # noqa: F401
@@ -127,7 +129,7 @@ from .sharded import (ServeLayout, ShardedSlotDecoder,  # noqa: F401
 from .tenancy import Tenant, TokenBucket, WDRRQueue  # noqa: F401
 
 __all__ = ["ServeEngine", "SlotDecoder", "EvaSlotDecoder", "MLASlotDecoder",
-           "Scheduler",
+           "HybridSlotDecoder", "Scheduler",
            "Request",
            "PageAllocator", "PrefixCache", "PagePoolExhausted",
            "QueueFull", "DeadlineExceeded", "EngineClosed",
@@ -138,4 +140,4 @@ __all__ = ["ServeEngine", "SlotDecoder", "EvaSlotDecoder", "MLASlotDecoder",
            "MigrationAborted",
            "Tenant", "TokenBucket", "WDRRQueue",
            "api", "disagg", "elastic", "engine", "eva", "gateway", "mla", "router",
-           "scheduler", "sharded", "tenancy"]
+           "scheduler", "sharded", "ssm", "tenancy"]

@@ -45,9 +45,10 @@ def slots_class(source):
     that names none is a GPT Block or `GPTDecoder`)."""
     from .eva import EvaSlotDecoder
     from .mla import MLASlotDecoder
+    from .ssm import HybridSlotDecoder
 
     families = {None: SlotDecoder, "evabyte": EvaSlotDecoder,
-                "pangu_moe": MLASlotDecoder}
+                "pangu_moe": MLASlotDecoder, "nemotron_h": HybridSlotDecoder}
     family = getattr(source, "family", None)
     if family not in families:
         raise ValueError(
@@ -64,19 +65,24 @@ class ServeEngine:
     Parameters
     ----------
     block_or_decoder : Block | GPTDecoder | EvaByteDecoder | PanguDecoder
+            | NemotronHDecoder
         The model to serve. Every family runs the same prefill-chunk and
         decode programs (`serve.engine.SlotDecoder`) over its own block,
         ``decoder.layer(li, lp, x, pos, cache)``, where
         ``cache.attend(li, q, k, v)`` writes the rows into the page pool
         and returns the attention output (`serve/pages.py`). A family
         brings its decoder (``embed`` / ``layer_params`` / ``layer`` /
-        ``next_logits`` / ``kv_geometry``) and a slots subclass with its
+        ``next_logits`` / ``kv_geometry`` / ``layer_kinds``) and a slots
+        subclass with its
         page arithmetic and chunk cache: an `EvaByteDecoder` (``family =
         "evabyte"``) is served by `serve.eva.EvaSlotDecoder` (window and
         summary pages; no speculative decoding, int8 pages or prefix
         reuse), a `PanguDecoder` (``"pangu_moe"``) by
         `serve.mla.MLASlotDecoder` (latent pages; no speculative decoding,
-        int8 pages or page handoff). The table is `slots_class`'s; a
+        int8 pages or page handoff), a `NemotronHDecoder`
+        (``"nemotron_h"``) by `serve.ssm.HybridSlotDecoder` (recurrent state
+        per slot beside pages; no prefix reuse, speculative decoding, int8
+        pages, page handoff or preemption). The table is `slots_class`'s; a
         ``family`` it does not name is an error.
     max_slots : int
         In-flight request capacity (static decode batch width).

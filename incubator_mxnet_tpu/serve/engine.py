@@ -59,8 +59,11 @@ scale per (page, head)); the pools travel through every program as ONE
 donated pytree argument, so a program has one signature and one jitted
 wrapper (`_observed`) whatever the format. A new family brings a decoder with
 ``embed`` / ``layer_params`` / ``layer`` / ``next_logits`` / ``kv_geometry``
-and a slots subclass with its page arithmetic (`pages_needed`, `pages_at`,
-`_table_width`, `_row_of`, `_count_rows`) and its chunk's cache access
+/ ``layer_kinds`` (what each layer keeps in a slot: ``"pages"``, ``"state"``
+or nothing — how many layers there are, and `kv_geometry()[0]` of them hold
+pages) and a slots subclass with its page arithmetic (`pages_needed`,
+`pages_at`, `_table_width`, `_row_of`, `_count_rows`) and its chunk's cache
+access
 (`_chunk_pages`, `_chunk_cache`): `serve/eva.py` is one.
 
 Two compiled program families in the base configuration:
@@ -598,6 +601,13 @@ class SlotDecoder:
         """Entries of a slot's row of the page table."""
         return self.view_tokens // self.page_tokens
 
+    def _quarter_and_whole_buckets(self):
+        """Two prefill programs, a quarter chunk and a whole one, in the
+        place of one a power of two: for a family whose prompts are many
+        chunks long, of which only the last is padded."""
+        return tuple(b for b in (self.prefill_chunk // 4, self.prefill_chunk)
+                     if b and b % self.page_tokens == 0)
+
     def pages_needed(self, n):
         """The most pages a request holds at once while it writes K/V for
         positions ``0 .. n-1``: the admission budget."""
@@ -750,7 +760,8 @@ class SlotDecoder:
                 "detail": {"kv_dtype": eng.kv_dtype,
                            "n_pages": eng.n_pages,
                            "pages_used": eng.allocator.used_pages,
-                           "prefix_cached_pages": cached},
+                           "prefix_cached_pages": cached,
+                           **eng._pool_detail()},
                 "derived": {"prefix_cache": int(cached * page_bytes)},
             }
 
@@ -762,6 +773,11 @@ class SlotDecoder:
 
         _hbm.register_owner(f"{self.census_name}.kv_pool", _pool_probe)
         _hbm.register_owner(f"{self.census_name}.params", _params_probe)
+
+    def _pool_detail(self):
+        """What a family adds to the KV-pool owner's census detail (the
+        state a slot keeps beside its pages: `serve/ssm.py`)."""
+        return {}
 
     def release(self):
         """Drop the device pool (shutdown); the next prefill reallocates."""
@@ -852,7 +868,7 @@ class SlotDecoder:
         weights is a copy the chip re-lays out every step: `PERF.md` §6,
         PR 32)."""
         x = dec.embed(params, tokens, pos)
-        for li in range(dec.kv_geometry()[0]):
+        for li in range(len(dec.layer_kinds())):
             x = dec.layer(li, dec.layer_params(params, li), x, pos, cache)
         return x.reshape(-1, x.shape[-1])
 

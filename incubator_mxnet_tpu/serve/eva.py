@@ -154,12 +154,7 @@ class EvaSlotDecoder(SlotDecoder):
             raise ValueError(
                 f"prefill_chunk ({self.prefill_chunk}) must divide the "
                 f"window ({self.window}): no chunk may straddle a roll")
-        # two prefill programs, not one a power of two: a prompt here is
-        # many chunks long and only its last is padded
-        quarter = self.prefill_chunk // 4
-        self.chunk_buckets = tuple(
-            b for b in (quarter, self.prefill_chunk)
-            if b and b % self.page_tokens == 0)
+        self.chunk_buckets = self._quarter_and_whole_buckets()
         self._held = onp.zeros(self.max_slots, onp.int64)    # pages a slot
         self._rolled = onp.zeros(self.max_slots, onp.int64)  # windows done
         self._roll_jit = None

@@ -56,7 +56,7 @@ from ..telemetry import registry, tracing
 from .engine import SlotDecoder, _j
 from .pages import PageCache, TokenCache
 
-__all__ = ["MLASlotDecoder"]
+__all__ = ["MLASlotDecoder", "ExpertStats", "ExpertStepCounts"]
 
 _NEG = -1.0e30
 #: rows of the slot's view a chunk attends at a time (up-projected)
@@ -80,11 +80,10 @@ MOE_HIT = registry.counter(
     "layers and fetched steps (what a step reads of the experts' weights)")
 
 
-class LatentCache(PageCache):
-    """A page's stored form for the one latent leaf ``"c"``, and what the
-    family's block asks of its cache beside `attend`: `valid` (the rows that
-    are real), `step` (the kind of step the cache serves: ``"decode"`` or
-    ``"chunk"``) and `count_experts`."""
+class ExpertStats:
+    """What a block with an expert layer asks of its cache beside the
+    attention: `valid` (the rows that are real), `step` (the kind of step
+    the cache serves: ``"decode"`` or ``"chunk"``) and `count_experts`."""
 
     valid = None
     step = None
@@ -94,6 +93,10 @@ class LatentCache(PageCache):
         if self.expert_stats is None:
             self.expert_stats = {}
         self.expert_stats[li] = stats
+
+
+class LatentCache(ExpertStats, PageCache):
+    """A page's stored form for the one latent leaf ``"c"``."""
 
     def _fit(self, rows):
         """Latent rows ``(..., width)`` as stored: ``(..., W)``, zeros
@@ -228,80 +231,10 @@ class _ChunkCache(LatentCache):
         return jnp.transpose(acc / l[..., None], (1, 0, 2))
 
 
-class MLASlotDecoder(SlotDecoder):
-    """Paged slot decoder over a `PanguDecoder` (see the module docstring).
-    Parameters as `SlotDecoder`'s; `max_len` defaults to the model's
-    ``max_position_embeddings``."""
-
-    #: latent pages are not moved between engines (prefill-only handoff,
-    #: adoption): the scheduler refuses both
-    page_handoff = False
-
-    def __init__(self, source, max_slots=8, max_len=None, page_tokens=None,
-                 prefill_chunk=None, n_pages=None, kv_dtype=None,
-                 prefix_reuse=None, do_sample=False, top_k=None,
-                 spec_k=None, draft=None):
-        from ..util import env_int
-
-        def refuse(what, why):
-            raise NotImplementedError(
-                f"the pangu_moe family is not served with {what}: {why}")
-
-        if spec_k is None:
-            spec_k = env_int("MXNET_SERVE_SPEC_K", 0)
-        if spec_k or draft is not None:
-            refuse("speculative decoding (spec_k > 0, draft)",
-                   "verify and draft programs exist for the GPT block only, "
-                   "and the release's multi-token-prediction module is not "
-                   "held (ROADMAP R7)")
-        if kv_dtype is None:
-            kv_dtype = os.environ.get("MXNET_SERVE_KV_DTYPE", "fp")
-        if kv_dtype != "fp":
-            refuse(f"kv_dtype={kv_dtype!r}",
-                   "what a per-page int8 scale does to a row that is a "
-                   "normed latent beside a rotated key is not worked out")
-        super().__init__(source, max_slots=max_slots, max_len=max_len,
-                         page_tokens=page_tokens, prefill_chunk=prefill_chunk,
-                         n_pages=n_pages, kv_dtype="fp",
-                         prefix_reuse=prefix_reuse, do_sample=do_sample,
-                         top_k=top_k, spec_k=0)
-        # two prefill programs, not one a power of two (as `serve/eva.py`):
-        # a prompt is many chunks long and only its last is padded
-        quarter = self.prefill_chunk // 4
-        self.chunk_buckets = tuple(
-            b for b in (quarter, self.prefill_chunk)
-            if b and b % self.page_tokens == 0)
-        self._expert_layers = self._dec.expert_layers
-        self._experts_per_tok = self._dec.config.num_experts_per_tok
-        # a step's (held pairs, held experts hit) an expert layer, and
-        # the rows that were routed
-        self.step_extra = 2 * self._expert_layers + 1
-        ref = weakref.ref(self)
-        registry.register_pull_gauge(
-            "mx_serve_pages_in_use",
-            lambda: None if ref() is None else ref().allocator.used_pages,
-            "pool pages the slots hold, by kind (latent: the MLA family)",
-            labels={"kind": "latent"})
-
-    def _resolve_decoder(self, source):
-        if not isinstance(source, PanguDecoder):
-            raise TypeError("MLASlotDecoder needs a PanguDecoder, got "
-                            f"{type(source).__name__}")
-        return source
-
-    # -- the cache objects ----------------------------------------------------
-
-    def _token_cache(self, pools, table, pos, active):
-        return _TokenCache(self, pools, table, *self._row_of(pos), active)
-
-    def _chunk_cache(self, pools, pages, t_start, t_len):
-        return _ChunkCache(self, pools, *pages, t_start, t_len)
-
-    def _count_rows(self, at):
-        DECODE_ROWS.inc(int((at + 1).sum()))
-        return super()._count_rows(at)
-
-    # -- the expert layers' counts, in the tokens' fetch ----------------------
+class ExpertStepCounts:
+    """For a slots class whose decoder has expert layers: their counts come
+    home in the tokens' fetch. The class sets `_expert_layers`,
+    `_experts_per_tok` and ``step_extra = 2 * _expert_layers + 1``."""
 
     def _step_out(self, tokens, cache):
         jnp = _j().numpy
@@ -338,6 +271,75 @@ class MLASlotDecoder(SlotDecoder):
 
     def fetch_first(self, out):
         return int(self._take_extra(out)[0])
+
+
+class MLASlotDecoder(ExpertStepCounts, SlotDecoder):
+    """Paged slot decoder over a `PanguDecoder` (see the module docstring).
+    Parameters as `SlotDecoder`'s; `max_len` defaults to the model's
+    ``max_position_embeddings``."""
+
+    #: latent pages are not moved between engines (prefill-only handoff,
+    #: adoption): the scheduler refuses both
+    page_handoff = False
+
+    def __init__(self, source, max_slots=8, max_len=None, page_tokens=None,
+                 prefill_chunk=None, n_pages=None, kv_dtype=None,
+                 prefix_reuse=None, do_sample=False, top_k=None,
+                 spec_k=None, draft=None):
+        from ..util import env_int
+
+        def refuse(what, why):
+            raise NotImplementedError(
+                f"the pangu_moe family is not served with {what}: {why}")
+
+        if spec_k is None:
+            spec_k = env_int("MXNET_SERVE_SPEC_K", 0)
+        if spec_k or draft is not None:
+            refuse("speculative decoding (spec_k > 0, draft)",
+                   "verify and draft programs exist for the GPT block only, "
+                   "and the release's multi-token-prediction module is not "
+                   "held (ROADMAP R7)")
+        if kv_dtype is None:
+            kv_dtype = os.environ.get("MXNET_SERVE_KV_DTYPE", "fp")
+        if kv_dtype != "fp":
+            refuse(f"kv_dtype={kv_dtype!r}",
+                   "what a per-page int8 scale does to a row that is a "
+                   "normed latent beside a rotated key is not worked out")
+        super().__init__(source, max_slots=max_slots, max_len=max_len,
+                         page_tokens=page_tokens, prefill_chunk=prefill_chunk,
+                         n_pages=n_pages, kv_dtype="fp",
+                         prefix_reuse=prefix_reuse, do_sample=do_sample,
+                         top_k=top_k, spec_k=0)
+        self.chunk_buckets = self._quarter_and_whole_buckets()
+        self._expert_layers = self._dec.expert_layers
+        self._experts_per_tok = self._dec.config.num_experts_per_tok
+        # a step's (held pairs, held experts hit) an expert layer, and
+        # the rows that were routed
+        self.step_extra = 2 * self._expert_layers + 1
+        ref = weakref.ref(self)
+        registry.register_pull_gauge(
+            "mx_serve_pages_in_use",
+            lambda: None if ref() is None else ref().allocator.used_pages,
+            "pool pages the slots hold, by kind (latent: the MLA family)",
+            labels={"kind": "latent"})
+
+    def _resolve_decoder(self, source):
+        if not isinstance(source, PanguDecoder):
+            raise TypeError("MLASlotDecoder needs a PanguDecoder, got "
+                            f"{type(source).__name__}")
+        return source
+
+    # -- the cache objects ----------------------------------------------------
+
+    def _token_cache(self, pools, table, pos, active):
+        return _TokenCache(self, pools, table, *self._row_of(pos), active)
+
+    def _chunk_cache(self, pools, pages, t_start, t_len):
+        return _ChunkCache(self, pools, *pages, t_start, t_len)
+
+    def _count_rows(self, at):
+        DECODE_ROWS.inc(int((at + 1).sum()))
+        return super()._count_rows(at)
 
     # -- debug / tests --------------------------------------------------------
 

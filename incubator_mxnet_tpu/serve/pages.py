@@ -12,7 +12,12 @@ a layout that shards heads shards them all alike (`serve/sharded.py`).
 A family whose geometry says ``"latent"`` in the place of the heads (MLA:
 `models/pangu.py`) has ONE leaf a layer, ``{"c": leaves}`` of ``(n_pages,
 page_tokens, W)`` rows shared by every head (`ops.paged_attention`, "latent
-pages"); its cache objects are `serve/mla.py`'s.
+pages"); its cache objects are `serve/mla.py`'s. A family whose layers differ
+in kind (`decoder.layer_kinds()`) has a page leaf for each layer that keeps
+pages — `geometry[0]` of them, not one a layer — and may add leaves that are
+not pages at all to the same pytree: `serve/ssm.py`'s recurrent state,
+``"ssm"`` / ``"conv"``, indexed by slot, which `PageCache` carries through a
+program beside the page leaves and never reads.
 
 **A cache-access object** is what a serving program hands a decoder's block
 (`GPTDecoder.layer`, `EvaByteDecoder.layer`) in place of a cache::

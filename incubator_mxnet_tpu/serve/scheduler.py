@@ -1094,6 +1094,9 @@ class Scheduler:
 
         Returns the `Request` with ``state="preempted"`` (its trace is
         closed; the caller owns re-queueing the remaining work)."""
+        refusal = getattr(self.slots, "preempt_refusal", None)
+        if refusal:
+            raise NotImplementedError(refusal)
         if now is None:
             now = time.monotonic()
         # the tokens of the step in flight are the request's (one of them

@@ -296,6 +296,12 @@ class ShardedSlotDecoder(SlotDecoder):
                 "has no rule for a latent pool shared by all heads nor for "
                 "experts held a share a chip, and the exchange between the "
                 "shares is not written (ROADMAP R1)")
+        if getattr(source, "family", None) == "nemotron_h":
+            raise NotImplementedError(
+                "the nemotron_h family is not served sharded: `ServeLayout` "
+                "has no rule for state leaves indexed by slot nor for "
+                "experts held a share a chip, and the exchange between the "
+                "shares is not written (ROADMAP R1, R4)")
         if layout is None:
             if not hasattr(mesh, "shape") or not hasattr(mesh, "devices"):
                 mesh = serve_mesh(mesh)

@@ -528,7 +528,9 @@ def step_records(since=None, until=None):
     iteration's end), ``pages_live`` and ``pages_view`` (of the decode
     launch, else 0: KV pages under the decoding slots' positions, and
     ``max_slots x pages_per_slot`` — what decode's attention reads,
-    against what a gathered view of every slot holds). The ring is the
+    against what a gathered view of every slot holds); a family may add
+    counts of its own (``moe_*``: `serve/mla.py`; ``state_resets``:
+    `serve/ssm.py`). The ring is the
     module's, not the engine's: it
     outlives shutdown and deletion of whatever wrote it, holds the newest
     `STEP_RING_CAPACITY` records and drops the oldest."""

@@ -172,6 +172,21 @@ def waitall_between_modules():
     mx.waitall()
 
 
+@pytest.fixture(autouse=True, scope="module")
+def own_serve_runner_between_modules():
+    """`chipbench/control_eva.py` and `control_pangu.py` put their family's
+    runner in `chipbench.runners.serve`'s place and leave it there: a module
+    that runs after them in the same process (`control.py`'s own tests)
+    would be handed another family's runner. Put the real one back."""
+    import sys
+
+    yield
+    pkg, real = (sys.modules.get("chipbench.runners"),
+                 sys.modules.get("chipbench.runners.serve"))
+    if pkg is not None and real is not None:
+        pkg.serve = real
+
+
 @pytest.fixture(autouse=True)
 def seed_rng():
     import numpy as onp

@@ -18,7 +18,7 @@ import pytest  # noqa: E402
 
 from incubator_mxnet_tpu.ops import dropout as dropout_k  # noqa: E402
 from incubator_mxnet_tpu.ops import (fused_block, layer_norm,  # noqa: E402
-                                     moe, paged_attention)
+                                     moe, paged_attention, ssm)
 from incubator_mxnet_tpu.ops.flash_attention import (  # noqa: E402
     flash_attention)
 
@@ -183,11 +183,11 @@ def test_mla_decode_compiles_for_v5e(chip):
     ids=["decode-64", "decode-128", "chunk-512"])
 def test_moe_experts_compile_for_v5e(chip, monkeypatch, tokens, tile, step):
     """The expert layer at the published widths (7,680 -> 2,048 -> 7,680,
-    top-8, 16 experts held): routing's layout, the two launches of the
+    top-8, 16 of 256 experts held): routing's layout, the two launches of the
     grouped product, the weighted gather back. The kernel's name is the
     step's, whatever tile the step's batch gives it."""
     C, F, E, K = 7680, 2048, 16, 8
-    assert moe._tile_rows(tokens * K) == tile
+    assert moe._tile_rows(tokens * K, 256) == tile
     # `held_experts` asks `_dispatch.interpret_default()`, which sees the CPU
     # here: the kernels are to be compiled for the chip
     monkeypatch.setattr(moe._dispatch, "interpret_default", lambda: False)
@@ -198,7 +198,7 @@ def test_moe_experts_compile_for_v5e(chip, monkeypatch, tokens, tile, step):
     compiled = jax.jit(
         lambda u, ids, w, wg, wu, wd: moe.held_experts(
             u, ids, w, (wg, wu, wd), (0, E), None, step=step,
-            impl="pallas"),
+            impl="pallas", routed=256),
     ).lower(arg((tokens, C)), arg((tokens, K), jnp.int32),
             arg((tokens, K), F32), arg((E, C, F)), arg((E, C, F)),
             arg((E, F, C))).compile()
@@ -207,3 +207,74 @@ def test_moe_experts_compile_for_v5e(chip, monkeypatch, tokens, tile, step):
     assert moe.KERNEL_NAMES[step] in text
     other, = set(moe.KERNEL_NAMES.values()) - {moe.KERNEL_NAMES[step]}
     assert other not in text
+
+
+def test_ssm_decode_compiles_for_v5e_and_updates_the_state_in_place(chip):
+    """`mx_ssm_decode` at the Nemotron-H cell's sizes: 64 slots of 128 heads
+    of 64 x 128 float32 state (4 MiB a slot a layer, one block of the
+    kernel, stored two heads to a row of 128 lanes), 8 groups. The state leaf, donated, is aliased to the kernel's
+    output: nothing of its 256 MiB is copied."""
+    S, H, P, N, G = 64, 128, 64, 128, 8
+
+    def arg(shape, dtype=F32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    compiled = jax.jit(
+        lambda st, x, b, c, dt, a, d, act: ssm._pallas_decode(
+            st, x, b, c, dt, a, d, act, False), donate_argnums=(0,)).lower(
+        arg((S,) + ssm.state_store_shape(H, P, N, G)), arg((S, H, P)),
+        arg((S, G, N)), arg((S, G, N)),
+        arg((S, H)), arg((H,)), arg((H,)), arg((S,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert ssm.KERNEL_NAME in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == S * H * P * N * 4
+    assert mem.temp_size_in_bytes < 32 * 2 ** 20
+
+
+def test_paged_decode_grouped_heads_compile_for_v5e(chip):
+    """`mx_paged_decode` with 32 query heads over 2 stored heads of 128
+    (the Nemotron-H cell's one attention block: 64 slots x 192 pages of 16
+    tokens, bfloat16): one kernel, a page fetched once for its 16 query
+    heads."""
+    S, P, pt, hq, hk, d = 64, 192, 16, 32, 2, 128
+
+    def arg(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    pool = arg((S * P + 1, hk) + paged_attention.page_store_shape(pt, d))
+    compiled = jax.jit(
+        lambda q, k, v, t, n: paged_attention._pallas_paged_decode(
+            q, k, v, t, n, False)).lower(
+        arg((S, hq, d)), pool, pool, arg((S, P), jnp.int32),
+        arg((S,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "mx_paged_decode" in text
+
+
+@pytest.mark.parametrize("tokens,tile,step", [
+    (64, 16, "decode"), (128, 128, "chunk"), (512, 128, "chunk")],
+    ids=["decode-64", "chunk-128", "chunk-512"])
+def test_ungated_experts_compile_for_v5e(chip, monkeypatch, tokens, tile,
+                                         step):
+    """The latent expert layer at the published widths (1,024 -> 2,688 ->
+    1,024, top-22, 128 of 512 experts held, ungated relu^2): two launches
+    under the step's name, at the tile the rows an expert can expect give."""
+    C, F, E, K = 1024, 2688, 128, 22
+    assert moe._tile_rows(tokens * K, 512) == tile
+    monkeypatch.setattr(moe._dispatch, "interpret_default", lambda: False)
+
+    def arg(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    compiled = jax.jit(
+        lambda u, ids, w, w1, w2: moe.held_experts(
+            u, ids, w, (w1, w2), (0, E), None, step=step, impl="pallas",
+            routed=512),
+    ).lower(arg((tokens, C)), arg((tokens, K), jnp.int32),
+            arg((tokens, K), F32), arg((E, C, F)), arg((E, F, C))).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert moe.KERNEL_NAMES[step] in text

@@ -8,7 +8,7 @@ import pytest
 
 from incubator_mxnet_tpu.ops import dropout as dropout_k
 from incubator_mxnet_tpu.ops import (fused_block, layer_norm, moe,
-                                     paged_attention)
+                                     paged_attention, ssm)
 from incubator_mxnet_tpu.ops.flash_attention import flash_attention
 
 ROWS, FEAT = 256, 256
@@ -31,6 +31,12 @@ MOE_TILES = jax.ShapeDtypeStruct((3,), jnp.int32)
 MOE_N = jax.ShapeDtypeStruct((), jnp.int32)
 MOE_IN = jax.ShapeDtypeStruct((6, 128, 256), jnp.float32)
 MOE_OUT = jax.ShapeDtypeStruct((6, 256, 128), jnp.float32)
+
+
+# a state-space decode step: 4 slots of 8 heads of 16 x 128 state, 2 groups
+SSM = tuple(jax.ShapeDtypeStruct(s, jnp.float32) for s in (
+    (4,) + ssm.state_store_shape(8, 16, 128, 2), (4, 8, 16), (4, 2, 128), (4, 2, 128), (4, 8), (8,),
+    (8,))) + (jax.ShapeDtypeStruct((4,), jnp.bool_),)
 
 
 def _moe(tile, step):
@@ -60,6 +66,7 @@ OPS = {
         lambda q, pool, t, n: paged_attention._pallas_mla_decode(
             q, pool, t, n, 32, 0.2, False),
         (), (MLA_Q, MLA_POOL, TABLE, LENGTHS)),
+    "ssm_decode": (lambda *a: ssm._pallas_decode(*a, False), (), SSM),
     "moe_experts": _moe(16, "decode"),
     "moe_chunk_experts": _moe(128, "chunk"),
 }
@@ -70,7 +77,7 @@ KERNELS = {
     "mx_gelu_dropout": "gelu_dropout", "mx_dropout": "dropout",
     "mx_flash_fwd": "flash", "mx_flash_dq": "flash", "mx_flash_dkv": "flash",
     "mx_paged_decode": "paged_decode", "mx_mla_decode": "mla_decode",
-    "mx_moe_experts": "moe_experts",
+    "mx_ssm_decode": "ssm_decode", "mx_moe_experts": "moe_experts",
     "mx_moe_chunk_experts": "moe_chunk_experts",
 }
 
@@ -144,4 +151,8 @@ def test_every_pallas_call_of_the_main_path_is_named():
     assert len(re.findall(r"pl\.pallas_call\(", src)) == 1
     assert "name=name," in src and "KERNEL_NAMES[step]" in src
     names += moe.KERNEL_NAMES.values()
+    src = inspect.getsource(ssm)
+    assert len(re.findall(r"pl\.pallas_call\(", src)) == 1
+    assert "name=KERNEL_NAME," in src
+    names.append(ssm.KERNEL_NAME)
     assert sorted(names) == sorted(KERNELS)

@@ -306,8 +306,11 @@ def test_moe_kernel_is_its_xla_expression_and_a_plain_loop(t, top_k):
     ws = tuple(jnp.asarray(rng.normal(size=s) * 0.2, jnp.float32)
                for s in ((6, C, F), (6, C, F), (6, F, C)))
     valid = jnp.arange(t) < t - 2
-    ya, sa = moe.held_experts(u, ids, w, ws, held, valid, impl="xla")
-    yb, sb = moe.held_experts(u, ids, w, ws, held, valid, impl="pallas")
+    assert moe._tile_rows(t * top_k, E) == (16 if t == 12 else 128)
+    ya, sa = moe.held_experts(u, ids, w, ws, held, valid, impl="xla",
+                              routed=E)
+    yb, sb = moe.held_experts(u, ids, w, ws, held, valid, impl="pallas",
+                              routed=E)
     want = _plain_experts(u, onp.asarray(ids), onp.asarray(w), ws, held,
                           onp.asarray(valid))
     onp.testing.assert_allclose(onp.asarray(ya), want, atol=1e-4)

@@ -39,7 +39,7 @@ import sys
 import tempfile
 
 # the kernels that can be named (`mx_<name>` in a trace)
-KERNELS = ("mla_decode", "paged_decode", "moe_experts")
+KERNELS = ("mla_decode", "paged_decode", "moe_experts", "ssm_decode")
 
 _BUNDLE = re.compile(
     r"^\s*(0x[0-9a-f]+|\d+)\s+(LH|LB|LE|PB|PF|CT)?:?\s*(>*)\s*\{(.*)$")
@@ -174,7 +174,7 @@ def _compile(kernel, dump_dir):
     import jax.numpy as jnp
     from jax.experimental import topologies
 
-    from incubator_mxnet_tpu.ops import moe, paged_attention
+    from incubator_mxnet_tpu.ops import moe, paged_attention, ssm
 
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu",
@@ -198,6 +198,14 @@ def _compile(kernel, dump_dir):
         pool = arg((513, 25) + paged_attention.page_store_shape(16, 64), f32)
         args = (arg((8, 25, 64), f32), pool, pool, arg((8, 64), i32),
                 arg((8,), i32))
+    elif kernel == "ssm_decode":    # nemotron3super.turns: 64 slots' state
+        def fn(state, x, b, c, dt, a, d, active):
+            return ssm._pallas_decode(state, x, b, c, dt, a, d, active, False)
+        args = (arg((64,) + ssm.state_store_shape(128, 64, 128, 8), f32),
+                arg((64, 128, 64), f32),
+                arg((64, 8, 128), f32), arg((64, 8, 128), f32),
+                arg((64, 128), f32), arg((128,), f32), arg((128,), f32),
+                arg((64,), jnp.bool_))
     else:                           # pangu718b.think's decode step: 64 rows
         # `held_experts` asks the backend, which is the CPU here
         moe._dispatch.interpret_default = lambda: False
