@@ -29,17 +29,40 @@ observe (`_dispatch.use_pallas()` and the pool's dtype), never from a
 failure or a knob, and counted as
 ``mx_kernel_dispatch_total{op="paged_decode_attention",impl=}``:
 
-- **pallas** (`mx_paged_decode`; one TPU device, float pools): lengths and
-  a work list made from the table (`_work_list`) are scalar-prefetched and
-  the pools stay in HBM. The grid has one step per LIVE block of
-  `_BLOCK_PAGES` pages, slot after slot (its length is read on the
-  device), and each step has that many page-sized blocks of K and of V
-  brought to VMEM by `BlockSpec`s whose index maps read the work list,
-  double-buffered against the step before. A page past a slot's length is
-  not fetched, nor the trash page, nor a free slot's row, and takes no
-  part in the arithmetic. Online softmax in float32, one page at a time;
-  products are exact float32 (the VPU multiplies, no MXU pass narrows
-  them).
+- **pallas** (`mx_paged_decode`; one TPU device, float pools). The grid has
+  one step per LIVE block of pages, slot after slot (`_block_list`: made from
+  the lengths, scalar-prefetched; `_block_pages`: 32 pages a block at most
+  and 1 MB of K, read from the page's bytes and the table's width). Each
+  pool is ONE operand left in HBM and the table is scalar-prefetched flat: a
+  step starts one copy a live page of a LATER step's block into one of
+  `_BUFFERS` ``(H, G rows, lanes)`` VMEM blocks of K and of V, then waits for
+  its own, started that many steps less one earlier. No copy for a page past
+  a slot's length, so none for the trash page a dead entry names nor for a
+  free slot's row; the copies carry no range checks
+  (`disable_bounds_checks`): the table is clipped to the pool before the
+  call. Rows never fetched hold zeros or an earlier page (every block is
+  zeroed at the call's first step) and the mask gives them no weight. The
+  body is written once a block, for the compiler orders loads after every
+  copy into the same array. Which unit multiplies is read from the shape
+  (`_on_mxu`; counted as ``...{op="paged_decode_products",impl="mxu"|
+  "vpu"}``), never from a flag or a model's name:
+
+  - a bfloat16 pool whose head fills whole 128-lane tiles: **two MXU
+    products over the block**, per stored head ``(rep, d) x (G pt, d)^T``
+    for the scores and ``(rep, G pt) x (G pt, d)`` for the sum, from the
+    bfloat16 operands as they are stored into float32 (products of
+    bfloat16 pairs summed in float32: what the VPU computed before), the
+    scores scaled by ``1 / sqrt(d)`` AFTER the product, in float32; mask,
+    max, `exp` and sum over ``(H, rep, G pt)`` with the rows in the lanes.
+    The weights enter the second product ROUNDED TO THE POOL'S DTYPE
+    (``p.astype(v.dtype)``), as the XLA expression below and
+    `mx_mla_decode` hand them over: the one rounding the VPU's body does
+    not make, inside bfloat16, the precision such a pool states. One query
+    row a head (``rep == 1``) is the same code: the array holds a head's
+    128 x 128 tile of K, then of V, and one row streams through it;
+  - narrower heads packed side by side in a row, and float32 pools: **a VPU
+    pass a live page** over the same fetched block, exact float32 products
+    (no MXU pass narrows them), online softmax a page at a time.
 - **xla** (CPU, a multi-device mesh — GSPMD cannot partition a Mosaic
   kernel — and int8 pools, which dequantise by a per-(page, head) scale):
   the expression the engine has always had — gather every slot's whole
@@ -53,27 +76,20 @@ of 576 would be padded to 640 lanes by the chip's tiling anyway, so a row
 takes 1,280 B either way, and stored so a page is one contiguous block and a
 row's score is one product). The queries come *absorbed*, ``(S, H, W)``, and
 what goes back is the weighted sum of ``c_kv``, ``(S, H, rank)``. The kernel
-``mx_mla_decode`` has a grid of its own (`_mla_work_list`), a step a live
-block of `_MLA_BLOCK_PAGES` pages, slot after slot, and fetches a block's
-pages ITSELF: the pool is one operand left in HBM, the table is
-scalar-prefetched flat, and a step starts one copy a live page of a LATER
-step's block into one of `_MLA_BUFFERS` ``(G pt, W)`` VMEM blocks (none for
-a page past the slot's length), then waits for its own, started that many
-steps less one earlier. (An operand a page, each pipelined through its own
-`BlockSpec`, cost a grid step more scalar bookkeeping than the products
-took: `tools/kernel_schedule.py`.) The step's body is written once a block,
-for the compiler orders loads after every copy into the same array; the
-copies start after the first product in program order and their address
-arithmetic lies under the products. They carry no range checks
-(`disable_bounds_checks` on this one call): the table is clipped to the pool
-before the call. Rows never fetched hold zeros or an earlier page (every
-block is zeroed at the call's first step), and the mask gives them no
-weight. A step is two MXU products, ``(H, W) x (W, rows)`` for the scores
-and ``(H, rows) x (rows, rank)`` for the sum, bfloat16 into float32, with
-the online softmax between them in float32; a slot's last block divides and
-stores the output row. At 128 heads that is 2 x 128 x (576 + 512) operations
-a row of 1,152 useful bytes, 242 op/B: on the v5e's ridge. The XLA
-expression (the CPU, a mesh) gathers every slot's view.
+``mx_mla_decode`` has the grid and the fetch of `mx_paged_decode` (one work
+list, `_block_list`; one way to start and to wait for a block's copies,
+`_start_pages` / `_wait_pages`): a step a live block of 32 pages, the pool
+one operand left in HBM, one copy a live page into one of `_BUFFERS`
+``(G pt, W)`` VMEM blocks two steps before it is read. (An operand a page,
+each pipelined through its own `BlockSpec`, cost a grid step more scalar
+bookkeeping than the products took: `tools/kernel_schedule.py`.) The copies
+start after the first product in program order and their address arithmetic
+lies under the products. A step is two MXU products, ``(H, W) x (W, rows)``
+for the scores and ``(H, rows) x (rows, rank)`` for the sum, bfloat16 into
+float32, with the online softmax between them in float32; a slot's last
+block divides and stores the output row. At 128 heads that is 2 x 128 x (576
++ 512) operations a row of 1,152 useful bytes, 242 op/B: on the v5e's ridge.
+The XLA expression (the CPU, a mesh) gathers every slot's view.
 """
 from __future__ import annotations
 
@@ -93,7 +109,6 @@ __all__ = ["paged_decode_attention", "takes_kernel", "page_store_shape",
 
 NEG_INF = -1.0e30   # finite stand-in for -inf: exp() and max() stay NaN-free
 LANES = 128
-_BLOCK_PAGES = 8    # pages of K and of V per grid step, at most
 
 
 def takes_kernel(pool_dtype):
@@ -162,20 +177,150 @@ def _xla_paged_decode(q, k_pool, v_pool, table, lengths, k_scale, v_scale):
 
 
 # ---------------------------------------------------------------------------
+# the kernels' grid and fetch (both kernels of this file)
+# ---------------------------------------------------------------------------
+
+_BLOCK_PAGES = 32       # pages per grid step, at most (512 rows of 16) ...
+_BLOCK_BYTES = 1 << 20  # ... and bytes of them (of K; V's are as many more)
+# VMEM blocks a kernel fetches into, a block's copies started one fewer
+# steps before it is read: from the first copy's start to the last one's end
+# a block of 32 latent pages takes 1.3 us on a v5e, a grid step's products as
+# long, so one step ahead the wait still shows (PERF.md, PR 34)
+_BUFFERS = 3
+
+
+def _block_pages(table_pages, page_bytes, at_most=_BLOCK_PAGES):
+    """Pages a grid step: read from the page's bytes and the table's width,
+    the most that divide the table, fill no more than `_BLOCK_BYTES` and
+    number no more than `at_most`. Small pages go 32 to a block (a step's
+    fixed ~0.35 us would outweigh the work of fewer), 128 KB pages 8."""
+    cap = max(1, min(at_most, _BLOCK_BYTES // page_bytes))
+    return max(g for g in range(1, cap + 1) if table_pages % g == 0)
+
+
+def _block_list(lengths, page_tokens, block_pages, n_blocks):
+    """A kernel's grid, made from the lengths (a few scalar-sized XLA ops,
+    the same for every layer of a step): one grid step per LIVE block of
+    `block_pages` pages, slot after slot — ``n`` of them (at least one),
+    then ``slot[i]``, ``block[i]`` and ``pages[i]``, the slot's live pages
+    from the block's first on (more than `block_pages` where the slot goes
+    on). The kernel reads a block's pages from the table itself."""
+    S, G, NB = lengths.shape[0], block_pages, n_blocks
+    n_pages = -(-lengths // page_tokens)                          # (S,)
+    live_block = (jnp.arange(NB)[None, :] * G < n_pages[:, None]).reshape(-1)
+    n = jnp.sum(live_block, dtype=jnp.int32)
+    flat, = jnp.nonzero(live_block, size=S * NB, fill_value=0)
+    flat = flat.astype(jnp.int32)
+    slot, block = flat // NB, flat % NB
+    return jnp.maximum(n, 1), slot, block, n_pages[slot] - block * G
+
+
+def _prefetched(table, lengths, n_pages, page_tokens, block_pages):
+    """What both kernels are scalar-prefetched with: the lengths held to the
+    view, the grid (`_block_list`) and the table, flat. No address can leave
+    the pool whatever the table holds: it is clipped to the pool here,
+    because the kernels' copies carry no range checks of their own
+    (`disable_bounds_checks`)."""
+    P = table.shape[1]
+    table = jnp.clip(table.astype(jnp.int32), 0, n_pages - 1)
+    lengths = jnp.clip(lengths.astype(jnp.int32), 0, P * page_tokens)
+    n, slot, block, pages = _block_list(lengths, page_tokens, block_pages,
+                                        P // block_pages)
+    return n, (lengths, slot, block, pages, table.reshape(-1))
+
+
+def _start_pages(work, step, copies):
+    """Start the copies of grid step `step`'s block, a live page at a time:
+    none for a page past the slot's length (so none for the trash page a
+    dead table entry names), none for a step past the last. `work` is the
+    scalar-prefetched ``(slot_ref, block_ref, pages_ref, table_ref)``, the
+    table's and a block's width in pages and the grid's steps;
+    ``copies(page, g)`` gives the copies that bring pool page `page` to the
+    block's `g`-th place."""
+    slot_ref, block_ref, pages_ref, table_ref, P, G, n = work
+    at = jnp.minimum(step, n - 1)
+    pages = jnp.where(step < n, pages_ref[at], 0)
+    first = slot_ref[at] * P + block_ref[at] * G    # in the flat table
+    for g in range(G):
+        @pl.when(g < pages)
+        def _(g=g):
+            for copy in copies(table_ref[first + g], g):
+                copy.start()
+
+
+def _wait_pages(pages, block_pages, whole, page):
+    """Wait for the copies `_start_pages` started for a block with `pages`
+    live pages: `whole` are copies the size of the whole block (one wait
+    for all its bytes), `page` copies the size of one page of it."""
+    @pl.when(pages >= block_pages)
+    def _():
+        for copy in whole:
+            copy.wait()
+
+    def one(_, carry):
+        for copy in page:
+            copy.wait()
+        return carry
+
+    jax.lax.fori_loop(0, jnp.where(pages < block_pages, pages, 0), one, None)
+
+
+# ---------------------------------------------------------------------------
 # the kernel
 # ---------------------------------------------------------------------------
 
-def _kernel(len_ref, slot_ref, block_ref, _page_ref, q_ref, *refs,
-            block_pages, head_dim, sm_scale):
+def _on_mxu(q, k_pool):
+    """True where the kernel's two products go to the MXU: a head fills
+    whole 128-lane tiles and the pool is bfloat16, so a block of K (then of
+    V) is the array's operand as it is stored. Narrower heads, packed side
+    by side in a row, and float32 pools keep the VPU's exact products."""
+    d = q.shape[-1]
+    return (k_pool.shape[-1] == d and d % LANES == 0
+            and k_pool.dtype == jnp.bfloat16)
+
+
+def _kernel(len_ref, slot_ref, block_ref, pages_ref, table_ref, q_ref,
+            k_pool, v_pool, o_ref, *scratch, block_pages, table_pages,
+            head_dim, sm_scale, on_mxu):
     G, d = block_pages, head_dim
-    k_refs, v_refs = refs[:G], refs[G:2 * G]
-    o_ref, m_scr, l_scr, acc_scr = refs[2 * G:]
-    i = pl.program_id(0)
-    j = block_ref[i]                    # which block of its slot's pages
-    H, rows, lanes = k_refs[0].shape
+    k_bufs, v_bufs = scratch[:_BUFFERS], scratch[_BUFFERS:2 * _BUFFERS]
+    sems, m_scr, l_scr, acc_scr = scratch[2 * _BUFFERS:]
+    ahead = _BUFFERS - 1    # a block's copies start this many steps early
+    H, block_rows, lanes = k_bufs[0].shape
+    rows = block_rows // G              # of one page
     rep = q_ref.shape[1]                # query heads a stored head
     per_row = lanes // d                # tokens side by side in a row
+    i = pl.program_id(0)
+    j = block_ref[i]                    # which block of its slot's pages
     length = len_ref[slot_ref[i]]
+    work = (slot_ref, block_ref, pages_ref, table_ref, table_pages, G,
+            pl.num_programs(0))
+
+    def start(step, b):
+        def copies(page, g):
+            at = (slice(None), pl.ds(g * rows, rows))
+            return [pltpu.make_async_copy(pool.at[page], bufs[b].at[at],
+                                          sems.at[b])
+                    for pool, bufs in ((k_pool, k_bufs), (v_pool, v_bufs))]
+        _start_pages(work, step, copies)
+
+    def wait(b):
+        whole, page = [], []
+        for buf in (k_bufs[b], v_bufs[b]):
+            first = buf.at[:, pl.ds(0, rows)]
+            whole.append(pltpu.make_async_copy(buf, buf, sems.at[b]))
+            page.append(pltpu.make_async_copy(first, first, sems.at[b]))
+        _wait_pages(pages_ref[i], G, whole, page)
+
+    # Rows that are never fetched (past a slot's length) must hold numbers:
+    # their weight is exp(-1e30) = 0, and 0 x NaN would poison the sum. So
+    # every block starts as zeros and only real pages are ever written
+    @pl.when(i == 0)
+    def _():
+        for buf in k_bufs + v_bufs:
+            buf[...] = jnp.zeros_like(buf)
+        for step in range(ahead):
+            start(step, step)
 
     @pl.when(j == 0)
     def _():
@@ -183,85 +328,96 @@ def _kernel(len_ref, slot_ref, block_ref, _page_ref, q_ref, *refs,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # the query, once per token of a row: (H, rep, lanes)
-    q = q_ref[...].astype(jnp.float32) * sm_scale
-    row = jax.lax.broadcasted_iota(jnp.int32, (H, rows, 1), 1)
-    if per_row > 1:                     # the lanes of a row's c-th token
-        lane = jax.lax.broadcasted_iota(jnp.int32, (H, rows, lanes), 2)
-        seg = [(lane >= c * d) & (lane < (c + 1) * d)
-               for c in range(per_row)]
+    def products(kb, vb, fetch_next):
+        """The block on the MXU: per stored head, its `rep` query rows
+        against the block's rows, the rows in the lanes through mask, max,
+        `exp` and sum, and the weights against V. bfloat16 pairs summed in
+        float32; the scores are scaled after the product, in float32."""
+        s = jax.lax.dot_general(q_ref[...], kb[...],
+                                (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * sm_scale
+        fetch_next()        # its address arithmetic lies under the products
+        at = j * block_rows + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(at < length, s, NEG_INF)          # (H, rep, rows)
+        m = m_scr[...]                                  # (H, rep, 1)
+        m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)              # a masked row: exp(-1e30) = 0
+        m_scr[...] = m_new
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=2, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot_general(
+            p.astype(vb.dtype), vb[...], (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
 
-    for g in range(G):
-        first = (j * G + g) * rows * per_row    # the page's first position
+    def passes(kb, vb, fetch_next):
+        """The block on the VPU, a live page at a time: exact float32
+        products, `per_row` tokens side by side in a row of the page."""
+        fetch_next()
+        # the query, once per token of a row: (H, rep, lanes)
+        q = q_ref[...].astype(jnp.float32) * sm_scale
+        row = jax.lax.broadcasted_iota(jnp.int32, (H, rows, 1), 1)
+        if per_row > 1:                 # the lanes of a row's c-th token
+            lane = jax.lax.broadcasted_iota(jnp.int32, (H, rows, lanes), 2)
+            seg = [(lane >= c * d) & (lane < (c + 1) * d)
+                   for c in range(per_row)]
 
-        # a page wholly past the length was not fetched (its buffer holds
-        # whatever page came before): it takes no part
-        @pl.when(first < length)
-        def _():
-            k = k_refs[g][...].astype(jnp.float32)        # (H, rows, lanes)
-            v = v_refs[g][...].astype(jnp.float32)
-            for r in range(rep):        # the stored head's r-th query head
-                at = (Ellipsis,) if rep == 1 else (slice(None),
-                                                   slice(r, r + 1))
-                kq = k * (q if rep == 1 else q[at])
-                sc = []                 # per token of a row: (H, rows, 1)
-                for c in range(per_row):
-                    part = kq if per_row == 1 else jnp.where(seg[c], kq, 0.0)
-                    s_c = jnp.sum(part, axis=-1, keepdims=True)
-                    alive = first + row * per_row + c < length
-                    sc.append(jnp.where(alive, s_c, NEG_INF))
-                m = m_scr[at]
-                m_new = m
-                for s_c in sc:
-                    m_new = jnp.maximum(m_new,
-                                        jnp.max(s_c, axis=1, keepdims=True))
-                alpha = jnp.exp(m - m_new)                # (H, 1, 1)
-                p = [jnp.exp(s_c - m_new) for s_c in sc]  # masked: exp(-1e30)
-                w = p[0]                # each token's weight on its lanes
-                for c in range(1, per_row):
-                    w = jnp.where(seg[c], p[c], w)
-                m_scr[at] = m_new
-                l_scr[at] = alpha * l_scr[at] + sum(
-                    jnp.sum(p_c, axis=1, keepdims=True) for p_c in p)
-                acc_scr[at] = alpha * acc_scr[at] + jnp.sum(
-                    w * v, axis=1, keepdims=True)         # (H, 1, lanes)
+        for g in range(G):
+            first = (j * G + g) * rows * per_row    # the page's first position
+
+            # a page wholly past the length was not fetched (its rows hold
+            # zeros or an earlier page): it takes no part
+            @pl.when(g < pages_ref[i])
+            def _(g=g, first=first):
+                page = (slice(None), slice(g * rows, (g + 1) * rows))
+                k = kb[page].astype(jnp.float32)        # (H, rows, lanes)
+                v = vb[page].astype(jnp.float32)
+                for r in range(rep):    # the stored head's r-th query head
+                    at = (Ellipsis,) if rep == 1 else (slice(None),
+                                                       slice(r, r + 1))
+                    kq = k * (q if rep == 1 else q[at])
+                    sc = []             # per token of a row: (H, rows, 1)
+                    for c in range(per_row):
+                        part = kq if per_row == 1 else jnp.where(seg[c], kq,
+                                                                 0.0)
+                        s_c = jnp.sum(part, axis=-1, keepdims=True)
+                        alive = first + row * per_row + c < length
+                        sc.append(jnp.where(alive, s_c, NEG_INF))
+                    m = m_scr[at]
+                    m_new = m
+                    for s_c in sc:
+                        m_new = jnp.maximum(
+                            m_new, jnp.max(s_c, axis=1, keepdims=True))
+                    alpha = jnp.exp(m - m_new)            # (H, 1, 1)
+                    p = [jnp.exp(s_c - m_new) for s_c in sc]
+                    w = p[0]            # each token's weight on its lanes
+                    for c in range(1, per_row):
+                        w = jnp.where(seg[c], p[c], w)
+                    m_scr[at] = m_new
+                    l_scr[at] = alpha * l_scr[at] + sum(
+                        jnp.sum(p_c, axis=1, keepdims=True) for p_c in p)
+                    acc_scr[at] = alpha * acc_scr[at] + jnp.sum(
+                        w * v, axis=1, keepdims=True)     # (H, 1, lanes)
+
+    # blocks the compiler can tell apart, the body once for each: with one
+    # array indexed by the step it orders a block's load after every start
+    for b in range(_BUFFERS):
+        @pl.when(i % _BUFFERS == b)
+        def _(b=b):
+            wait(b)
+            # a later step's pages into the block the step before this one
+            # read, while this one is worked on
+            (products if on_mxu else passes)(
+                k_bufs[b], v_bufs[b],
+                lambda: start(i + ahead, (b + ahead) % _BUFFERS))
 
     # the slot's row of the output stays in VMEM until the slot changes:
-    # what its last block writes is what goes back. Still one partial sum
-    # per token of a row; the caller adds them
-    l = l_scr[...]
-    o_ref[...] = (acc_scr[...] / jnp.where(l > 0.0, l, 1.0)
-                  ).astype(o_ref.dtype)
-
-
-def _work_list(table, lengths, page_tokens, block_pages):
-    """The grid, made from the table and the lengths (a few scalar-sized
-    XLA ops, the same for every layer of a step): one grid step per LIVE
-    block of `block_pages` pages, slot after slot — ``n`` of them (at least
-    one), then ``slot[i]``, ``block[i]`` and, flat, ``page[i, g]``: the pool
-    page operand `g` holds at step `i`. That is the slot's own page where
-    it is alive, else the page the operand held the step before (the
-    pipeline fetches a block only when its index changes), else — before
-    the operand's first live page — that page, a prefetch. No dead page,
-    no free slot's row and not the trash page is named while the operand
-    has a live page anywhere."""
-    S, P = table.shape
-    G = block_pages
-    NB = P // G
-    n_pages = -(-lengths // page_tokens)                          # (S,)
-    live_block = (jnp.arange(NB)[None, :] * G < n_pages[:, None]).reshape(-1)
-    n = jnp.sum(live_block, dtype=jnp.int32)
-    flat, = jnp.nonzero(live_block, size=S * NB, fill_value=0)
-    flat = flat.astype(jnp.int32)
-    slot, block = flat // NB, flat % NB
-    pos = block[:, None] * G + jnp.arange(G, dtype=jnp.int32)[None, :]
-    step = jnp.arange(S * NB, dtype=jnp.int32)[:, None]
-    live = (pos < n_pages[slot][:, None]) & (step < n)
-    last = jax.lax.cummax(jnp.where(live, step, -1), axis=0)
-    first = jnp.argmax(live, axis=0).astype(jnp.int32)
-    src = jnp.where(last >= 0, last, first[None, :])
-    page = jnp.take_along_axis(table[slot[:, None], pos], src, axis=0)
-    return jnp.maximum(n, 1), slot, block, page.reshape(-1)
+    # its last block writes what goes back. With narrow heads still one
+    # partial sum per token of a row; the caller adds them
+    @pl.when((j + 1) * (block_rows * per_row) >= length)
+    def _():
+        l = l_scr[...]
+        o_ref[...] = (acc_scr[...] / jnp.where(l > 0.0, l, 1.0)
+                      ).astype(o_ref.dtype)
 
 
 # jitted, so that a program calling it once a layer traces and lowers the
@@ -277,38 +433,38 @@ def _pallas_paged_decode(q, k_pool, v_pool, table, lengths, interpret):
     rep = Hq // H                       # query heads a stored head
     per_row = lanes // d
     pt = rows * per_row
-    # pages per grid step: one-page steps would cost more than the pages
-    G = max(g for g in range(1, _BLOCK_PAGES + 1) if P % g == 0)
-    table = table.astype(jnp.int32)
-    lengths = jnp.clip(lengths.astype(jnp.int32), 0, P * pt)
-    n, slot, block, page = _work_list(table, lengths, pt, G)
+    on_mxu = _on_mxu(q, k_pool)
+    G = _block_pages(P, H * rows * lanes * k_pool.dtype.itemsize)
+    n, scalars = _prefetched(table, lengths, n_pages, pt, G)
+    rows_q = jnp.tile(q, (1, 1, per_row)).reshape(S, H, rep, lanes)
     row = pl.BlockSpec((None, H, rep, lanes),
                        lambda i, lens, slot, *_: (slot[i], 0, 0, 0))
-    pages = [pl.BlockSpec((None, H, rows, lanes),
-                          lambda i, lens, slot, block, page, g=g:
-                          (page[i * G + g], 0, 0, 0))
-             for g in range(G)]
+    block_shape = (H, G * rows, lanes)
     out = pl.pallas_call(
-        functools.partial(_kernel, block_pages=G, head_dim=d,
-                          sm_scale=1.0 / math.sqrt(d)),
+        functools.partial(_kernel, block_pages=G, table_pages=P, head_dim=d,
+                          sm_scale=1.0 / math.sqrt(d), on_mxu=on_mxu),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(n,),
-            in_specs=[row] + pages + pages,
+            in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=row,
-            scratch_shapes=[pltpu.VMEM((H, rep, 1), jnp.float32),
-                            pltpu.VMEM((H, rep, 1), jnp.float32),
-                            pltpu.VMEM((H, rep, lanes), jnp.float32)]),
+            scratch_shapes=[
+                *[pltpu.VMEM(block_shape, k_pool.dtype)] * (2 * _BUFFERS),
+                pltpu.SemaphoreType.DMA((_BUFFERS,)),
+                pltpu.VMEM((H, rep, 1), jnp.float32),
+                pltpu.VMEM((H, rep, 1), jnp.float32),
+                pltpu.VMEM((H, rep, lanes), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((S, H, rep, lanes), q.dtype),
         # in order on one core: the softmax state is carried over a slot's
-        # blocks, and a partial block relies on what the step before fetched
+        # blocks, and a step starts the copies a later one waits for
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            disable_bounds_checks=True),
         interpret=interpret,
         name="mx_paged_decode",
-    )(lengths, slot, block, page,
-      jnp.tile(q, (1, 1, per_row)).reshape(S, H, rep, lanes),
-      *([k_pool] * G), *([v_pool] * G))
+    )(*scalars, rows_q.astype(k_pool.dtype) if on_mxu else rows_q, k_pool,
+      v_pool)
     out = out.reshape(S, Hq, per_row, d).sum(axis=2)
     # a slot with nothing alive has no grid step: its row was never written
     return jnp.where((lengths > 0)[:, None, None], out,
@@ -326,6 +482,8 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths, *,
     scale planes of int8 pools."""
     if takes_kernel(k_pool.dtype):
         _dispatch.note("paged_decode_attention", "pallas")
+        _dispatch.note("paged_decode_products",
+                       "mxu" if _on_mxu(q, k_pool) else "vpu")
         return _pallas_paged_decode(q, k_pool, v_pool, table, lengths,
                                     _dispatch.interpret_default())
     _dispatch.note("paged_decode_attention", "xla")
@@ -336,14 +494,6 @@ def paged_decode_attention(q, k_pool, v_pool, table, lengths, *,
 # ---------------------------------------------------------------------------
 # latent pages (MLA): one shared row a token, queries absorbed
 # ---------------------------------------------------------------------------
-
-_MLA_BLOCK_PAGES = 32   # pages per grid step, at most (512 rows of 16)
-# VMEM blocks the latent kernel fetches into, a block's copies started one
-# fewer steps before it is read: from the first copy's start to the last
-# one's end a block of 32 pages takes 1.3 us on a v5e, a grid step's products
-# as long, so one step ahead the wait still shows (PERF.md, PR 34)
-_MLA_BUFFERS = 3
-
 
 def latent_store_width(width):
     """Lanes a latent row of `width` values is stored in: whole tiles."""
@@ -364,62 +514,28 @@ def _xla_mla_decode(q, pool, table, lengths, rank, sm_scale):
     return jnp.where((lengths > 0)[:, None, None], o, 0.0).astype(q.dtype)
 
 
-def _mla_work_list(lengths, page_tokens, block_pages, n_blocks):
-    """The latent kernel's grid, made from the lengths (a few scalar-sized
-    XLA ops, the same for every layer of a step): one grid step per LIVE
-    block of `block_pages` pages, slot after slot — ``n`` of them (at least
-    one), then ``slot[i]``, ``block[i]`` and ``pages[i]``, the slot's live
-    pages from the block's first on (more than `block_pages` where the slot
-    goes on). The kernel reads a block's pages from the table itself."""
-    S, G, NB = lengths.shape[0], block_pages, n_blocks
-    n_pages = -(-lengths // page_tokens)                          # (S,)
-    live_block = (jnp.arange(NB)[None, :] * G < n_pages[:, None]).reshape(-1)
-    n = jnp.sum(live_block, dtype=jnp.int32)
-    flat, = jnp.nonzero(live_block, size=S * NB, fill_value=0)
-    flat = flat.astype(jnp.int32)
-    slot, block = flat // NB, flat % NB
-    return jnp.maximum(n, 1), slot, block, n_pages[slot] - block * G
-
-
 def _mla_kernel(len_ref, slot_ref, block_ref, pages_ref, table_ref, q_ref,
                 pool_ref, o_ref, *scratch, block_pages, table_pages,
                 rank_lanes, sm_scale):
-    G, P = block_pages, table_pages
-    bufs = scratch[:_MLA_BUFFERS]
-    sems, m_scr, l_scr, acc_scr = scratch[_MLA_BUFFERS:]
-    ahead = _MLA_BUFFERS - 1    # a block's copies start this many steps early
+    G = block_pages
+    bufs = scratch[:_BUFFERS]
+    sems, m_scr, l_scr, acc_scr = scratch[_BUFFERS:]
+    ahead = _BUFFERS - 1    # a block's copies start this many steps early
     pt = bufs[0].shape[0] // G
     i = pl.program_id(0)
-    n = pl.num_programs(0)
     j = block_ref[i]
     length = len_ref[slot_ref[i]]
+    work = (slot_ref, block_ref, pages_ref, table_ref, table_pages, G,
+            pl.num_programs(0))
 
     def start(step, buf, sem):
-        """One copy a live page of `step`'s block: none for a page past the
-        slot's length, none for a step past the last."""
-        at = jnp.minimum(step, n - 1)
-        pages = jnp.where(step < n, pages_ref[at], 0)
-        first = slot_ref[at] * P + block_ref[at] * G    # in the flat table
-        for g in range(G):
-            @pl.when(g < pages)
-            def _(g=g):
-                pltpu.make_async_copy(pool_ref.at[table_ref[first + g]],
-                                      buf.at[pl.ds(g * pt, pt)], sem).start()
+        _start_pages(work, step, lambda page, g: [pltpu.make_async_copy(
+            pool_ref.at[page], buf.at[pl.ds(g * pt, pt)], sem)])
 
     def wait(buf, sem):
-        pages = pages_ref[i]
-
-        @pl.when(pages >= G)        # the whole block: one wait for its bytes
-        def _():
-            pltpu.make_async_copy(buf, buf, sem).wait()
-
-        page = buf.at[pl.ds(0, pt)]     # a page's bytes, `pages` times
-
-        def one(_, carry):
-            pltpu.make_async_copy(page, page, sem).wait()
-            return carry
-
-        jax.lax.fori_loop(0, jnp.where(pages < G, pages, 0), one, None)
+        page = buf.at[pl.ds(0, pt)]
+        _wait_pages(pages_ref[i], G, [pltpu.make_async_copy(buf, buf, sem)],
+                    [pltpu.make_async_copy(page, page, sem)])
 
     # Rows that are never fetched (past a slot's length) must hold numbers:
     # their weight is exp(-1e30) = 0, and 0 x NaN would poison the sum. So
@@ -463,10 +579,10 @@ def _mla_kernel(len_ref, slot_ref, block_ref, pages_ref, table_ref, q_ref,
 
     # blocks the compiler can tell apart, the body once for each: with one
     # array indexed by the step it orders a block's load after every start
-    for r in range(_MLA_BUFFERS):
-        @pl.when(i % _MLA_BUFFERS == r)
+    for r in range(_BUFFERS):
+        @pl.when(i % _BUFFERS == r)
         def _(r=r):
-            free = (r + ahead) % _MLA_BUFFERS
+            free = (r + ahead) % _BUFFERS
             step(bufs[r], sems.at[r], bufs[free], sems.at[free])
 
     # the slot's row of the output stays in VMEM until the slot changes:
@@ -481,19 +597,15 @@ def _mla_kernel(len_ref, slot_ref, block_ref, pages_ref, table_ref, q_ref,
 @functools.partial(jax.jit, static_argnames=("rank", "sm_scale", "interpret",
                                              "block_pages"))
 def _pallas_mla_decode(q, pool, table, lengths, rank, sm_scale, interpret,
-                       block_pages=_MLA_BLOCK_PAGES):
+                       block_pages=_BLOCK_PAGES):
     S, H, W = q.shape
     n_pages, pt, Wp = pool.shape
     P = table.shape[1]
     if Wp != W:
         raise ValueError(f"pool {pool.shape} does not match q {q.shape}")
     rank_lanes = latent_store_width(rank)
-    G = max(g for g in range(1, block_pages + 1) if P % g == 0)
-    # no address can leave the pool whatever the table holds: the kernel's
-    # copies carry no range checks of their own (`disable_bounds_checks`)
-    table = jnp.clip(table.astype(jnp.int32), 0, n_pages - 1)
-    lengths = jnp.clip(lengths.astype(jnp.int32), 0, P * pt)
-    n, slot, block, pages = _mla_work_list(lengths, pt, G, P // G)
+    G = _block_pages(P, pt * W * pool.dtype.itemsize, block_pages)
+    n, scalars = _prefetched(table, lengths, n_pages, pt, G)
     row = lambda i, lens, slot, *_: (slot[i], 0, 0)  # noqa: E731
     out = pl.pallas_call(
         functools.partial(_mla_kernel, block_pages=G, table_pages=P,
@@ -505,8 +617,8 @@ def _pallas_mla_decode(q, pool, table, lengths, rank, sm_scale, interpret,
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((None, H, rank_lanes), row),
             scratch_shapes=[
-                *[pltpu.VMEM((G * pt, W), pool.dtype)] * _MLA_BUFFERS,
-                pltpu.SemaphoreType.DMA((_MLA_BUFFERS,)),
+                *[pltpu.VMEM((G * pt, W), pool.dtype)] * _BUFFERS,
+                pltpu.SemaphoreType.DMA((_BUFFERS,)),
                 pltpu.VMEM((H, 1), jnp.float32),
                 pltpu.VMEM((H, 1), jnp.float32),
                 pltpu.VMEM((H, rank_lanes), jnp.float32)]),
@@ -518,8 +630,7 @@ def _pallas_mla_decode(q, pool, table, lengths, rank, sm_scale, interpret,
             disable_bounds_checks=True),
         interpret=interpret,
         name="mx_mla_decode",
-    )(lengths, slot, block, pages, table.reshape(-1), q.astype(pool.dtype),
-      pool)
+    )(*scalars, q.astype(pool.dtype), pool)
     # a slot with nothing alive has no grid step: its row was never written
     return jnp.where((lengths > 0)[:, None, None], out[..., :rank],
                      jnp.zeros((), q.dtype))

@@ -30,7 +30,8 @@ not ``max_len`` rows of HBM, and a shared prompt prefix is prefilled once:
   array mapping each slot's token range to pool pages (mirrored to the
   device lazily, refreshed only when allocation changes). Decode's
   attention (`ops.paged_attention.paged_decode_attention`) goes through
-  it to the pages under each slot's ``pos`` and to no others; prefill
+  it to the pages under each slot's ``pos`` and to no others (the kernel
+  is handed the table itself and copies a live page at a time); prefill
   writes whole pages with a static-shape scatter and gathers ONE slot's
   logical view with a static-shape ``jnp.take`` over its table row.
 - **allocator + prefix cache** — `PageAllocator` (host-only free list +
@@ -86,8 +87,11 @@ Two compiled program families in the base configuration:
   observes and counted in
   ``mx_kernel_dispatch_total{op="paged_decode_attention",impl=}``: on one
   TPU device with float pools the pallas kernel ``mx_paged_decode``,
-  which reads the pages below ``pos`` straight from the layer's pool
-  leaf and never builds the ``(S, H, max_len, d)`` view; on the CPU,
+  which copies the pages below ``pos`` straight from the layer's pool
+  leaf, a block of them a grid step, and never builds the ``(S, H,
+  max_len, d)`` view (its two products on the MXU where a bfloat16 head
+  fills the 128 lanes, on the VPU in exact float32 otherwise:
+  ``mx_kernel_dispatch_total{op="paged_decode_products",impl=}``); on the CPU,
   under a multi-device mesh and for int8 pools the XLA expression —
   gather every slot's whole view through the table, mask, softmax, two
   einsums.

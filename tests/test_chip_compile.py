@@ -7,6 +7,7 @@ compiles for a chip that is described, not attached — so these cases guard
 every later PR at no chip time. Nothing runs; a compile that passes is not
 a chip run. Skipped where the topology cannot be described.
 """
+import math
 import os
 import re
 
@@ -123,28 +124,54 @@ def test_kernel_compiles_for_v5e(chip, fn, kinds, shape, dtypes, n_kernels):
             'custom_call_target="tpu_custom_call"') == n_kernels, dtype
 
 
-@pytest.mark.parametrize("d,heads", [(64, 25), (128, 16)],
-                         ids=["gpt2xl-25x64", "16x128"])
-def test_paged_decode_compiles_for_v5e(chip, d, heads):
-    """`mx_paged_decode` at mx.serve's GPT-2 XL sizes (8 slots x 64 pages
-    of 16 tokens, 25 heads of 64, stored two tokens to a row of 128 lanes)
-    and at a 128-wide head. The pools as the chip lays them out by itself:
-    nothing else of a pool's size may appear, since a re-layout of one
-    leaf is 50 MB."""
-    S, P, pt = 8, 64, 16
+def _pools_are_operands_once(compiled, shape, dtype, at=(1, 2)):
+    """One kernel in the program; each pool of `shape` (parameters `at`)
+    ONE operand of it, left in HBM for the kernel's own copies; and nothing
+    of a pool's size copied or laid out anew (a re-layout of one leaf of a
+    cell is 50 MB to 0.5 GB, and would show as temporary bytes)."""
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call, = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    operands = re.findall(
+        r"%[\w.]+", call.split("custom-call(", 1)[1].split(")", 1)[0])
+    dims = ",".join(str(n) for n in shape)
+    for n in at:
+        pool, = re.findall(
+            rf"(%[\w.]+) = {dtype}\[{dims}\]\S* parameter\({n}\)", text)
+        assert operands.count(pool) == 1
+    pool_bytes = math.prod(shape) * (2 if dtype == "bf16" else 4)
+    assert compiled.memory_analysis().temp_size_in_bytes < min(
+        pool_bytes // 2, 32 * 2 ** 20)
+    return text
 
-    def arg(shape, dtype=F32):
+
+@pytest.mark.parametrize("S,P,d,heads,dtype", [
+    (8, 64, 64, 25, F32), (8, 64, 128, 16, F32), (8, 248, 128, 32, BF16)],
+    ids=["gpt2xl-25x64", "16x128", "evabyte-32x128-bf16"])
+def test_paged_decode_compiles_for_v5e(chip, S, P, d, heads, dtype):
+    """`mx_paged_decode` at mx.serve's GPT-2 XL sizes (8 slots x 64 pages
+    of 16 tokens, 25 heads of 64, stored two tokens to a row of 128 lanes),
+    at a 128-wide float32 head, and at the EvaByte cell's (8 slots x 248
+    pages, 32 heads of 128, bfloat16: one query row a head through the MXU).
+    The pools as the chip lays them out by itself, each ONE operand of the
+    kernel, which fetches a block's pages itself."""
+    pt = 16
+
+    def arg(shape, dtype=dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     pool = arg((S * P + 1, heads) + paged_attention.page_store_shape(pt, d))
+    assert paged_attention._on_mxu(arg((S, heads, d)), pool) is (dtype == BF16)
     compiled = jax.jit(
         lambda q, k, v, t, n: paged_attention._pallas_paged_decode(
             q, k, v, t, n, False)).lower(
         arg((S, heads, d)), pool, pool, arg((S, P), jnp.int32),
         arg((S,), jnp.int32)).compile()
-    text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 1
-    assert "{3,2,1,0:T(8,128)}" in text.split("->")[0]     # pages major
+    text = _pools_are_operands_once(
+        compiled, pool.shape, "bf16" if dtype == BF16 else "f32")
+    tiles = "T(8,128)(2,1)" if dtype == BF16 else "T(8,128)"
+    assert "{3,2,1,0:%s}" % tiles in text.split("->")[0]     # pages major
 
 
 def test_mla_decode_compiles_for_v5e(chip):
@@ -236,22 +263,23 @@ def test_ssm_decode_compiles_for_v5e_and_updates_the_state_in_place(chip):
 def test_paged_decode_grouped_heads_compile_for_v5e(chip):
     """`mx_paged_decode` with 32 query heads over 2 stored heads of 128
     (the Nemotron-H cell's one attention block: 64 slots x 192 pages of 16
-    tokens, bfloat16): one kernel, a page fetched once for its 16 query
-    heads."""
+    tokens, bfloat16): one kernel, a block of 32 pages fetched once for its
+    16 query heads a stored head and multiplied on the MXU, each pool one
+    operand."""
     S, P, pt, hq, hk, d = 64, 192, 16, 32, 2, 128
 
     def arg(shape, dtype=BF16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     pool = arg((S * P + 1, hk) + paged_attention.page_store_shape(pt, d))
+    assert paged_attention._on_mxu(arg((S, hq, d)), pool)
     compiled = jax.jit(
         lambda q, k, v, t, n: paged_attention._pallas_paged_decode(
             q, k, v, t, n, False)).lower(
         arg((S, hq, d)), pool, pool, arg((S, P), jnp.int32),
         arg((S,), jnp.int32)).compile()
-    text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 1
-    assert "mx_paged_decode" in text
+    assert "mx_paged_decode" in _pools_are_operands_once(
+        compiled, pool.shape, "bf16")
 
 
 @pytest.mark.parametrize("tokens,tile,step", [

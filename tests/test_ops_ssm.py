@@ -178,36 +178,47 @@ def test_conv_tail_carries_over_any_split_and_through_decode(splits):
 
 # -- grouped heads in the paged decode kernel ---------------------------------
 
-@pytest.mark.parametrize("hq,hk,d", [(8, 2, 128), (4, 2, 64), (2, 2, 64)],
-                         ids=["8over2x128", "4over2x64", "ungrouped"])
-def test_paged_decode_grouped_heads_kernel_is_xla_is_plain_attention(hq, hk,
-                                                                     d):
+@pytest.mark.parametrize("hq,hk,d,dtype", [
+    (8, 2, 128, jnp.float32), (4, 2, 64, jnp.float32),
+    (2, 2, 64, jnp.float32), (32, 2, 128, jnp.bfloat16)],
+    ids=["8over2x128", "4over2x64", "ungrouped", "32over2x128-bf16"])
+def test_paged_decode_grouped_heads_kernel_is_xla_is_plain_attention(
+        hq, hk, d, dtype):
+    """The last form is the cell's own (16 query heads a stored head of 128,
+    bfloat16): the kernel's two products go to the MXU there, the weights
+    handed over in bfloat16 as the XLA expression hands them; the float32
+    forms keep the VPU's exact products."""
     rng = onp.random.default_rng(5)
     S, pages, pt = 3, 4, 8
     n_pages = S * pages + 1
-    k = draw(rng, n_pages, hk, pt, d)
-    v = draw(rng, n_pages, hk, pt, d)
-    q = draw(rng, S, hq, d)
+    k, v, q = (draw(rng, *shape).astype(dtype) for shape in (
+        (n_pages, hk, pt, d), (n_pages, hk, pt, d), (S, hq, d)))
     table = jnp.asarray(1 + onp.arange(S * pages).reshape(S, pages),
                         jnp.int32)
     lengths = jnp.asarray([pt * pages, 11, 0], jnp.int32)
     kp, vp = paged_attention.pack_pages(k), paged_attention.pack_pages(v)
-    a = paged_attention._xla_paged_decode(q, kp, vp, table, lengths, None,
-                                          None)
-    b = paged_attention._pallas_paged_decode(q, kp, vp, table, lengths, True)
-    onp.testing.assert_allclose(a, b, atol=2e-5)
+    assert paged_attention._on_mxu(q, kp) is (dtype == jnp.bfloat16)
+    tol = 8e-3 if dtype == jnp.bfloat16 else 2e-5
+    a, b = (onp.asarray(x, onp.float32) for x in (
+        paged_attention._xla_paged_decode(q, kp, vp, table, lengths, None,
+                                          None),
+        paged_attention._pallas_paged_decode(q, kp, vp, table, lengths,
+                                             True)))
+    onp.testing.assert_allclose(a, b, atol=tol)
+    k, v, q = (onp.asarray(x, onp.float32) for x in (k, v, q))
     rep = hq // hk
     for s, n in enumerate(onp.asarray(lengths)):
         if not n:
-            assert not onp.asarray(a[s]).any()
+            assert not a[s].any() and not b[s].any()
             continue
-        ks = onp.concatenate([onp.asarray(k[p]) for p in table[s]], 1)[:, :n]
-        vs = onp.concatenate([onp.asarray(v[p]) for p in table[s]], 1)[:, :n]
+        ks = onp.concatenate([k[p] for p in table[s]], 1)[:, :n]
+        vs = onp.concatenate([v[p] for p in table[s]], 1)[:, :n]
         for h in range(hq):
-            sc = ks[h // rep] @ onp.asarray(q[s, h]) / onp.sqrt(d)
+            sc = ks[h // rep] @ q[s, h] / onp.sqrt(d)
             w = onp.exp(sc - sc.max())
-            onp.testing.assert_allclose(a[s, h], (w / w.sum()) @ vs[h // rep],
-                                        atol=2e-5)
+            for got in (a, b):
+                onp.testing.assert_allclose(
+                    got[s, h], (w / w.sum()) @ vs[h // rep], atol=tol)
 
 
 # -- ops/moe.py: the score bias, ungated experts, the tile rule ---------------

@@ -2,8 +2,12 @@
 
 The pallas kernel (`mx_paged_decode`) runs here in interpret mode on the CPU
 and is held against the XLA expression of the same op (the engine's gather of
-every slot's whole view): on random pools, tables and lengths; with pages
-shared between slots; with every page that is not alive poisoned. Then the
+every slot's whole view): on random pools, tables and lengths; in the three
+forms the benchmark's cells give it (narrow float32 heads packed two to a
+row, bfloat16 heads of 128 with and without grouped query heads: the last
+two multiply on the MXU); with pages shared between slots; with every page
+that is not alive poisoned; and the grid both kernels of the file are handed
+(`_block_list`). Then the
 op's place in the engine: the counters that show the kernel engaged, and
 `ServeEngine` serving the same greedy tokens through the kernel as through
 the XLA expression. What the chip's compiler makes of the kernel is
@@ -66,7 +70,7 @@ LENGTHS = {"one": lambda pt, full: 1, "a_page": lambda pt, full: pt,
 @pytest.mark.parametrize("d", [32, 64, 128])
 @pytest.mark.parametrize("pt", [8, 16])
 def test_kernel_matches_the_xla_expression(pt, d, length):
-    P, S = 12, 3                       # 12 pages a slot: two blocks of 6
+    P, S = 12, 3                       # 12 pages a slot: one block
     rng = onp.random.default_rng([pt, d, len(length)])
     kp, vp = _pools(rng, S * P + 1, pt, d)
     table = rng.permutation(onp.arange(1, S * P + 1)).reshape(S, P) \
@@ -118,28 +122,127 @@ def test_dead_pages_and_the_trash_page_never_reach_the_output(lengths):
     onp.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-6)
 
 
-def test_work_list_names_live_blocks_and_live_pages_only():
-    """One grid step per live block; an operand whose page is past the
-    length keeps the page it held the step before (an unchanged index
-    fetches nothing), so no dead page, no free slot's row and not the
-    trash page is ever named."""
-    pt, G = 16, 4
-    table = onp.arange(1, 25, dtype=onp.int32).reshape(3, 8)
-    table[1] = 0                         # a free slot's row: the trash page
-    lengths = onp.asarray([5 * pt, 0, 2 * pt + 1], onp.int32)
-    n, slot, block, page = (onp.asarray(a) for a in pa._work_list(
-        jnp.asarray(table), jnp.asarray(lengths), pt, G))
-    assert n == 3                        # blocks 0, 1 of slot 0; 0 of slot 2
-    onp.testing.assert_array_equal(slot[:3], [0, 0, 2])
-    onp.testing.assert_array_equal(block[:3], [0, 1, 0])
-    page = page.reshape(-1, G)
-    onp.testing.assert_array_equal(page[0], [1, 2, 3, 4])
-    onp.testing.assert_array_equal(page[1], [5, 2, 3, 4])    # 2-4 repeat
-    onp.testing.assert_array_equal(page[2], [17, 18, 19, 4])
-    # nothing decodes: one step all the same, and it attends nothing
-    n0, slot0, _, _ = pa._work_list(jnp.asarray(table),
-                                    jnp.zeros(3, jnp.int32), pt, G)
-    assert n0 == 1 and slot0[0] == 0
+# the three cells' forms: query heads, stored heads, head size, dtype, and
+# what the shape gives them: the pages of a block, and the body (the MXU's
+# products or the VPU's passes)
+FORMS = {"gpt2xl": (25, 25, 64, jnp.float32, 8, False),
+         "evabyte": (32, 32, 128, jnp.bfloat16, 8, True),
+         "nemotron": (32, 2, 128, jnp.bfloat16, 32, True)}
+# where a slot's length ends, in pages of `pt` and blocks of `G` pages
+ENDS = {"empty": lambda pt, G: 0, "inside_a_page": lambda pt, G: pt + 3,
+        "a_pages_edge": lambda pt, G: 3 * pt,
+        "a_blocks_edge": lambda pt, G: G * pt,
+        "past_a_block": lambda pt, G: G * pt + 1,
+        "full_view": lambda pt, G: 2 * G * pt}
+
+
+def _form_case(form, length, seed=0):
+    """Pools, a table whose dead entries name the trash page, lengths and
+    queries in one cell's form: three slots of two blocks each, the middle
+    one of another length; every page no live entry names holds NaN."""
+    hq, hk, d, dtype, G, on_mxu = FORMS[form]
+    pt, S, P = 16, 3, 2 * G
+    assert pa._block_pages(P, hk * pt * d * jnp.dtype(dtype).itemsize) == G
+    rng = onp.random.default_rng([seed, len(form), len(length)])
+    n_pages = S * P + 1
+    k, v = (rng.normal(size=(n_pages, hk, pt, d)).astype(onp.float32)
+            for _ in range(2))
+    table = rng.permutation(onp.arange(1, n_pages)).reshape(S, P) \
+        .astype(onp.int32)
+    n = ENDS[length](pt, G)
+    lengths = onp.asarray([n, int(rng.integers(1, P * pt)), n], onp.int32)
+    for s in range(S):
+        table[s, -(-int(lengths[s]) // pt):] = 0      # unmapped -> trash
+    dead = sorted(set(range(n_pages)) - _live_pages(table, lengths, pt))
+    k[dead] = v[dead] = onp.nan
+    q = rng.normal(size=(S, hq, d)).astype(onp.float32)
+    q, k, v = (jnp.asarray(a).astype(dtype) for a in (q, k, v))
+    return (q, pa.pack_pages(k), pa.pack_pages(v), jnp.asarray(table),
+            jnp.asarray(lengths)), (G, P, pt, on_mxu)
+
+
+@pytest.mark.parametrize("length", sorted(ENDS))
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_kernel_in_the_three_cells_forms(form, length):
+    """Against the XLA expression on the clean pools. The MXU's body hands
+    the weights to the second product in the pool's dtype, as the XLA
+    expression does: what differs is where the sums round, inside bfloat16's
+    own step; the VPU's body is exact float32."""
+    args, (G, P, pt, on_mxu) = _form_case(form, length)
+    q, k, v, table, lengths = args
+    assert pa._on_mxu(q, k) is on_mxu
+    clean = [jnp.nan_to_num(a) for a in (k, v)]
+    ref = onp.asarray(pa._xla_paged_decode(q, *clean, table, lengths, None,
+                                           None), onp.float32)
+    out = onp.asarray(pa._pallas_paged_decode(*args, True), onp.float32)
+    assert not onp.isnan(out).any()
+    tol = dict(rtol=2e-2, atol=2e-2) if on_mxu else dict(rtol=2e-5, atol=2e-6)
+    onp.testing.assert_allclose(out, ref, **tol)
+    if int(lengths[0]) == 0:
+        assert not out[0].any() and not out[2].any()     # zeros, not NaN
+
+
+def test_mxu_body_is_plain_attention_in_float32_to_bfloat16s_step():
+    """The grouped bfloat16 form against dense float32 attention over the
+    same (bfloat16-valued) rows: the kernel's own rounding (`p` handed to the
+    second product in bfloat16, sums in float32) stays inside the stated
+    precision, whatever the XLA expression rounds."""
+    args, (G, P, pt, _) = _form_case("nemotron", "past_a_block", seed=1)
+    q, k, v, table, lengths = (onp.asarray(a, onp.float32) for a in args)
+    out = onp.asarray(pa._pallas_paged_decode(*args, True), onp.float32)
+    hq, hk, d = q.shape[1], k.shape[1], q.shape[2]
+    for s, n in enumerate(lengths.astype(int)):
+        pages = table[s].astype(int)[:-(-n // pt)]
+        ks = onp.concatenate([k[p] for p in pages], 1)[:, :n]
+        vs = onp.concatenate([v[p] for p in pages], 1)[:, :n]
+        for h in range(hq):
+            sc = ks[h // (hq // hk)] @ q[s, h] / onp.sqrt(d)
+            w = onp.exp(sc - sc.max())
+            want = (w / w.sum()) @ vs[h // (hq // hk)]
+            onp.testing.assert_allclose(out[s, h], want, atol=8e-3)
+
+
+def test_block_list_names_live_blocks_and_counts_their_live_pages():
+    """The grid both kernels are handed: one step per live block, slot after
+    slot, with the slot's live pages from the block's first on; a free slot
+    has no step, and nothing alive leaves one step that attends nothing. No
+    page is named: the kernels read the table themselves, a live page at a
+    time, so a dead entry (the trash page) is never fetched."""
+    pt, G, NB = 16, 4, 2
+    lengths = jnp.asarray([5 * pt, 0, 2 * pt + 1, 8 * pt], jnp.int32)
+    n, slot, block, pages = (onp.asarray(a) for a in pa._block_list(
+        lengths, pt, G, NB))
+    assert n == 5            # blocks 0, 1 of slot 0; 0 of slot 2; 0, 1 of 3
+    onp.testing.assert_array_equal(slot[:5], [0, 0, 2, 3, 3])
+    onp.testing.assert_array_equal(block[:5], [0, 1, 0, 0, 1])
+    onp.testing.assert_array_equal(pages[:5], [5, 1, 3, 8, 4])
+    n0, slot0, block0, pages0 = pa._block_list(jnp.zeros(4, jnp.int32), pt,
+                                               G, NB)
+    assert n0 == 1 and slot0[0] == 0 and block0[0] == 0 and pages0[0] == 0
+    # what both kernels' callers hand on (`_prefetched`): that grid, the
+    # lengths held to the view, and the table flat and clipped to the pool,
+    # because the kernels' copies carry no range checks
+    table = jnp.arange(32, dtype=jnp.int32).reshape(4, 8) - 3
+    n1, (lens, slot1, block1, pages1, flat) = pa._prefetched(
+        table, lengths.at[3].set(99 * pt), n_pages=20, page_tokens=pt,
+        block_pages=G)
+    assert n1 == n and int(lens[3]) == 8 * pt
+    for got, want in ((slot1, slot), (block1, block), (pages1, pages)):
+        onp.testing.assert_array_equal(got, want)
+    onp.testing.assert_array_equal(flat, onp.clip(onp.arange(32) - 3, 0, 19))
+
+
+@pytest.mark.parametrize("table_pages,page_bytes,want", [
+    (192, 8192, 32),          # nemotron3super.turns: 32 pages of 8 KB
+    (248, 131072, 8),         # evabyte.docs: 8 pages of 128 KB, 1 MB of K
+    (64, 102400, 8),          # gpt2xl.chat: 8 pages of 100 KB
+    (768, 20480, 32),         # pangu718b.think's latent pages
+    (7, 8192, 7), (62, 8192, 31), (5, 2 ** 21, 1)])
+def test_pages_a_block_come_from_the_pages_bytes_and_the_tables_width(
+        table_pages, page_bytes, want):
+    assert pa._block_pages(table_pages, page_bytes) == want
+    assert pa._block_pages(table_pages, page_bytes, at_most=4) == max(
+        g for g in range(1, min(want, 4) + 1) if table_pages % g == 0)
 
 
 def test_length_past_the_view_is_held_to_the_view():
@@ -172,6 +275,26 @@ def test_dispatch_counter_ticks_for_the_branch_taken(impl, monkeypatch):
         jnp.asarray([9, 0], jnp.int32))
     assert out.shape == (2, H, 64) and not onp.asarray(out[1]).any()
     assert _dispatch_count(impl) == before + 1
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_products_counter_says_which_body_ran(form, monkeypatch):
+    """``mx_kernel_dispatch_total{op="paged_decode_products"}``: one tick a
+    traced call of the kernel, `impl` the unit the shape chose; none where
+    the XLA expression is taken."""
+    args, (_, _, _, on_mxu) = _form_case(form, "inside_a_page")
+
+    def count():
+        got = _dispatch.choices()
+        return [got.get(("paged_decode_products", impl), 0)
+                for impl in ("mxu", "vpu")]
+
+    before = count()
+    pa.paged_decode_attention(*args)                 # the CPU: XLA
+    assert count() == before
+    monkeypatch.setattr(_dispatch, "use_pallas", lambda: True)
+    pa.paged_decode_attention(*args)
+    assert count() == [before[0] + on_mxu, before[1] + (not on_mxu)]
 
 
 def test_int8_pools_keep_the_xla_expression(monkeypatch):

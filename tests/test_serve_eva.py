@@ -170,17 +170,20 @@ def test_summaries_are_the_references():
 # -- (c) summary ++ window through the paged op -------------------------------
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-@pytest.mark.parametrize("d", [128, 16])
-def test_summary_then_window_pages_through_paged_decode(impl, d):
+@pytest.mark.parametrize("d,dtype", [(128, "float32"), (16, "float32"),
+                                     (128, "bfloat16")])
+def test_summary_then_window_pages_through_paged_decode(impl, d, dtype):
     """A slot's row: its summary pages, then its window pages; `lengths` the
-    row count. Against dense softmax over the concatenated rows."""
+    row count. Against dense softmax over the concatenated rows. bfloat16 at
+    head size 128 is the cell's own form: one query row a head through the
+    kernel's MXU products."""
     rng = onp.random.default_rng(d)
     S, H, pt, P, n_pages = 3, 2, 16, 8, 40
-    pool_k, pool_v = (rng.normal(size=(n_pages, H, pt, d)).astype(onp.float32)
-                      for _ in range(2))
+    pool_k, pool_v, q = (
+        onp.asarray(jnp.asarray(rng.normal(size=shape), dtype), onp.float32)
+        for shape in ((n_pages, H, pt, d), (n_pages, H, pt, d), (S, H, d)))
     table = onp.zeros((S, P), onp.int32)
     lengths = onp.zeros(S, onp.int32)
-    q = rng.normal(size=(S, H, d)).astype(onp.float32)
     want = onp.zeros((S, H, d), onp.float32)
     free = list(rng.permutation(onp.arange(1, n_pages)))
     for s, (n_sum, in_window) in enumerate([(2, 21), (0, 5), (3, 64)]):
@@ -193,17 +196,18 @@ def test_summary_then_window_pages_through_paged_decode(impl, d):
         e = onp.einsum("hd,hnd->hn", q[s], k) / math.sqrt(d)
         e = onp.exp(e - e.max(-1, keepdims=True))
         want[s] = onp.einsum("hn,hnd->hd", e / e.sum(-1, keepdims=True), v)
-    pk, pv = (paged_attention.pack_pages(jnp.asarray(a))
+    pk, pv = (paged_attention.pack_pages(jnp.asarray(a, dtype))
               for a in (pool_k, pool_v))
+    args = (jnp.asarray(q, dtype), pk, pv, jnp.asarray(table),
+            jnp.asarray(lengths))
+    assert paged_attention._on_mxu(args[0], pk) is (dtype == "bfloat16")
     if impl == "pallas":
-        got = paged_attention._pallas_paged_decode(
-            jnp.asarray(q), pk, pv, jnp.asarray(table), jnp.asarray(lengths),
-            True)
+        got = paged_attention._pallas_paged_decode(*args, True)
     else:
-        got = paged_attention._xla_paged_decode(
-            jnp.asarray(q), pk, pv, jnp.asarray(table), jnp.asarray(lengths),
-            None, None)
-    onp.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+        got = paged_attention._xla_paged_decode(*args, None, None)
+    tol = 8e-3 if dtype == "bfloat16" else 2e-5
+    onp.testing.assert_allclose(onp.asarray(got, onp.float32), want,
+                                rtol=tol, atol=tol)
 
 
 # -- (d) the page arithmetic and the allocator --------------------------------

@@ -5,6 +5,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -1298,5 +1300,25 @@ def test_kernel_schedule_reads_a_final_bundles_file():
     ks.report("mx_sample", text, out=out)
     assert "the grid loop 12 (file lines 9-22)" in out.getvalue()
     assert "[or skip to 16] 1 check" in out.getvalue()
+    assert ("in the loop, every body counted: 2 vmatmul, 2 vpop, 0 vxpose, "
+            "2 dma, 1 wait, 1 check") in out.getvalue()
     ks.report("mx_none", "     0   :  { %s1 = smov 1 }", out=out)
     assert "mx_none: 1 bundles, no loop" in out.getvalue()
+
+
+def test_kernel_schedule_compiles_paged_decode_at_the_three_cells_shapes():
+    """`paged_decode --shape`: the cells that run `mx_paged_decode`, each
+    with a body of its own (packed float32 heads on the VPU; bfloat16 heads
+    of 128 on the MXU, one query row a head or sixteen), GPT-2 XL's by
+    default as before."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    try:
+        import kernel_schedule as ks
+    finally:
+        sys.path.pop(0)
+    assert set(ks.PAGED_SHAPES) == {"gpt2xl", "evabyte", "nemotron"}
+    assert ks.PAGED_SHAPES["gpt2xl"] == (8, 64, 25, 25, 64, "float32")
+    assert ks.PAGED_SHAPES["evabyte"] == (8, 248, 32, 32, 128, "bfloat16")
+    assert ks.PAGED_SHAPES["nemotron"] == (64, 192, 32, 2, 128, "bfloat16")
+    with pytest.raises(SystemExit):
+        ks.main(["paged_decode", "--shape", "bert"])

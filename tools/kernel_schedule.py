@@ -2,7 +2,8 @@
 """The TPU compiler's own schedule of one pallas kernel, read without a chip.
 
 ``python tools/kernel_schedule.py mla_decode`` compiles the named kernel of
-the main path at its cell's shapes for a DESCRIBED v5e (as
+the main path at its cell's shapes (``paged_decode --shape
+gpt2xl|evabyte|nemotron``: it serves three) for a DESCRIBED v5e (as
 `tests/test_chip_compile.py` does) in a child process that asks libtpu to
 dump its passes (``--xla_jf_dump_to=<dir> --xla_jf_dump_llo_text=true`` in
 ``LIBTPU_INIT_ARGS``, set before jax loads the library), then reads the
@@ -11,7 +12,9 @@ order the core walks them. Printed are the lines of the GRID LOOP (a grid
 step walks the loop once), cut into stretches at the loop's start, at every
 branch and branch target, and at the first and the last line of each kind
 of work (``dma`` starts, ``dma.done.wait``, ``vmatmul``, ``vpop`` of the
-MXU's results), each with its count of lines and what it holds.
+MXU's results), each with its count of lines and what it holds; a last line
+counts the loop's ``vmatmul``, ``vpop``, ``vxpose`` (an operand turned for
+the MXU), ``dma``, waits and range checks over all its bodies.
 
 A stretch after a branch is walked only where the branch is not taken:
 ``or skip to 2363`` names the file line the branch goes to. A step's walk
@@ -40,6 +43,13 @@ import tempfile
 
 # the kernels that can be named (`mx_<name>` in a trace)
 KERNELS = ("mla_decode", "paged_decode", "moe_experts", "ssm_decode")
+# `paged_decode` serves three cells, each with a body of its own: slots,
+# table pages, query heads, stored heads, head size, the pool's dtype
+PAGED_SHAPES = {
+    "gpt2xl": (8, 64, 25, 25, 64, "float32"),       # gpt2xl.chat (packed)
+    "evabyte": (8, 248, 32, 32, 128, "bfloat16"),   # evabyte.docs
+    "nemotron": (64, 192, 32, 2, 128, "bfloat16"),  # nemotron3super.turns
+}
 
 _BUNDLE = re.compile(
     r"^\s*(0x[0-9a-f]+|\d+)\s+(LH|LB|LE|PB|PF|CT)?:?\s*(>*)\s*\{(.*)$")
@@ -54,6 +64,9 @@ KINDS = collections.OrderedDict([
     ("wait", lambda op: op.startswith("dma.done")),
     ("vmatmul", lambda op: op.startswith("vmatmul")),
     ("vpop", lambda op: op.startswith("vpop.f32.mrf")),
+    # an operand turned on its way into the MXU: a product written the
+    # wrong way round for the array pays one a tile
+    ("vxpose", lambda op: op.startswith("vxpose")),
     ("check", lambda op: op == "shalt.err"),
 ])
 _CUT_KINDS = ("dma", "wait", "vmatmul", "vpop")
@@ -158,13 +171,17 @@ def report(name, text, out=sys.stdout):
         print(f"{p['first']:>5}-{p['last']:<5}  {p['lines']:>5}  "
               f"{p['delayed'] if p['delayed'] is not None else '':>7}  "
               f"{holds}", file=out)
+    held = sum((b["kinds"] for b in loop), collections.Counter())
+    print("in the loop, every body counted: " + ", ".join(
+        f"{held[k]} {k}" for k in ("vmatmul", "vpop", "vxpose", "dma",
+                                   "wait", "check")), file=out)
 
 
 # ---------------------------------------------------------------------------
 # the child: compile one kernel for a described v5e, dumping
 # ---------------------------------------------------------------------------
 
-def _compile(kernel, dump_dir):
+def _compile(kernel, dump_dir, shape):
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["LIBTPU_INIT_ARGS"] = (
@@ -191,13 +208,15 @@ def _compile(kernel, dump_dir):
                 q, pool, table, lengths, 512, 192 ** -0.5, False)
         args = (arg((64, 128, 640)), arg((18240, 16, 640)),
                 arg((64, 768), i32), arg((64,), i32))
-    elif kernel == "paged_decode":  # gpt2xl.chat: 8 slots x 64 pages
+    elif kernel == "paged_decode":  # pages of 16 tokens, a full pool
         def fn(q, k, v, table, lengths):
             return paged_attention._pallas_paged_decode(
                 q, k, v, table, lengths, False)
-        pool = arg((513, 25) + paged_attention.page_store_shape(16, 64), f32)
-        args = (arg((8, 25, 64), f32), pool, pool, arg((8, 64), i32),
-                arg((8,), i32))
+        S, P, hq, hk, d, dtype = PAGED_SHAPES[shape]
+        pool = arg((S * P + 1, hk) + paged_attention.page_store_shape(16, d),
+                   jnp.dtype(dtype))
+        args = (arg((S, hq, d), jnp.dtype(dtype)), pool, pool,
+                arg((S, P), i32), arg((S,), i32))
     elif kernel == "ssm_decode":    # nemotron3super.turns: 64 slots' state
         def fn(state, x, b, c, dt, a, d, active):
             return ssm._pallas_decode(state, x, b, c, dt, a, d, active, False)
@@ -224,13 +243,15 @@ def _compile(kernel, dump_dir):
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("kernel", choices=KERNELS)
+    ap.add_argument("--shape", choices=sorted(PAGED_SHAPES), default="gpt2xl",
+                    help="which cell's shape `paged_decode` is compiled at")
     ap.add_argument("--keep", metavar="DIR",
                     help="leave the compiler's dump in DIR (some 3,000 "
                          "files) instead of a temporary directory")
     ap.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
     a = ap.parse_args(argv)
     if a.child:
-        _compile(a.kernel, a.keep)
+        _compile(a.kernel, a.keep, a.shape)
     with tempfile.TemporaryDirectory() as tmp:
         dump = a.keep or tmp
         os.makedirs(dump, exist_ok=True)
@@ -239,7 +260,8 @@ def main(argv=None):
             p for p in (root, os.environ.get("PYTHONPATH")) if p))
         done = subprocess.run(
             [sys.executable, os.path.abspath(__file__), a.kernel, "--child",
-             "--keep", dump], env=env, capture_output=True, text=True)
+             "--keep", dump, "--shape", a.shape], env=env,
+            capture_output=True, text=True)
         files = sorted(glob.glob(os.path.join(dump, "*-final_bundles.txt")))
         files = [f for f in files if "schedule-analysis" not in f
                  and f"-mx_{a.kernel}" in os.path.basename(f)]
