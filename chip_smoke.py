@@ -289,6 +289,7 @@ def serve_phase(cfg, seed, watch):
     branches0 = _dispatch.choices()
     compiles.reset()
     compiles.enable()
+    t_run = time.perf_counter()
     eng = mx.serve.ServeEngine(net, max_slots=cfg["max_slots"],
                                max_len=cfg["max_len"])
     try:
@@ -357,6 +358,30 @@ def serve_phase(cfg, seed, watch):
                 f"{len(steps)} step records in the window, phases cover "
                 f"{accounted:.2f} % of the steps' wall (< 99: a boundary of "
                 "Scheduler.step is no longer stamped)")
+        # the launch's four parts are stamped inside `decode_launch` and
+        # leave only the engine's counters out (2 % of a launch, or the
+        # 0.1 ms that is of a chip-size one where the model is a toy); a
+        # dry interval is made of two stamps of this run, in order
+        launches = [r for r in steps if r["decode_launch"] > 0.0]
+        launch = sum(r["decode_launch"] for r in launches)
+        rest = launch - sum(r[p] for r in launches
+                            for p in tracing.LAUNCH_PARTS)
+        dry = [iv for r in steps for iv in r["dry"]]
+        by_cause = {c: round(sum(r["dry_" + c] for r in steps), 4)
+                    for c in tracing.DRY_CAUSES}
+        say(f"launch parts leave {100.0 * rest / launch:.2f} % of "
+            f"decode_launch ({1e6 * rest / len(launches):.0f} us a launch); "
+            f"{len(dry)} dry intervals, seconds by cause {by_cause}")
+        if not -1e-9 <= rest <= max(0.02 * launch, 1e-4 * len(launches)):
+            raise RuntimeError(
+                f"the launch's parts leave {rest:.6f} s of {launch:.6f} s of "
+                "decode_launch (a boundary of SlotDecoder.decode_step is "
+                "missing or charged twice)")
+        t_end = time.perf_counter()
+        if not dry or not all(t_run <= a < b <= t_end for a, b, _ in dry):
+            raise RuntimeError(
+                f"{len(dry)} dry intervals, not all inside the run "
+                f"[{t_run}, {t_end}]: {dry[:4]}")
         launch_modes(steps, "serve")
         net.hybridize()
         i = longest(prompts)

@@ -744,6 +744,17 @@ class SlotDecoder:
         """Every device array of the pools, target and draft."""
         return _j().tree.leaves((self._pools, self._draft_pools))
 
+    def device_dry(self):
+        """Whether everything this engine launched has finished, without
+        blocking (one ``is_ready()``). Every program takes the pools donated
+        and returns them first (`_observed`), so any leaf of ``_pools`` is an
+        output of the newest program launched, whatever its kind; on the
+        device's one in-order stream that leaf is ready when nothing queued
+        is left. (The draft program writes ``_draft_pools``, and its round
+        fetches what it launches.) True before the first launch."""
+        pools = self._pools
+        return pools is None or next(iter(pools.values()))[0].is_ready()
+
     def _register_hbm_owners(self):
         """Attribute this engine's device memory to named HBM-census
         owners (`telemetry.hbm`): the KV pool (+ page table, with the
@@ -972,7 +983,8 @@ class SlotDecoder:
         runs inside the launch span with the rest of the host's work.
         """
         jnp = _j().numpy
-        with tracing.phase("mx.serve.prefill.launch", "prefill_launch"):
+        with tracing.phase("mx.serve.prefill.launch",
+                           "prefill_launch") as launch:
             self._refresh_params()
             self._ensure_pool()
             if self._prefill_jit is None:
@@ -984,6 +996,7 @@ class SlotDecoder:
             args = (jnp.asarray(chunk)[None, :], pages, jnp.int32(t_start),
                     jnp.int32(n), key,
                     jnp.float32(max(float(temperature), 1e-6)))
+            launch.site()
             self._pools, first = self._prefill_jit(
                 self._dec._params, self._pools, *args, top_k=self._top_k,
                 do_sample=self._do_sample)
@@ -1060,26 +1073,37 @@ class SlotDecoder:
         `fetch_tokens` of it is the one host sync of a step, and whoever
         needs the tokens makes it (`Scheduler._land`). `key`: a PRNG key, or
         a callable that makes one (called inside the launch span, as in
-        `prefill_chunk_step`)."""
+        `prefill_chunk_step`). The launch is stamped in four parts
+        (`tracing.launch_phase`), each a span nested in
+        ``mx.serve.decode.launch`` and a step-record field
+        (`tracing.step_records`): prepare, key, upload, dispatch; the
+        dispatch is the launch site the dry account watches."""
         jnp = _j().numpy
-        with tracing.phase("mx.serve.decode.launch", "decode_launch"):
+        with tracing.launch_phase() as boundary:
+            # prepare (with the scheduler's work since the boundary before)
             self._refresh_params()
             self._ensure_pool()
             if self._decode_jit is None:
                 self._decode_jit = self._build_decode()
-            if callable(key):
-                key = key()
             if self._tokens is None:
                 self._tokens = self._pin_tokens(
                     jnp.zeros(self.max_slots + self.step_extra, jnp.int32))
-            # after the pools: the table, the launch's own copies of the
-            # host's arrays, and the tokens of the launch before
+            table = self._table_device()
+            boundary()                    # key: the eager fold_in
+            if callable(key):
+                key = key()
+            boundary()
+            # upload: the launch's own copies of the host's arrays, around
+            # the tokens of the launch before
+            args = (_upload(last_tok, onp.int32), self._tokens,
+                    _upload(pos, onp.int32), _upload(active, bool), key,
+                    _upload(temperature, onp.float32))
+            boundary()                    # dispatch: the launch site
             self._pools, self._tokens = self._decode_jit(
-                self._dec._params, self._pools, self._table_device(),
-                _upload(last_tok, onp.int32), self._tokens,
-                _upload(pos, onp.int32), _upload(active, bool), key,
-                _upload(temperature, onp.float32), top_k=self._top_k,
-                do_sample=self._do_sample)
+                self._dec._params, self._pools, table, *args,
+                top_k=self._top_k, do_sample=self._do_sample)
+            boundary()
+            # the counters: the launch's, in none of its four parts
             live = self._count_rows(
                 onp.asarray(pos, onp.int64)[onp.asarray(active, bool)])
             view = self.max_slots * self.pages_per_slot
@@ -1171,14 +1195,16 @@ class SlotDecoder:
     def spec_draft_step(self, last_tok, pos, active, limit):
         """Run the draft model's k-step program; returns drafted tokens
         ``(max_slots, spec_k)`` as host numpy."""
-        with tracing.phase("mx.serve.spec.draft.launch", "decode_launch"):
+        with tracing.phase("mx.serve.spec.draft.launch",
+                           "decode_launch") as launch:
             self._draft_dec._auto_refresh()
             self._ensure_pool()
             if self._draft_jit is None:
                 self._draft_jit = self._build_draft()
+            args = self._spec_args(last_tok, pos, active, limit)
+            launch.site()
             self._draft_pools, toks = self._draft_jit(
-                self._draft_dec._params, self._draft_pools,
-                *self._spec_args(last_tok, pos, active, limit))
+                self._draft_dec._params, self._draft_pools, *args)
         with tracing.phase("mx.serve.spec.draft.readback",
                            "decode_readback"):
             return onp.asarray(toks)
@@ -1191,7 +1217,8 @@ class SlotDecoder:
         ``[last, d_1..d_i]`` — the scheduler accepts the longest drafted
         prefix matching rows ``0..m-1`` plus row ``m`` as the bonus
         token (>= 1 token of guaranteed progress per round)."""
-        with tracing.phase("mx.serve.spec.verify.launch", "decode_launch"):
+        with tracing.phase("mx.serve.spec.verify.launch",
+                           "decode_launch") as launch:
             self._refresh_params()
             self._ensure_pool()
             if self._verify_jit is None:
@@ -1201,9 +1228,10 @@ class SlotDecoder:
             toks = onp.concatenate(
                 [onp.asarray(last_tok, onp.int32)[:, None],
                  onp.asarray(drafts, onp.int32)], axis=1)
+            args = self._spec_args(toks, pos, active, limit)
+            launch.site()
             self._pools, tgt = self._verify_jit(
-                self._dec._params, self._pools,
-                *self._spec_args(toks, pos, active, limit))
+                self._dec._params, self._pools, *args)
         with tracing.phase("mx.serve.spec.verify.readback",
                            "decode_readback"):
             return onp.asarray(tgt)

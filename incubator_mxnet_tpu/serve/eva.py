@@ -283,7 +283,7 @@ class EvaSlotDecoder(SlotDecoder):
         in order) into summary rows in `new_pages`. Launched, not waited
         for: the program that next reads the pool runs after it."""
         jnp = _j().numpy
-        with tracing.phase("mx.serve.eva.roll", "eva_roll"):
+        with tracing.phase("mx.serve.eva.roll", "eva_roll") as launch:
             self._ensure_pool()
             if self._roll_jit is None:
                 self._roll_jit = self._build_roll()
@@ -295,9 +295,10 @@ class EvaSlotDecoder(SlotDecoder):
                     f"and {len(new_pages)}")
             features = tuple((lp["phi"], lp["mu"])
                              for lp in self._dec._params["layers"])
-            self._pools = self._roll_jit(
-                features, self._pools, jnp.asarray(win_pages, jnp.int32),
-                jnp.asarray(new_pages, jnp.int32))
+            pages = (jnp.asarray(win_pages, jnp.int32),
+                     jnp.asarray(new_pages, jnp.int32))
+            launch.site()
+            self._pools = self._roll_jit(features, self._pools, *pages)
             self._rolled[slot] += 1
             ROLLS.inc()
 

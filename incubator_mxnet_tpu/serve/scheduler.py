@@ -143,6 +143,19 @@ STEP_SECONDS = {
         "Scheduler.step (idle: the driver's back-off sleep)",
         labels={"phase": ph})
     for ph in tracing.PHASES + ("idle",)}
+# the step records' dry intervals as running totals (`tracing.DRY_CAUSES`):
+# over wall time, chunk_fetch + cold_fetch + late_launch is the share of the
+# chip the host wastes, no_work its spare capacity
+DEVICE_DRY = {
+    cause: registry.counter(
+        "mx_serve_device_dry_seconds_total",
+        "seconds the device had nothing queued, by cause: chunk_fetch (a "
+        "prompt's final chunk fetched), cold_fetch (a decode step fetched "
+        "with none launched behind it), late_launch (a launch found its "
+        "queue already finished: a lower bound), no_work (the engine was "
+        "empty)",
+        labels={"cause": cause})
+    for cause in tracing.DRY_CAUSES}
 SUBMIT_LOCK_WAIT = registry.histogram(
     "mx_serve_submit_lock_wait_seconds",
     "entry of ServeEngine.submit to the request queued: the wait for the "
@@ -351,6 +364,11 @@ class Scheduler:
         # the decode step launched and not yet fetched: its tokens (a
         # device array) and its rows ``[(slot, request)]``
         self._flight = None
+        # the open dry interval ``[t0, cause]`` (t0 None: none open), shared
+        # with each iteration's step clock (`StepClock.watch_dry`); a slots
+        # object that computes on the host has no `device_dry` and no account
+        self._dry = [None, None]
+        self._device_dry = getattr(slots, "device_dry", None)
         self._next_id = 0
         self.closed = False
         # cost-ledger identity (the gateway stamps the model name) and
@@ -528,6 +546,13 @@ class Scheduler:
         # zero-steady-state-recompile guarantee is untouched
         with tracing.StepClock(waited_since, queued=len(self._queue),
                                active=self._n_active) as clock:
+            if self._device_dry is not None:
+                # a launch site that finds the device dry: the host was
+                # late, unless this iteration began with an empty engine
+                clock.watch_dry(
+                    self._dry, self._device_dry,
+                    "no_work" if not self._n_active and self._flight is None
+                    else "late_launch")
             with tracing.phase("mx.serve.admit", "admit"):
                 inject_at("serve_step")
                 now = time.monotonic()
@@ -538,6 +563,9 @@ class Scheduler:
             decoded = self._decode_once(clock)
             with tracing.phase("mx.serve.emit", "emit"):
                 self._refresh_gauges()
+            if self._dry[0] is not None and not self._n_active \
+                    and not self._queue:
+                self._dry[1] = "no_work"    # whatever opened it: nothing to run
             clock.progressed = bool(admitted or chunks or decoded)
             # the decode launch counted its rows and pages on the clock,
             # the fetch its overshoot rows
@@ -550,7 +578,16 @@ class Scheduler:
         for ph, seconds in clock.seconds.items():
             if seconds:
                 STEP_SECONDS[ph].inc(seconds)
+        for t0, t1, cause in clock.dry:
+            DEVICE_DRY[cause].inc(t1 - t0)
         return clock.progressed
+
+    def _dry_opens(self, cause):
+        """A blocking fetch just returned (the last boundary's stamp) and
+        nothing was launched behind what it fetched: the device has nothing
+        queued until the next launch returns (`StepClock.launched`)."""
+        if self._device_dry is not None and self._dry[0] is None:
+            self._dry[:] = tracing.stamp(), cause
 
     def _accrue_page_seconds(self, t):
         """Cost-ledger seam: resident KV pages × seconds since the last
@@ -670,6 +707,7 @@ class Scheduler:
                                    "prefill_readback"):
                     first = self.slots.fetch_first(first)  # blocks: it ran
                 w1 = clock.cursor
+                self._dry_opens("chunk_fetch")    # the queue drained with it
             else:
                 w1 = clock.lap("prefill_launch")
             PREFILL_FETCHES["final" if last_chunk else "skipped"].inc()
@@ -757,6 +795,10 @@ class Scheduler:
         launched = None
         if self._n_decoding:
             clock.counts["mode"] = self._launch()
+            # `_launch`'s tail (the rows' bookkeeping, slots freed in
+            # advance) is the launch's, and of its parts the bookkeeping
+            # one: the read-back that follows is the blocking fetch alone
+            clock.lap("decode_launch", "launch_prepare")
             launched = self._flight
             if landing is None and isinstance(launched[0], onp.ndarray):
                 # a slots object that computes on the host: its tokens
@@ -784,7 +826,11 @@ class Scheduler:
         token on its way is its ``max_new``-th leaves its slot at once
         (no row, no write and no key are spent on a step it cannot use;
         the programs that may touch its pages again are queued behind
-        this one). Returns the mode."""
+        this one). Returns the mode. What follows `decode_step` here (the
+        rows' bookkeeping) is charged to ``decode_launch`` by the caller's
+        boundary, and of the launch's four parts (`tracing.LAUNCH_PARTS`)
+        to ``launch_prepare``, which holds the host's bookkeeping on both
+        sides of the call."""
         ahead = self._flight
         if self._lazy_pages:
             for s, req in enumerate(self._in_slot):
@@ -827,6 +873,8 @@ class Scheduler:
         with tracing.phase("mx.serve.decode.readback", "decode_readback"):
             tokens = self.slots.fetch_tokens(tokens)  # blocks: it ran
         fetched = tracing.stamp()
+        if self._flight is None:
+            self._dry_opens("cold_fetch")     # nothing launched behind it
         with tracing.phase("mx.serve.emit", "emit"):
             now = time.monotonic()
             for s, req in rows:
@@ -972,6 +1020,7 @@ class Scheduler:
         targets = self.slots.spec_verify_step(
             self._last, drafts, self._pos, self._active, limit)
         w1 = clock.lap("decode_readback")
+        self._dry_opens("cold_fetch")     # the round fetched what it launched
         tracing.record_span("serve.spec_verify", w_mid, w1,
                             slots=self._n_decoding, k=k)
         spec_dt = self._charge_decode(w0, w1)

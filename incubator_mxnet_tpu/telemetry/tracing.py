@@ -71,9 +71,10 @@ from .locks import tracked_lock
 
 __all__ = ["Span", "Tracer", "enable", "disable", "is_enabled", "span",
            "open_span", "record_span", "event", "annotate", "current_span",
-           "StepClock", "phase", "stamp",
+           "StepClock", "phase", "launch_phase", "stamp",
            "add_request_record", "count", "step_records", "request_records",
-           "PHASES", "STEP_RING_CAPACITY", "REQUEST_RING_CAPACITY",
+           "PHASES", "LAUNCH_PARTS", "DRY_CAUSES", "STEP_RING_CAPACITY",
+           "REQUEST_RING_CAPACITY",
            "current_trace_id", "new_trace_id", "finished_spans",
            "open_spans", "reset", "chrome_events", "chrome_trace",
            "dump_chrome", "flight_dump", "maybe_flight_dump",
@@ -86,6 +87,15 @@ REQUEST_RING_CAPACITY = 4096  # request records kept
 # the phases of one serving iteration, as a step record names them
 PHASES = ("lock_wait", "admit", "prefill_launch", "prefill_readback",
           "decode_launch", "decode_readback", "emit", "eva_roll")
+# the parts of ``decode_launch``, stamped inside it (`launch_phase`): step
+# record fields beside the phases, in no series and not in `PHASES`
+LAUNCH_PARTS = ("launch_prepare", "launch_key", "launch_upload",
+                "launch_dispatch")
+# why the device had nothing queued (`StepClock.watch_dry`): a second axis
+# over the same wall, step record fields ``dry_<cause>``, never phases
+DRY_CAUSES = ("chunk_fetch", "cold_fetch", "late_launch", "no_work")
+_NO_PARTS = dict.fromkeys(LAUNCH_PARTS, 0.0)
+_NO_DRY = {"dry_" + cause: 0.0 for cause in DRY_CAUSES}
 
 _ENABLED = False
 _LOCK = tracked_lock("telemetry.tracing", kind="lock")
@@ -401,17 +411,26 @@ class StepClock:
     ``serve.step`` span (`attrs` are its attributes) takes its start and
     end from the same two readings. While the clock is open it is the
     calling thread's current one: `phase()` / `stamp()` beneath find it.
-    An iteration that set ``progressed`` leaves one step record."""
+    An iteration that set ``progressed`` leaves one step record.
 
-    __slots__ = ("t_start", "t_end", "cursor", "seconds", "counts",
-                 "progressed", "span", "_open", "_note", "_prev")
+    Beside the phases, and in none of them: ``parts`` (`LAUNCH_PARTS`, the
+    boundaries inside ``decode_launch``: `launch_phase`) and ``dry``, the
+    intervals ``[t0, t1, cause]`` in which the device had nothing queued
+    and which a launch of this iteration ended (`watch_dry`)."""
+
+    __slots__ = ("t_start", "t_end", "cursor", "seconds", "parts", "dry",
+                 "counts", "progressed", "span", "_open", "_note", "_prev",
+                 "_dry", "_probe", "_cause")
 
     def __init__(self, waited_since=None, **attrs):
         self._open = (waited_since, attrs)
         self.counts = {}
         self.progressed = False
         self.seconds = dict.fromkeys(PHASES, 0.0)
+        self.parts = _NO_PARTS.copy()
+        self.dry = []
         self.t_end = None
+        self._dry = None
 
     def __enter__(self):
         waited_since, attrs = self._open
@@ -434,20 +453,55 @@ class StepClock:
         _TLS.clock = self._prev
         if self.progressed and exc_type is None:
             rec = {"t_start": self.t_start,
-                   "wall": self.t_end - self.t_start}
-            rec.update(self.seconds)
-            rec.update(self.counts)
+                   "wall": self.t_end - self.t_start, **self.seconds,
+                   **self.parts, **_NO_DRY, "dry": self.dry, **self.counts}
+            for t0, t1, cause in self.dry:
+                rec["dry_" + cause] += t1 - t0
             with _LOCK:
                 _STEPS.append(rec)
         return False
 
-    def lap(self, field):
-        """A boundary: charge `field` the time since the last one.
-        Returns the stamp."""
+    def lap(self, field, part=None):
+        """A boundary: charge `field` (and `part`, one of `LAUNCH_PARTS`
+        inside it) the time since the last one. Returns the stamp."""
         now = time.perf_counter()
         self.seconds[field] += now - self.cursor
+        if part is not None:
+            self.parts[part] += now - self.cursor
         self.cursor = now
         return now
+
+    def watch_dry(self, state, probe, cause):
+        """Arm this iteration's account of when the device stood dry.
+        `state` is the engine's open interval ``[t0, cause]`` (``t0`` None:
+        none open), a list its owner keeps between iterations and opens
+        itself where a blocking fetch returns with nothing queued behind it;
+        `probe()` says, without blocking, whether everything the engine
+        launched has finished; `cause` is what an interval that a launch
+        site opens (`launching`) is charged to."""
+        self._dry, self._probe, self._cause = state, probe, cause
+
+    def launching(self):
+        """A launch site, at the boundary just taken and before its jitted
+        call: if no interval is open and the device has nothing left to run,
+        one opens here. The device went dry at some moment before this
+        boundary, which nothing read: the interval is a lower bound."""
+        state = self._dry
+        if state is not None and state[0] is None and self._probe():
+            state[:] = self.cursor, self._cause
+
+    def launched(self):
+        """The jitted call returned (the boundary its phase just took): the
+        device has work again, and an open interval closes at that stamp,
+        charged to this iteration."""
+        state = self._dry
+        if state is not None:
+            if state[0] is not None:
+                self.dry.append([state[0], self.cursor, state[1]])
+                state[0] = None
+            # from here on the iteration has launched: a launch site that
+            # finds the device dry again was late, whatever came before
+            self._cause = "late_launch"
 
 
 class phase:
@@ -459,12 +513,14 @@ class phase:
     profiler's trace); at exit `field` is charged on the thread's open
     `StepClock` (none open, or no field: no reading). `timed`: stamp both
     ends itself and keep ``seconds`` (a phase outside any step, like the
-    driver's back-off sleep)."""
+    driver's back-off sleep). A phase that queues work on the device marks
+    the place with `site()`, just before its jitted call."""
 
-    __slots__ = ("_field", "_note", "_t0", "seconds")
+    __slots__ = ("_field", "_site", "_note", "_t0", "seconds")
 
     def __init__(self, name, field=None, timed=False):
         self._field = field
+        self._site = False
         self._note = _note(name)
         self._t0 = timed or None          # None: untimed; else the start
         self.seconds = None
@@ -475,15 +531,88 @@ class phase:
             self._t0 = time.perf_counter()
         return self
 
+    def site(self):
+        """The launch site: what follows is the phase's jitted call. One
+        boundary of its own (charged to the phase's field like its end), at
+        which the clock's dry account looks (`StepClock.launching`); the
+        phase's end then closes what is open (`launched`)."""
+        clock = getattr(_TLS, "clock", None)
+        if clock is not None and self._field is not None:
+            clock.lap(self._field)
+            clock.launching()
+            self._site = True
+
     def __exit__(self, exc_type, exc, tb):
         if self._field is not None:
             clock = getattr(_TLS, "clock", None)
             if clock is not None:
                 clock.lap(self._field)
+                if self._site:
+                    clock.launched()
         if self._t0 is not None:
             self.seconds = time.perf_counter() - self._t0
         self._note.__exit__(None, None, None)
         return False
+
+
+_LAUNCH = "mx.serve.decode.launch"
+_LAUNCH_SPANS = tuple(f"{_LAUNCH}.{part[len('launch_'):]}"
+                      for part in LAUNCH_PARTS)
+_LAUNCH_SITE = LAUNCH_PARTS.index("launch_dispatch")   # the jitted call
+
+
+class launch_phase(phase):
+    """``mx.serve.decode.launch`` with its four parts stamped inside it::
+
+        with tracing.launch_phase() as boundary:
+            ...                 # prepare: parameters, pools, the page table
+            boundary()
+            ...                 # key: the key maker's eager fold_in
+            boundary()
+            ...                 # upload: the host arrays' copies
+            boundary()          # the launch site
+            ...                 # dispatch: the jitted call
+            boundary()
+            ...                 # the launch's tail: in no part
+
+    Each part is a `TraceAnnotation` ``mx.serve.decode.launch.<part>`` nested
+    in the phase's own, and each ``boundary()`` one reading of the open
+    clock, charged to ``decode_launch`` and to that one of `LAUNCH_PARTS`.
+    The third boundary is where the dry account looks (the end of the
+    uploads, just before the jitted call), the fourth where it closes."""
+
+    __slots__ = ("_clock", "_at", "_part")
+
+    def __init__(self):
+        super().__init__(_LAUNCH, "decode_launch")
+
+    def __enter__(self):
+        super().__enter__()
+        self._clock = getattr(_TLS, "clock", None)
+        self._at = 0
+        self._part = _note(_LAUNCH_SPANS[0])
+        self._part.__enter__()
+        return self._boundary
+
+    def _boundary(self):
+        at, clock = self._at, self._clock
+        self._part.__exit__(None, None, None)
+        self._part = None
+        if clock is not None:
+            clock.lap(self._field, LAUNCH_PARTS[at])
+            if at == _LAUNCH_SITE:
+                clock.launched()
+        self._at = at = at + 1
+        if at < len(LAUNCH_PARTS):
+            self._part = _note(_LAUNCH_SPANS[at])
+            self._part.__enter__()
+            if at == _LAUNCH_SITE and clock is not None:
+                clock.launching()
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._part is not None:        # a part that raised
+            self._part.__exit__(None, None, None)
+        return super().__exit__(exc_type, exc, tb)
 
 
 def stamp():
@@ -530,10 +659,47 @@ def step_records(since=None, until=None):
     ``max_slots x pages_per_slot`` — what decode's attention reads,
     against what a gathered view of every slot holds); a family may add
     counts of its own (``moe_*``: `serve/mla.py`; ``state_resets``:
-    `serve/ssm.py`). The ring is the
-    module's, not the engine's: it
-    outlives shutdown and deletion of whatever wrote it, holds the newest
-    `STEP_RING_CAPACITY` records and drops the oldest."""
+    `serve/ssm.py`).
+
+    ``mode`` says how the iteration's decode launch was made: ``"ahead"``,
+    queued behind a step the host had not fetched yet (the device goes from
+    one to the next if the host was in time); ``"cold"``, with nothing in
+    flight (the device waits for this launch); None, no decode launch (the
+    iteration may still have fetched one). ``overshoot`` counts the rows of
+    the step fetched here whose request had already ended when they ran
+    (an EOS is learnt a step late; their tokens are dropped).
+
+    The seconds of `LAUNCH_PARTS` split ``decode_launch`` where the work
+    happens (`SlotDecoder.decode_step`): ``launch_prepare`` is the host's
+    bookkeeping, from the boundary before the launch to the key (the
+    scheduler's page mapping, its copy of the last tokens and its row
+    list; parameter refresh, pools, the page table) and again after the
+    call (the scheduler's update of the rows it launched); ``launch_key``
+    is the key maker's eager ``fold_in``, ``launch_upload`` the host
+    arrays' copies, ``launch_dispatch`` the jitted call from entry to
+    return. They add up to ``decode_launch`` less the engine's counters
+    after the call.
+
+    ``dry`` lists the intervals ``[t0, t1, cause]`` (``perf_counter``
+    seconds, usually none) in which the device had nothing queued and which
+    a launch of this iteration ended; ``dry_<cause>`` are their seconds by
+    `DRY_CAUSES`: ``chunk_fetch`` (a prompt's final chunk was fetched: the
+    queue drained to hand out a first token), ``cold_fetch`` (a decode
+    step was fetched with none launched behind it), ``late_launch`` (a
+    launch site found everything it had queued already finished: opened at
+    the boundary taken there, so a lower bound on the time the device
+    stood dry) and ``no_work`` (no slot occupied and the queue empty: the
+    interval runs across the driver's back-off until the next launch). A
+    second axis over the same wall: in no phase, not in `PHASES`, and an
+    interval is charged once, to the iteration that closed it, wherever it
+    began. ``mode == "ahead"`` goes with ``dry_cold_fetch`` and
+    ``dry_no_work`` of 0.0 (something was in flight); a ``"cold"`` launch
+    ends an interval of one of the causes. A slots object that computes on
+    the host keeps no such account (all 0.0).
+
+    The ring is the module's, not the engine's: it outlives shutdown and
+    deletion of whatever wrote it, holds the newest `STEP_RING_CAPACITY`
+    records and drops the oldest."""
     return _window(_STEPS, "t_start", since, until)
 
 

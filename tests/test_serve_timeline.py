@@ -108,7 +108,8 @@ def test_every_progressed_step_leaves_one_record(served):
     assert set(recs[0]) == {"t_start", "wall", "chunks", "decoding",
                             "prefilling", "queued", "pages_live",
                             "pages_view", "mode", "overshoot",
-                            *tracing.PHASES}
+                            *tracing.PHASES, *tracing.LAUNCH_PARTS, "dry",
+                            *("dry_" + c for c in tracing.DRY_CAUSES)}
 
 
 def test_phases_are_non_negative_and_add_up_to_at_most_wall(served):
@@ -363,10 +364,13 @@ def _reads_of_a_decode_only_step(count_clock_reads):
 
 
 def test_a_decode_only_step_reads_the_clock_once_a_boundary(count_clock_reads):
-    """Start, end of admit, return of the decode call, end of emit, end of
-    the step's own emit, end: six stamps in tracing; in the scheduler the
-    two monotonic readings of deadlines and TTFT and nothing else."""
-    assert _reads_of_a_decode_only_step(count_clock_reads) == (6, 2)
+    """Start, end of admit, return of the decode call, end of `_launch`'s
+    tail (ISSUE 37: the rows' bookkeeping is the launch's, so that the
+    read-back is the fetch alone), end of emit, end of the step's own emit,
+    end: seven stamps in tracing; in the scheduler the two monotonic
+    readings of deadlines and TTFT and nothing else. (The stand-in computes
+    on the host: no launch parts, no dry account.)"""
+    assert _reads_of_a_decode_only_step(count_clock_reads) == (7, 2)
 
 
 def test_arming_the_ledgers_adds_no_clock_read_to_the_scheduler(
@@ -494,3 +498,371 @@ def test_spec_decode_path_is_stamped_and_annotated(net, tmp_path):
     assert rounds and all(r["decode_launch"] > 0.0 < r["decode_readback"]
                           for r in rounds)
     assert all(sum(r[ph] for ph in PHASES) >= 0.9 * r["wall"] for r in rounds)
+
+
+# -- dry intervals and the launch's four parts (ISSUE 37) ----------------------
+
+class _Dev:
+    """What a launch returns until it is fetched: not a host array."""
+
+    def __init__(self, value):
+        self.value = value
+
+
+class _DrySlots(_StubSlots):
+    """`_StubSlots` that launches like `SlotDecoder` (the phases the dry
+    account watches, tokens fetched by the scheduler) and whose
+    `device_dry()` the test drives: ``dry`` is what the next probes say."""
+
+    def __init__(self, dry=False):
+        super().__init__()
+        self.dry, self.probes, self.fetches = dry, 0, 0
+        self._prev = onp.zeros(self.max_slots, onp.int32)
+
+    def device_dry(self):
+        self.probes += 1
+        return self.dry
+
+    def prefill_chunk_step(self, slot, chunk_tokens, t_start, key,
+                           temperature=1.0):
+        with tracing.phase("mx.serve.prefill.launch",
+                           "prefill_launch") as launch:
+            launch.site()
+            return (_Dev(int(t_start) + len(chunk_tokens)),
+                    len(chunk_tokens), 0)
+
+    def decode_step(self, last, pos, active, key, temps):
+        with tracing.launch_phase() as boundary:
+            boundary()                        # prepare
+            boundary()                        # key
+            boundary()                        # upload: the launch site
+            last = onp.where(last < 0, self._prev, last)
+            self._prev = onp.where(active, last + 1, last).astype(onp.int32)
+            boundary()                        # dispatch
+        return _Dev(self._prev)
+
+    def fetch_tokens(self, out):
+        self.fetches += 1
+        return out.value
+
+    def fetch_first(self, out):
+        self.fetches += 1
+        return out.value
+
+
+def _dry_sched(**kw):
+    slots = _DrySlots(**kw)
+    return sched_mod.Scheduler(slots, max_queue=8), slots
+
+
+def _intervals(recs=None):
+    recs = tracing.step_records() if recs is None else recs
+    return [iv for r in recs for iv in r["dry"]]
+
+
+def test_a_final_chunks_fetch_opens_chunk_fetch_and_the_next_launch_closes():
+    """Opens at the stamp at which `fetch_first` returned, closes at the
+    stamp the decode launch's dispatch took: both boundaries the clock held
+    anyway, and the record is the iteration's that closed it."""
+    sched, slots = _dry_sched()
+    sched.submit(_prompt(4), 8)
+    assert sched.step() is True
+    (r,) = tracing.step_records()
+    ((t0, t1, cause),) = r["dry"]
+    assert cause == "chunk_fetch" and r["mode"] == "cold"
+    fetched = r["t_start"] + r["admit"] + r["prefill_launch"] \
+        + r["prefill_readback"]
+    assert t0 == pytest.approx(fetched, abs=1e-7)
+    # emit (`_prompt_done`), then the launch up to its dispatch's return
+    assert t1 - t0 <= r["emit"] + r["decode_launch"] + 1e-9
+    assert t1 - t0 >= r["launch_key"] + r["launch_upload"] \
+        + r["launch_dispatch"] - 1e-9
+    assert r["dry_chunk_fetch"] == pytest.approx(t1 - t0)
+    assert r["dry_cold_fetch"] == r["dry_late_launch"] == r["dry_no_work"] \
+        == 0.0
+
+
+def test_a_fetch_with_nothing_behind_it_opens_cold_fetch_while_slots_decode():
+    """`settle` fetches the step in flight outside any iteration: the
+    device has nothing queued until the next (cold) launch returns."""
+    sched, slots = _dry_sched()
+    sched.submit(_prompt(4), 8)
+    sched.step()
+    sched.step()                              # a step in flight, ahead
+    tracing.reset()
+    before = time.perf_counter()
+    sched.settle()
+    after = time.perf_counter()
+    assert sched._flight is None and sched._dry[1] == "cold_fetch"
+    assert sched.step() is True
+    (r,) = tracing.step_records()
+    ((t0, t1, cause),) = r["dry"]
+    assert cause == "cold_fetch" and r["mode"] == "cold"
+    assert before <= t0 <= after < r["t_start"] < t1
+    assert r["dry_cold_fetch"] == pytest.approx(t1 - t0) and r["chunks"] == 0
+
+
+def test_a_launch_that_finds_the_device_dry_opens_late_launch():
+    """Mode ``ahead``, and the step in flight had finished all the same:
+    the interval opens at the end of `launch.upload` (the boundary just
+    taken, a lower bound) and closes where the dispatch returned, so it is
+    exactly ``launch_dispatch`` long."""
+    sched, slots = _dry_sched()
+    sched.submit(_prompt(4), 8)
+    sched.step()
+    tracing.reset()
+    slots.dry = True
+    assert sched.step() is True
+    (r,) = tracing.step_records()
+    ((t0, t1, cause),) = r["dry"]
+    assert cause == "late_launch" and r["mode"] == "ahead"
+    assert t1 - t0 == pytest.approx(r["launch_dispatch"], abs=1e-9)
+    assert r["dry_late_launch"] == pytest.approx(t1 - t0)
+    assert r["dry_cold_fetch"] == r["dry_no_work"] == 0.0
+    slots.dry = False
+    sched.step()
+    assert tracing.step_records()[-1]["dry"] == []      # in time: none
+
+
+def test_an_empty_engine_is_no_work_across_iterations_and_charged_once():
+    """The last fetch leaves nothing to run: whatever opened the interval,
+    it is ``no_work``; it stays open over iterations that make no progress
+    (they leave no record) and is charged, whole, to the iteration whose
+    launch closed it."""
+    sched, slots = _dry_sched()
+    sched.submit(_prompt(4), 2)
+    while sched.step():
+        pass
+    assert sched._dry == [pytest.approx(sched._dry[0]), "no_work"]
+    opened = sched._dry[0]
+    assert all(iv[2] != "no_work" for iv in _intervals())
+    for _ in range(3):
+        assert sched.step() is False          # nothing to do: no record
+    time.sleep(0.01)
+    tracing.reset()
+    sched.submit(_prompt(4, seed=1), 2)
+    assert sched.step() is True
+    r = tracing.step_records()[0]
+    t0, t1, cause = r["dry"][0]
+    assert cause == "no_work" and t0 == opened < r["t_start"] < t1
+    assert r["dry_no_work"] == pytest.approx(t1 - t0) and t1 - t0 > 0.01
+    while sched.step():
+        pass
+    assert [iv[2] for iv in _intervals()].count("no_work") == 1
+
+
+def test_the_first_launch_of_an_idle_engine_is_no_work_the_next_late():
+    """Nothing was ever fetched, so nothing is open: the first launch site
+    finds the device dry and the iteration began with an empty engine
+    (``no_work``); once it has launched, a launch site that finds the device
+    dry again was late."""
+    sched, slots = _dry_sched(dry=True)
+    sched.submit(_prompt(4), 4)
+    sched.submit(_prompt(4, seed=1), 4)
+    sched.step()
+    causes = [iv[2] for iv in _intervals()]
+    assert causes[0] == "no_work"
+    assert set(causes[1:]) <= {"late_launch", "chunk_fetch"}
+    assert "late_launch" in causes or causes.count("chunk_fetch") == 2
+
+
+def test_every_interval_is_closed_in_order_and_inside_its_record():
+    sched, slots = _dry_sched()
+    for i in range(4):
+        sched.submit(_prompt(4 + i, seed=i), 3 + i)
+    flip = 0
+    while sched.step():
+        flip += 1
+        slots.dry = flip % 3 == 0
+    recs = tracing.step_records()
+    ivs = _intervals(recs)
+    assert len(ivs) >= 4
+    assert all(t0 < t1 for t0, t1, _ in ivs)
+    assert [iv[1] for iv in ivs] == sorted(iv[1] for iv in ivs)
+    # no two overlap: one interval is open at a time
+    assert all(a[1] <= b[0] for a, b in zip(ivs, ivs[1:]))
+    for r in recs:
+        for t0, t1, cause in r["dry"]:
+            assert cause in tracing.DRY_CAUSES
+            assert r["t_start"] <= t1 <= r["t_start"] + r["wall"]
+
+
+@pytest.mark.parametrize("cause", tracing.DRY_CAUSES)
+def test_dry_series_equals_the_records_sums(cause):
+    registry.reset()
+    sched, slots = _dry_sched()
+    sched.submit(_prompt(4), 3)
+    flip = 0
+    while sched.step():
+        flip += 1
+        slots.dry = flip % 2 == 1
+    sched.submit(_prompt(5, seed=2), 6)
+    sched.step()
+    sched.step()
+    sched.settle()
+    while sched.step():
+        pass
+    recs = tracing.step_records()
+    by_field = sum(r["dry_" + cause] for r in recs)
+    by_interval = sum(t1 - t0 for t0, t1, c in _intervals(recs) if c == cause)
+    assert by_field == pytest.approx(by_interval)
+    assert sched_mod.DEVICE_DRY[cause].value == pytest.approx(by_field)
+    rep = registry.report()
+    assert f'mx_serve_device_dry_seconds_total{{cause="{cause}"}}' in rep
+    if cause != "cold_fetch":
+        assert by_field > 0.0
+
+
+def test_dry_and_launch_fields_are_not_phases():
+    """A second axis over the same wall: not in `PHASES`, not in the clock's
+    ``seconds``, not in `mx_serve_step_seconds_total` (the share of a step's
+    wall its phases account for would count them twice)."""
+    fields = {"dry_" + c for c in tracing.DRY_CAUSES} \
+        | set(tracing.LAUNCH_PARTS)
+    assert not fields & set(tracing.PHASES)
+    assert set(sched_mod.STEP_SECONDS) == set(tracing.PHASES) | {"idle"}
+    assert set(sched_mod.DEVICE_DRY) == set(tracing.DRY_CAUSES)
+    sched, slots = _dry_sched(dry=True)
+    sched.submit(_prompt(4), 4)
+    with tracing.StepClock() as probe:
+        pass
+    assert set(probe.seconds) == set(tracing.PHASES)
+    while sched.step():
+        pass
+    for r in tracing.step_records():
+        assert sum(r[ph] for ph in PHASES) <= r["wall"] + 1e-9, r
+    assert sum(r["dry_late_launch"] for r in tracing.step_records()) > 0.0
+
+
+def test_the_dry_account_adds_no_fetch_and_probes_once_a_launch():
+    """The probe is the only thing the account asks of the device, once a
+    launch site while no interval is open; every fetch is one the loop made
+    before (a first token a request, the tokens of each decode step)."""
+    sched, slots = _dry_sched()
+    sched.submit(_prompt(4), 6)
+    sched.step()                              # chunk + fetch + cold launch
+    assert (slots.probes, slots.fetches) == (1, 1)   # open at the launch: 0
+    sched.step()                              # ahead: one probe, one fetch
+    assert (slots.probes, slots.fetches) == (2, 2)
+    import inspect
+    from incubator_mxnet_tpu.serve import engine as engine_mod
+    src = inspect.getsource(engine_mod.SlotDecoder.device_dry)
+    assert "is_ready()" in src
+    assert "block_until_ready" not in src and "asarray" not in src
+    assert "block_until_ready" not in inspect.getsource(tracing)
+    assert "block_until_ready" not in inspect.getsource(sched_mod)
+
+
+def test_a_host_computing_stand_in_keeps_no_dry_account():
+    sched = sched_mod.Scheduler(_StubSlots(), max_queue=4)
+    sched.submit(_prompt(4), 4)
+    while sched.step():
+        pass
+    recs = tracing.step_records()
+    assert all(r["dry"] == [] for r in recs)
+    assert all(r["dry_" + c] == 0.0 for r in recs for c in tracing.DRY_CAUSES)
+    assert sched._dry == [None, None]
+
+
+def test_launch_parts_of_a_stand_in_add_up_to_the_launch():
+    """The four parts and nothing else between the launch's first and last
+    boundary but the engine's counters; the scheduler's bookkeeping of the
+    rows it launched is ``launch_prepare``'s."""
+    sched, slots = _dry_sched()
+    sched.submit(_prompt(4), 6)
+    while sched.step():
+        pass
+    launched = [r for r in tracing.step_records() if r["decoding"]]
+    assert launched
+    for r in launched:
+        parts = sum(r[p] for p in tracing.LAUNCH_PARTS)
+        assert all(r[p] > 0.0 for p in tracing.LAUNCH_PARTS)
+        assert parts <= r["decode_launch"] + 1e-9
+        assert r["decode_launch"] - parts < 2e-4       # one phase's exit
+
+
+# the real engine on the CPU
+
+def test_real_launch_parts_add_up_to_decode_launch(served):
+    recs = [r for r in tracing.step_records() if r["decoding"]]
+    for r in recs:
+        assert all(r[p] > 0.0 for p in tracing.LAUNCH_PARTS), r
+        assert sum(r[p] for p in tracing.LAUNCH_PARTS) \
+            <= r["decode_launch"] + 1e-9
+    rest = [r for r in tracing.step_records() if not r["decoding"]]
+    assert all(r[p] == 0.0 for r in rest for p in tracing.LAUNCH_PARTS)
+    # leaving out the first launch (it builds and compiles the program)
+    parts = sum(r[p] for r in recs[1:] for p in tracing.LAUNCH_PARTS)
+    assert parts >= 0.8 * sum(r["decode_launch"] for r in recs[1:])
+
+
+def test_mode_and_the_dry_causes_agree(served):
+    """``ahead``: something was in flight, so no interval that a fetch with
+    nothing behind it or an empty engine opened can end there; ``cold``: the
+    device waited for this launch, and the record says since when."""
+    recs = tracing.step_records()
+    for r in recs:
+        if r["mode"] == "ahead":
+            assert r["dry_cold_fetch"] == r["dry_no_work"] == 0.0, r
+        if r["mode"] == "cold":
+            assert r["dry"], r
+            assert r["dry_chunk_fetch"] + r["dry_cold_fetch"] \
+                + r["dry_no_work"] > 0.0, r
+    assert any(r["mode"] == "cold" for r in recs)
+    assert all(t0 < t1 for t0, t1, _ in _intervals(recs))
+
+
+def test_a_real_final_chunk_yields_exactly_one_chunk_fetch_interval(net):
+    """GPT-2 tiny through `ServeEngine`: a 40-token prompt alone is three
+    chunks, and only the last is fetched: one drain, in that iteration."""
+    eng = _engine(net)
+    try:
+        eng.generate(PROMPTS[0], 2)           # compiled, pools made
+        tracing.reset()
+        h = eng.submit(PROMPTS[1], 3)
+        while not h.done:
+            eng.step()
+    finally:
+        eng.shutdown(drain=False)
+    recs = tracing.step_records()
+    drains = [(r, iv) for r in recs for iv in r["dry"]
+              if iv[2] == "chunk_fetch"]
+    assert len(drains) == 1
+    r, (t0, t1, _) = drains[0]
+    assert r["prefill_readback"] > 0.0 and r["mode"] == "cold"
+    assert r["t_start"] < t0 < t1 <= r["t_start"] + r["wall"]
+    # the engine had been empty before the request came
+    assert recs[0]["dry"][0][2] == "no_work"
+
+
+def _reads_of_a_real_decode_only_step(net, count_clock_reads):
+    eng = _engine(net)
+    try:
+        sched = eng._sched
+        probes = []
+        dry = sched._device_dry
+        sched._device_dry = lambda: probes.append(1) or dry()
+        sched.submit(PROMPTS[0], 8)
+        sched.step()                          # admit + chunk + cold launch
+        sched.step()                          # ahead: programs compiled
+        clocks = count_clock_reads(tracing), count_clock_reads(sched_mod)
+        del probes[:]
+        assert sched.step() is True           # decode only
+        r = tracing.step_records()[-1]
+        assert r["chunks"] == 0 and r["mode"] == "ahead"
+        return clocks[0].reads, clocks[1].reads, len(probes)
+    finally:
+        eng.shutdown(drain=False)
+
+
+def test_a_real_decode_only_step_reads_the_clock_twelve_times(
+        net, count_clock_reads):
+    """The parent's seven (start, end of admit, end of the launch, end of
+    the read-back, end of emit twice, end; the stand-in above has no launch
+    phase of its own) and five more: the ends of `launch.prepare`, `.key`,
+    `.upload` and `.dispatch`, and the end of `_launch`'s tail. At most
+    seven more were allowed, and one `is_ready()`."""
+    reads, sched_reads, probes = _reads_of_a_real_decode_only_step(
+        net, count_clock_reads)
+    assert reads == 7 + 5 <= 7 + 7
+    assert sched_reads == 2 and probes == 1
