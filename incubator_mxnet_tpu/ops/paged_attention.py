@@ -84,12 +84,19 @@ one operand left in HBM, one copy a live page into one of `_BUFFERS`
 each pipelined through its own `BlockSpec`, cost a grid step more scalar
 bookkeeping than the products took: `tools/kernel_schedule.py`.) The copies
 start after the first product in program order and their address arithmetic
-lies under the products. A step is two MXU products, ``(H, W) x (W, rows)``
-for the scores and ``(H, rows) x (rows, rank)`` for the sum, bfloat16 into
-float32, with the online softmax between them in float32; a slot's last
-block divides and stores the output row. At 128 heads that is 2 x 128 x (576
-+ 512) operations a row of 1,152 useful bytes, 242 op/B: on the v5e's ridge.
-The XLA expression (the CPU, a mesh) gathers every slot's view.
+lies under the products. A step is two MXU products, bfloat16 into float32,
+each with the SLOT'S side held in the array and the block's rows streamed
+through it (counted as ``mx_kernel_dispatch_total{op="mla_decode_products",
+impl="queries_held"}``): the scores turned, ``(rows, W) x (W, H)``,
+against q, and the sum turned, ``(rank, rows) x (rows, H)``, against the
+weights, V's rows turned on their way. So the array is loaded with the
+slot's side, never with the block: 96 weight pushes a step of 512 rows
+where holding the block's 36 tiles took 288 (`tools/kernel_schedule.py`).
+Between them the online softmax in float32, rows in the sublanes and heads
+in the lanes; a slot's last block divides and stores the output row turned
+back, ``(H, rank)``. At 128 heads that is 2 x 128 x (576 + 512) operations
+a row of 1,152 useful bytes, 242 op/B: on the v5e's ridge. The XLA
+expression (the CPU, a mesh) gathers every slot's view.
 """
 from __future__ import annotations
 
@@ -555,9 +562,12 @@ def _mla_kernel(len_ref, slot_ref, block_ref, pages_ref, table_ref, q_ref,
 
     def step(buf, sem, free, free_sem):
         wait(buf, sem)
-        # the block as one (G pt, W) array, read where it is used: held as
-        # a value it would be spilled between the two products
-        s = jax.lax.dot_general(q_ref[...], buf[...],
+        # Both products keep the slot's side in the MXU and stream the
+        # block's rows through it: the scores turned, (G pt, H), with q
+        # held; the sum turned, (rank, H), with the weights held. The block
+        # is read where it is used, as one (G pt, W) array: held as a value
+        # it would be spilled between the two products
+        s = jax.lax.dot_general(buf[...], q_ref[...],
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
         # a later step's pages into the block the step before this one
@@ -565,17 +575,17 @@ def _mla_kernel(len_ref, slot_ref, block_ref, pages_ref, table_ref, q_ref,
         # program order, so that the copies' address arithmetic lies under
         # the products
         start(i + ahead, free, free_sem)
-        at = j * (G * pt) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(at < length, s, NEG_INF)                # (H, G pt)
-        m = m_scr[...]                                        # (H, 1)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        at = j * (G * pt) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        s = jnp.where(at < length, s, NEG_INF)                # (G pt, H)
+        m = m_scr[...]                                        # (1, H)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)              # a masked row: exp(-1e30) = 0
         m_scr[...] = m_new
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
-            p.astype(buf.dtype), buf[:, :rank_lanes],
-            preferred_element_type=jnp.float32)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=0, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot_general(
+            buf[:, :rank_lanes], p.astype(buf.dtype),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     # blocks the compiler can tell apart, the body once for each: with one
     # array indexed by the step it orders a block's load after every start
@@ -586,12 +596,12 @@ def _mla_kernel(len_ref, slot_ref, block_ref, pages_ref, table_ref, q_ref,
             step(bufs[r], sems.at[r], bufs[free], sems.at[free])
 
     # the slot's row of the output stays in VMEM until the slot changes:
-    # its last block writes what goes back
+    # its last block writes what goes back, turned back to (H, rank)
     @pl.when((j + 1) * (G * pt) >= length)
     def _():
         l = l_scr[...]
         o_ref[...] = (acc_scr[...] / jnp.where(l > 0.0, l, 1.0)
-                      ).astype(o_ref.dtype)
+                      ).T.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("rank", "sm_scale", "interpret",
@@ -619,9 +629,9 @@ def _pallas_mla_decode(q, pool, table, lengths, rank, sm_scale, interpret,
             scratch_shapes=[
                 *[pltpu.VMEM((G * pt, W), pool.dtype)] * _BUFFERS,
                 pltpu.SemaphoreType.DMA((_BUFFERS,)),
-                pltpu.VMEM((H, 1), jnp.float32),
-                pltpu.VMEM((H, 1), jnp.float32),
-                pltpu.VMEM((H, rank_lanes), jnp.float32)]),
+                pltpu.VMEM((1, H), jnp.float32),
+                pltpu.VMEM((1, H), jnp.float32),
+                pltpu.VMEM((rank_lanes, H), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((S, H, rank_lanes), q.dtype),
         # in order on one core: the softmax state is carried over a slot's
         # blocks, and a step starts the copies a later one waits for
@@ -648,6 +658,7 @@ def mla_decode_attention(q, pool, table, lengths, *, rank, sm_scale,
         impl = "pallas" if _dispatch.use_pallas() else "xla"
         _dispatch.note("mla_decode_attention", impl)
     if impl == "pallas":
+        _dispatch.note("mla_decode_products", "queries_held")
         return _pallas_mla_decode(q, pool, table, lengths, rank,
                                   float(sm_scale),
                                   _dispatch.interpret_default())

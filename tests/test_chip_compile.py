@@ -203,6 +203,15 @@ def test_mla_decode_compiles_for_v5e(chip):
                        text)
     operands = call.split("custom-call(", 1)[1].split(")", 1)[0]
     assert re.findall(r"%[\w.]+", operands).count(pool) == 1
+    # What the kernel keeps in VMEM, as the compiler placed it: three blocks
+    # of 512 x 640 rows, the turned sum (512 x 128 float32), the softmax's
+    # two (1, 128) rows, q's and the output's double buffers: 2.87 MB of the
+    # 16 MiB a kernel may hold (the weight pushes a step are read from the
+    # schedule, `tools/kernel_schedule.py mla_decode`: no HLO shows them)
+    used, = re.findall(r'"used_scoped_memory_configs":\[\{"memory_space":'
+                       r'"1","offset":"0","size":"(\d+)"\}\]', call)
+    blocks = 3 * 512 * W * 2 + 512 * 128 * 4 + 2 * (H * W + H * 512) * 2
+    assert blocks <= int(used) < blocks + 2 ** 16 < 16 * 2 ** 20
 
 
 @pytest.mark.parametrize("tokens,tile,step", [

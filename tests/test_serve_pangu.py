@@ -14,7 +14,7 @@ import incubator_mxnet_tpu as mx
 from chipbench.reference import pangu as ref
 from chipbench.runners import serve_pangu
 from incubator_mxnet_tpu.models import pangu
-from incubator_mxnet_tpu.ops import moe, paged_attention
+from incubator_mxnet_tpu.ops import _dispatch, moe, paged_attention
 from incubator_mxnet_tpu.serve import ShardedSlotDecoder, SlotDecoder
 from incubator_mxnet_tpu.serve.api import slots_class
 from incubator_mxnet_tpu.serve.mla import MLASlotDecoder
@@ -210,9 +210,10 @@ def test_stored_form_is_the_checkpoints_product(dec):
 
 # -- (c) the kernels in interpret mode ----------------------------------------
 
-# (id, lengths, pages a block at most, pool dtype): of a table of 8 pages of
-# 4 rows a slot. The kernel fetches a block's pages itself into one of a few
-# buffers, so the cases walk every way a buffer can be left and found
+# (id, lengths, pages a block at most, pool dtype[, widths]): of a table of
+# 8 pages of 4 rows a slot, 4 heads against rows of 32 + 8 stored 128 wide.
+# The kernel fetches a block's pages itself into one of a few buffers, so
+# the cases walk every way a buffer can be left and found
 MLA_CASES = [
     ("ragged", (5, 0, 29), 32, jnp.float32),
     ("full-and-one", (32, 17, 1), 32, jnp.float32),
@@ -231,17 +232,33 @@ MLA_CASES = [
     ("one-row-in-all", (0, 1, 0), 2, jnp.float32),
     ("bf16-ragged", (5, 0, 29), 2, jnp.bfloat16),
     ("bf16-full", (32, 17, 1), 4, jnp.bfloat16),
-]
+] + [(f"cell-{name}", lengths, 32, jnp.bfloat16, "cell") for name, lengths in [
+    # the cell's widths: 128 heads against rows of 512 + 64 stored 640 wide,
+    # pages of 16 rows, 96 a slot, the kernel's own block of 32 (512 rows),
+    # where the MXU holds the slot's queries and weights, not the block
+    ("nothing-alive", (0, 0, 0)),
+    ("one-row", (0, 1, 0)),
+    ("one-block", (512, 0, 0)),
+    ("one-block-and-a-row", (0, 0, 513)),
+    ("blocks-partial-last", (1300, 0, 0)),
+    # the slot changes between grid steps, a short one after a long one
+    ("slots-change", (513, 1, 1300))]]
+# heads, rank, rope, page rows, table pages
+MLA_WIDTHS = {"toy": (4, 32, 8, 4, 8), "cell": (128, 512, 64, 16, 96)}
 
 
-@pytest.mark.parametrize("lengths,block_pages,dtype",
-                         [c[1:] for c in MLA_CASES],
+@pytest.mark.parametrize("lengths,block_pages,dtype,widths",
+                         [(c + ("toy",))[1:5] for c in MLA_CASES],
                          ids=[c[0] for c in MLA_CASES])
-def test_mla_decode_kernel_is_its_xla_expression(lengths, block_pages, dtype):
+def test_mla_decode_kernel_is_its_xla_expression(lengths, block_pages, dtype,
+                                                 widths):
     rng = onp.random.default_rng(0)
-    S, H, rank, dr, pt, P, n_pages = 3, 4, 32, 8, 4, 8, 40
+    S = 3
+    H, rank, dr, pt, P = MLA_WIDTHS[widths]
     W = paged_attention.latent_store_width(rank + dr)
-    assert W == 128 and paged_attention.latent_store_width(576) == 640
+    assert paged_attention.latent_store_width(40) == 128
+    assert paged_attention.latent_store_width(576) == 640
+    n_pages = S * P + 16
     table = jnp.asarray(rng.permutation(onp.arange(1, n_pages))[:S * P]
                         .reshape(S, P), jnp.int32)
     pool = rng.normal(size=(n_pages, pt, W))
@@ -250,19 +267,48 @@ def test_mla_decode_kernel_is_its_xla_expression(lengths, block_pages, dtype):
     q = jnp.asarray(rng.normal(size=(S, H, W)), jnp.float32)
     q = q.at[..., rank + dr:].set(0)
     n = jnp.asarray(lengths, jnp.int32)
+    scale = 0.2 if widths == "toy" else 192 ** -0.5
     a = paged_attention.mla_decode_attention(q, pool, table, n, impl="xla",
-                                             rank=rank, sm_scale=0.2)
-    b = paged_attention._pallas_mla_decode(q, pool, table, n, rank, 0.2,
+                                             rank=rank, sm_scale=scale)
+    b = paged_attention._pallas_mla_decode(q, pool, table, n, rank, scale,
                                            True, block_pages=block_pages)
     assert a.shape == b.shape == (S, H, rank) and b.dtype == q.dtype
     # bfloat16: the kernel rounds exp(s - m) of a running maximum, the
     # expression a softmax already normalised
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
-    onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b), atol=tol,
-                                rtol=tol)
+    if widths == "toy":
+        onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b), atol=tol,
+                                    rtol=tol)
     for s, ln in enumerate(lengths):
+        if widths == "cell":
+            # on the slot's own scale: the first slot's outputs reach ~400
+            # at these widths, where the parent's body was ~0.9 off too
+            atol = tol * max(1.0, float(onp.abs(onp.asarray(a[s])).max()))
+            onp.testing.assert_allclose(onp.asarray(a[s]), onp.asarray(b[s]),
+                                        atol=atol, rtol=tol)
         if ln == 0:
             assert not onp.asarray(b[s]).any()
+
+
+def test_mla_decode_products_counter_says_which_form_ran(monkeypatch):
+    """``mx_kernel_dispatch_total{op="mla_decode_products"}``: one tick a
+    traced call of the kernel, `impl` the form its products take; none
+    where the XLA expression is taken."""
+    def count():
+        return _dispatch.choices().get(("mla_decode_products",
+                                        "queries_held"), 0)
+
+    rng = onp.random.default_rng(3)
+    args = (jnp.asarray(rng.normal(size=(1, 2, 128)), jnp.float32),
+            jnp.asarray(rng.normal(size=(9, 4, 128)), jnp.float32),
+            jnp.arange(1, 9, dtype=jnp.int32).reshape(1, 8),
+            jnp.asarray([30], jnp.int32))
+    before = count()
+    MLA_DECODE(*args, rank=32, sm_scale=0.2)        # the CPU: XLA
+    assert count() == before
+    monkeypatch.setattr(_dispatch, "use_pallas", lambda: True)
+    MLA_DECODE(*args, rank=32, sm_scale=0.2)
+    assert count() == before + 1
 
 
 def test_mla_decode_op_takes_the_kernel_by_name():

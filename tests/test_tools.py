@@ -1301,9 +1301,41 @@ def test_kernel_schedule_reads_a_final_bundles_file():
     assert "the grid loop 12 (file lines 9-22)" in out.getvalue()
     assert "[or skip to 16] 1 check" in out.getvalue()
     assert ("in the loop, every body counted: 2 vmatmul, 2 vpop, 0 vxpose, "
-            "2 dma, 1 wait, 1 check") in out.getvalue()
+            "0 vmatpush.xpose, 0 vmatpush, 0 vld, 0 vor, 2 dma, 1 wait, "
+            "1 check") in out.getvalue()
     ks.report("mx_none", "     0   :  { %s1 = smov 1 }", out=out)
     assert "mx_none: 1 bundles, no loop" in out.getvalue()
+
+
+def test_kernel_schedule_counts_the_mxu_loads_and_the_vector_loads():
+    """The weight pushes into the MXU, turned on their way in or not, and
+    the loads and ORs that assemble a bfloat16 vreg (lines of the kind
+    `mx_mla_decode`'s dump holds): what the products' orientation moves."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    try:
+        import kernel_schedule as ks
+    finally:
+        sys.path.pop(0)
+    text = "\n".join([
+        "     0 LB: > { %v1_v1 = vld [vmem:[%s2_s3] sm:$0xf]  ;;  "
+        "%v2_v2 = vld [vmem:[%s2_s3 + $0x4] sm:$0xf0] }",
+        "   0x1   : > { %v3_v3 = vor.u32 %v1_v1, %v2_v2  ;;  "
+        "%5 = vmatpush.bf16.xpose.msra.mxu0 %v3_v3 }",
+        "   0x2   : > { %6 = vmatpush.bf16.msrb.mxu1 %v3_v3  ;;  "
+        "%7 = vmatmul.bf16.gmra.mxu0 %v3_v3  ;;  %v8_v4 = vpop.eup %9 }",
+        "   0x3   : > { %v10_v5 = vld [vmem:[%s2_s3]]  ;;  "
+        "%v11_v6 = vpop.f32.mrf.mxu0  ;;  %12 = vxpose.binary.c.b16.cont "
+        "%v3_v3 }"])
+    kinds = [dict(b["kinds"]) for b in ks.parse(text)]
+    assert kinds == [{"vld": 2}, {"vor": 1, "vmatpush.xpose": 1},
+                     {"vmatpush": 1, "vmatmul": 1},
+                     {"vld": 1, "vpop": 1, "vxpose": 1}]
+    import io
+    out = io.StringIO()
+    ks.report("mx_mla", text, out=out)
+    assert ("every body counted: 1 vmatmul, 1 vpop, 1 vxpose, "
+            "1 vmatpush.xpose, 1 vmatpush, 3 vld, 1 vor, 0 dma, 0 wait, "
+            "0 check") in out.getvalue()
 
 
 def test_kernel_schedule_compiles_paged_decode_at_the_three_cells_shapes():
@@ -1322,3 +1354,24 @@ def test_kernel_schedule_compiles_paged_decode_at_the_three_cells_shapes():
     assert ks.PAGED_SHAPES["nemotron"] == (64, 192, 32, 2, 128, "bfloat16")
     with pytest.raises(SystemExit):
         ks.main(["paged_decode", "--shape", "bert"])
+
+
+def test_mla_decode_micro_rehearses_on_the_cpu():
+    """`tools/mla_decode_micro.py`: off the chip the kernel runs interpreted
+    at toy widths, one line a set of slot lengths, each kernel beside the
+    XLA expression and timed; `--parent` times another checkout beside."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tools", "mla_decode_micro.py"),
+         "--parent", REPO, "--reps", "1", "--calls", "1"],
+        capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    lines = dict(ln.split(" ", 1) for ln in proc.stdout.splitlines()
+                 if ln.split(" ", 1)[0] in ("cell_mix", "all_long", "short"))
+    assert set(lines) == {"cell_mix", "all_long", "short"}
+    for res in map(json.loads, lines.values()):
+        assert res["grid_steps"] > 0
+        for side in ("parent", "change"):
+            assert res[side + "_max_abs_diff"] <= 2e-2 * max(
+                1.0, res["ref_scale"])
+            assert len(res[side + "_us_call"]) == 1

@@ -14,7 +14,9 @@ branch and branch target, and at the first and the last line of each kind
 of work (``dma`` starts, ``dma.done.wait``, ``vmatmul``, ``vpop`` of the
 MXU's results), each with its count of lines and what it holds; a last line
 counts the loop's ``vmatmul``, ``vpop``, ``vxpose`` (an operand turned for
-the MXU), ``dma``, waits and range checks over all its bodies.
+the MXU), ``vmatpush`` (an operand loaded into the MXU: ``.xpose`` turned
+on its way in, or plain), ``vld``, ``vor``, ``dma``, waits and range checks
+over all its bodies.
 
 A stretch after a branch is walked only where the branch is not taken:
 ``or skip to 2363`` names the file line the branch goes to. A step's walk
@@ -67,6 +69,16 @@ KINDS = collections.OrderedDict([
     # an operand turned on its way into the MXU: a product written the
     # wrong way round for the array pays one a tile
     ("vxpose", lambda op: op.startswith("vxpose")),
+    # an operand loaded into the MXU, a pass of 16 bfloat16 rows each (8 a
+    # 128 x 128 tile): the side of a product the array holds, turned on its
+    # way in or not
+    ("vmatpush.xpose", lambda op: op.startswith("vmatpush")
+     and ".xpose" in op),
+    ("vmatpush", lambda op: op.startswith("vmatpush") and ".xpose" not in op),
+    # vector loads, and the ORs that join two half-loads of a bfloat16 vreg
+    # from a pool tiled (8, 128)(2, 1)
+    ("vld", lambda op: op == "vld"),
+    ("vor", lambda op: op.startswith("vor.")),
     ("check", lambda op: op == "shalt.err"),
 ])
 _CUT_KINDS = ("dma", "wait", "vmatmul", "vpop")
@@ -173,8 +185,9 @@ def report(name, text, out=sys.stdout):
               f"{holds}", file=out)
     held = sum((b["kinds"] for b in loop), collections.Counter())
     print("in the loop, every body counted: " + ", ".join(
-        f"{held[k]} {k}" for k in ("vmatmul", "vpop", "vxpose", "dma",
-                                   "wait", "check")), file=out)
+        f"{held[k]} {k}" for k in ("vmatmul", "vpop", "vxpose",
+                                   "vmatpush.xpose", "vmatpush", "vld", "vor",
+                                   "dma", "wait", "check")), file=out)
 
 
 # ---------------------------------------------------------------------------
