@@ -84,19 +84,24 @@ one operand left in HBM, one copy a live page into one of `_BUFFERS`
 each pipelined through its own `BlockSpec`, cost a grid step more scalar
 bookkeeping than the products took: `tools/kernel_schedule.py`.) The copies
 start after the first product in program order and their address arithmetic
-lies under the products. A step is two MXU products, bfloat16 into float32,
-each with the SLOT'S side held in the array and the block's rows streamed
-through it (counted as ``mx_kernel_dispatch_total{op="mla_decode_products",
-impl="queries_held"}``): the scores turned, ``(rows, W) x (W, H)``,
-against q, and the sum turned, ``(rank, rows) x (rows, H)``, against the
-weights, V's rows turned on their way. So the array is loaded with the
-slot's side, never with the block: 96 weight pushes a step of 512 rows
-where holding the block's 36 tiles took 288 (`tools/kernel_schedule.py`).
-Between them the online softmax in float32, rows in the sublanes and heads
-in the lanes; a slot's last block divides and stores the output row turned
-back, ``(H, rank)``. At 128 heads that is 2 x 128 x (576 + 512) operations
-a row of 1,152 useful bytes, 242 op/B: on the v5e's ridge. The XLA
-expression (the CPU, a mesh) gathers every slot's view.
+lies under the products. A step's products are MXU products, bfloat16 into
+float32, each with the SLOT'S side held in the array and the block's rows
+streamed through it: the scores turned, ``(rows, W) x (W, H)``, against q,
+and the sum turned, ``(rank, rows) x (rows, H)``, against the weights, V's
+rows turned on their way. So the array is loaded with the slot's side,
+never with the block: 160 weight pushes a step of 512 rows where holding
+the block's 36 tiles took 288 (`tools/kernel_schedule.py`). Between them
+the online softmax in float32, rows in the sublanes and heads in the lanes.
+The block is worked in two halves, each its own softmax update, and both
+halves' scores are written before the first half's sum (counted as
+``mx_kernel_dispatch_total{op="mla_decode_products",
+impl="queries_held_overlapped"}``): the MXU scores the second half while the
+VPU does the first's softmax, and sums the first while the VPU does the
+second's, where one softmax over the whole block left the array idle an
+eighth of the step. A slot's last block divides and stores the output row
+turned back, ``(H, rank)``. At 128 heads that is 2 x 128 x (576 + 512)
+operations a row of 1,152 useful bytes, 242 op/B: on the v5e's ridge. The
+XLA expression (the CPU, a mesh) gathers every slot's view.
 """
 from __future__ import annotations
 
@@ -560,32 +565,59 @@ def _mla_kernel(len_ref, slot_ref, block_ref, pages_ref, table_ref, q_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    # The block's rows in two halves of whole pages where its pages divide
+    # evenly, each its own online-softmax update; a half's sum in two
+    # products over halves of the rank where it divides into whole tiles.
+    # The MXU takes its work in program order, so both halves' scores come
+    # before the first half's sum: the array scores the second half while
+    # the VPU and EUP do the first half's softmax, and sums the first half
+    # while they do the second's. The compiler gives each held tile's
+    # stream of rows to one of the four MXUs: over the whole rank a half's
+    # sum is two streams of 512 rows, over its halves four of 256, so the
+    # last half's sum takes all four MXUs after its softmax, not two
+    rows = G // 2 * pt if G % 2 == 0 else G * pt
+    cols = rank_lanes // 2 if rank_lanes % (2 * LANES) == 0 else rank_lanes
+    firsts = range(0, G * pt, rows)
+    parts = range(0, rank_lanes, cols)
+
     def step(buf, sem, free, free_sem):
         wait(buf, sem)
         # Both products keep the slot's side in the MXU and stream the
-        # block's rows through it: the scores turned, (G pt, H), with q
-        # held; the sum turned, (rank, H), with the weights held. The block
-        # is read where it is used, as one (G pt, W) array: held as a value
-        # it would be spilled between the two products
-        s = jax.lax.dot_general(buf[...], q_ref[...],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        # a later step's pages into the block the step before this one
-        # read, while this one is worked on: after the first product in
-        # program order, so that the copies' address arithmetic lies under
-        # the products
-        start(i + ahead, free, free_sem)
-        at = j * (G * pt) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        s = jnp.where(at < length, s, NEG_INF)                # (G pt, H)
-        m = m_scr[...]                                        # (1, H)
-        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)              # a masked row: exp(-1e30) = 0
-        m_scr[...] = m_new
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=0, keepdims=True)
-        acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot_general(
-            buf[:, :rank_lanes], p.astype(buf.dtype),
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        # block's rows through it: the scores turned, (rows, H), with q
+        # held; the sum turned, (cols, H), with the weights held. The rows
+        # are read where they are used: held as a value they would be
+        # spilled between the two products
+        scores = []
+        for first in firsts:
+            scores.append(jax.lax.dot_general(
+                buf[pl.ds(first, rows), :], q_ref[...],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale)
+            if first == 0:
+                # a later step's pages into the block the step before this
+                # one read, while this one is worked on: after the first
+                # product in program order, so that the copies' address
+                # arithmetic lies under the products
+                start(i + ahead, free, free_sem)
+        m, l = m_scr[...], l_scr[...]                         # (1, H)
+        acc = [acc_scr[pl.ds(c, cols), :] for c in parts]     # (cols, H)
+        for first, s in zip(firsts, scores):
+            at = (j * (G * pt) + first
+                  + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+            s = jnp.where(at < length, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)          # a masked row: exp(-1e30) = 0
+            l = alpha * l + jnp.sum(p, axis=0, keepdims=True)
+            p = p.astype(buf.dtype)
+            acc = [alpha * a + jax.lax.dot_general(
+                buf[pl.ds(first, rows), pl.ds(c, cols)], p,
+                (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+                for a, c in zip(acc, parts)]
+            m = m_new
+        m_scr[...], l_scr[...] = m, l
+        for a, c in zip(acc, parts):
+            acc_scr[pl.ds(c, cols), :] = a
 
     # blocks the compiler can tell apart, the body once for each: with one
     # array indexed by the step it orders a block's load after every start
@@ -658,7 +690,7 @@ def mla_decode_attention(q, pool, table, lengths, *, rank, sm_scale,
         impl = "pallas" if _dispatch.use_pallas() else "xla"
         _dispatch.note("mla_decode_attention", impl)
     if impl == "pallas":
-        _dispatch.note("mla_decode_products", "queries_held")
+        _dispatch.note("mla_decode_products", "queries_held_overlapped")
         return _pallas_mla_decode(q, pool, table, lengths, rank,
                                   float(sm_scale),
                                   _dispatch.interpret_default())

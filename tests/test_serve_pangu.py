@@ -210,10 +210,15 @@ def test_stored_form_is_the_checkpoints_product(dec):
 
 # -- (c) the kernels in interpret mode ----------------------------------------
 
-# (id, lengths, pages a block at most, pool dtype[, widths]): of a table of
-# 8 pages of 4 rows a slot, 4 heads against rows of 32 + 8 stored 128 wide.
-# The kernel fetches a block's pages itself into one of a few buffers, so
-# the cases walk every way a buffer can be left and found
+# (id, lengths, pages a block at most, pool dtype[, widths[, hot]]): of a
+# table of 8 pages of 4 rows a slot, 4 heads against rows of 32 + 8 stored
+# 128 wide. The kernel fetches a block's pages itself into one of a few
+# buffers, so the cases walk every way a buffer can be left and found. It
+# works a block of an even number of pages in two halves, each its own
+# softmax update: lengths end at a half's edge, a row past it and a row
+# before it. `hot` is a range of the second slot's pages whose rows are
+# scaled x100, a later half's scores far above an earlier one's, so that
+# the update rescales a sum that is not empty
 MLA_CASES = [
     ("ragged", (5, 0, 29), 32, jnp.float32),
     ("full-and-one", (32, 17, 1), 32, jnp.float32),
@@ -232,7 +237,16 @@ MLA_CASES = [
     ("one-row-in-all", (0, 1, 0), 2, jnp.float32),
     ("bf16-ragged", (5, 0, 29), 2, jnp.bfloat16),
     ("bf16-full", (32, 17, 1), 4, jnp.bfloat16),
-] + [(f"cell-{name}", lengths, 32, jnp.bfloat16, "cell") for name, lengths in [
+    # blocks of 4 pages: halves of 8 rows, in the first block and a later
+    ("half-edge", (8, 9, 7), 4, jnp.float32),
+    ("later-half-edge", (24, 25, 23), 4, jnp.float32),
+    ("bf16-half-edge", (8, 9, 7), 4, jnp.bfloat16),
+    # the whole table a block: halves of 16 rows
+    ("half-edge-one-block", (16, 17, 15), 32, jnp.float32),
+    ("later-half-far-above", (5, 16, 0), 4, jnp.float32, "toy", (2, 4)),
+    ("later-block-far-above", (0, 30, 9), 4, jnp.float32, "toy", (6, 8)),
+] + [(f"cell-{name}", lengths, 32, jnp.bfloat16, "cell", *hot)
+     for name, lengths, *hot in [
     # the cell's widths: 128 heads against rows of 512 + 64 stored 640 wide,
     # pages of 16 rows, 96 a slot, the kernel's own block of 32 (512 rows),
     # where the MXU holds the slot's queries and weights, not the block
@@ -242,16 +256,21 @@ MLA_CASES = [
     ("one-block-and-a-row", (0, 0, 513)),
     ("blocks-partial-last", (1300, 0, 0)),
     # the slot changes between grid steps, a short one after a long one
-    ("slots-change", (513, 1, 1300))]]
+    ("slots-change", (513, 1, 1300)),
+    # halves of 256 rows
+    ("half-edge", (256, 257, 255)),
+    ("later-half-edge", (768, 769, 767)),
+    ("later-half-far-above", (0, 700, 0), (16, 32))]]
 # heads, rank, rope, page rows, table pages
 MLA_WIDTHS = {"toy": (4, 32, 8, 4, 8), "cell": (128, 512, 64, 16, 96)}
 
 
-@pytest.mark.parametrize("lengths,block_pages,dtype,widths",
-                         [(c + ("toy",))[1:5] for c in MLA_CASES],
+@pytest.mark.parametrize("lengths,block_pages,dtype,widths,hot",
+                         [(c + ("toy", None)[len(c) - 4:])[1:]
+                          for c in MLA_CASES],
                          ids=[c[0] for c in MLA_CASES])
 def test_mla_decode_kernel_is_its_xla_expression(lengths, block_pages, dtype,
-                                                 widths):
+                                                 widths, hot):
     rng = onp.random.default_rng(0)
     S = 3
     H, rank, dr, pt, P = MLA_WIDTHS[widths]
@@ -263,6 +282,8 @@ def test_mla_decode_kernel_is_its_xla_expression(lengths, block_pages, dtype,
                         .reshape(S, P), jnp.int32)
     pool = rng.normal(size=(n_pages, pt, W))
     pool[onp.asarray(table[0])] *= 100.0          # the first slot's rows
+    if hot is not None:
+        pool[onp.asarray(table[1, slice(*hot)])] *= 100.0
     pool = jnp.asarray(pool, dtype).at[..., rank + dr:].set(0)
     q = jnp.asarray(rng.normal(size=(S, H, W)), jnp.float32)
     q = q.at[..., rank + dr:].set(0)
@@ -296,7 +317,7 @@ def test_mla_decode_products_counter_says_which_form_ran(monkeypatch):
     where the XLA expression is taken."""
     def count():
         return _dispatch.choices().get(("mla_decode_products",
-                                        "queries_held"), 0)
+                                        "queries_held_overlapped"), 0)
 
     rng = onp.random.default_rng(3)
     args = (jnp.asarray(rng.normal(size=(1, 2, 128)), jnp.float32),
